@@ -501,7 +501,7 @@ def _check_jit_target(sf: SourceFile, kinds: _Kinds, call: ast.Call,
             "capture discipline is established elsewhere",
             f"{sf.qualname(call)}:{target.id}"))
         return
-    # jit of an arbitrary expression (e.g. jax.jit(_shard_map(...)))
+    # jit of an arbitrary expression (e.g. jax.jit(jax.shard_map(...)))
     waivers = _parse_waivers(sf.comment_near(call))
     if waivers is not None and waivers.wildcard:
         return

@@ -594,7 +594,7 @@ class Booster:
         metrics lack device implementations.
 
         The engine's training loop uses this to pipeline: iteration
-        i+1's fused step overlaps the RPC that fetches iteration i's
+        i+1's fused step overlaps the copy that fetches iteration i's
         metric scalars, so per-iteration evaluation (early stopping)
         costs latency, not throughput."""
         idxs = ([(0, self._train_data_name)] if include_train else [])
@@ -719,6 +719,12 @@ class Booster:
     def learner_mode(self) -> str:
         """Resolved tree learner (may be 'serial' after fallback)."""
         return self._gbdt.learner_mode
+
+    def device_report(self) -> dict:
+        """What the training engine resolved to on this process's
+        devices (route, tier, mesh, shard placement) — see
+        GBDT.device_report."""
+        return self._gbdt.device_report()
 
     def leaves_and_waves(self, start_group: int = 0):
         """Per-iteration leaf/wave counts (ONE stacked download) —

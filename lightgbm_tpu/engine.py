@@ -203,7 +203,7 @@ def _train_loop(booster, params, init_iteration, num_boost_round,
     # pipelined evaluation: when every metric evaluates on device
     # (Booster.eval_dispatch_async), iteration i's metric scalars are
     # fetched WHILE iteration i+1 computes, so per-round evaluation
-    # (early stopping) costs RPC latency, not training throughput.
+    # (early stopping) costs copy latency, not training throughput.
     # Custom fevals need host scores -> synchronous path. USER
     # callbacks also force the synchronous path: under pipelining an
     # after-iteration callback for iteration i runs while the booster

@@ -14,7 +14,6 @@ trivial-feature exclusion, metadata (labels/weights/queries/init scores).
 """
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -23,19 +22,6 @@ from ..config import Config
 from ..ops.split import FeatureMeta
 from ..utils import log
 from .binning import BinMapper, BinType
-
-
-def default_cache_dir() -> str:
-    """Shared on-disk cache directory for engine artifacts that persist
-    across processes: the kernel tuning cache (ops/autotune.py) and the
-    persistent XLA compile cache live here; dataset binary files
-    (save_binary) take explicit paths but share the versioned-token
-    discipline. Overridable via LGBM_TPU_CACHE_DIR."""
-    import tempfile
-    d = os.environ.get("LGBM_TPU_CACHE_DIR") or os.path.join(
-        tempfile.gettempdir(), "lgbm_tpu_cache")
-    os.makedirs(d, exist_ok=True)
-    return d
 
 
 class Metadata:
@@ -341,8 +327,8 @@ class TpuDataset:
                 binner = SparseDeviceBinner(
                     self.mappers, self.used_feature_map, self.config)
             except IngestUnsupported as e:
-                log.debug("sparse device ingest unavailable (%s); "
-                          "host scatter", e)
+                log.warning("sparse device ingest unavailable (%s); "
+                            "host scatter", e)
             else:
                 self.bins_t_dev, coords = binner.bin_matrix_sparse(
                     sm, want_coords=keep_coords)
@@ -446,7 +432,7 @@ class TpuDataset:
                 binner = DeviceBinner(self.mappers, self.used_feature_map,
                                       self.config, X.dtype)
             except IngestUnsupported as e:
-                log.debug("device ingest unavailable (%s); host binner", e)
+                log.warning("device ingest unavailable (%s); host binner", e)
             else:
                 # valid sets ride as passenger columns of the grower
                 # matrix (models/gbdt.py) — only the train set's rows

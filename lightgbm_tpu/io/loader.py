@@ -264,8 +264,8 @@ class DatasetLoader:
                 binner = DeviceBinner(ds.mappers, ds.used_feature_map,
                                       cfg, np.float64)
             except IngestUnsupported as e:
-                log.debug("two_round device ingest unavailable (%s); "
-                          "host binner", e)
+                log.warning("two_round device ingest unavailable (%s); "
+                            "host binner", e)
             else:
                 # valid sets ride as passenger columns of the grower
                 # matrix (models/gbdt.py) — only the train set's rows

@@ -29,44 +29,51 @@ _lib = None
 _tried = False
 
 
+def _build(src: str) -> None:
+    subprocess.run(
+        ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _SO, src],
+        check=True, capture_output=True, timeout=120)
+
+
 def _load() -> Optional[ctypes.CDLL]:
+    """The native library, built on first use from
+    ``native/fast_parser.cpp`` (a clean checkout carries no binary).
+    Whichever implementation ends up serving this process is logged
+    once: the native one at info level, the python fallback — slower by
+    orders of magnitude on big files — at warning level with the
+    reason."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
     src = os.path.normpath(_SRC)
-    if not os.path.exists(_SO) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(_SO)):
-        if not os.path.exists(src):
-            return None
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-pthread",
-                 "-o", _SO, src],
-                check=True, capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError) as e:
-            log.debug("native parser build unavailable (%s); using the "
-                      "python parser", e)
-            return None
     try:
+        built = False
+        if not os.path.exists(_SO) or (
+                os.path.exists(src)
+                and os.path.getmtime(src) > os.path.getmtime(_SO)):
+            _build(src)
+            built = True
         lib = ctypes.CDLL(_SO)
-    except OSError:
-        return None
-    try:
-        _bind(lib)
-    except AttributeError:
-        # stale cached .so from an older version missing a symbol:
-        # rebuild once, else fall back to the python paths
         try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-pthread",
-                 "-o", _SO, src],
-                check=True, capture_output=True, timeout=120)
+            _bind(lib)
+        except AttributeError:
+            # stale cached .so from an older version missing a symbol:
+            # rebuild once
+            _build(src)
+            built = True
             lib = ctypes.CDLL(_SO)
             _bind(lib)
-        except (OSError, subprocess.SubprocessError, AttributeError):
-            return None
+    except (OSError, subprocess.SubprocessError, AttributeError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        log.warning("native parser unavailable (%s: %s%s); using the "
+                    "python parser and binner (io/parser.py)",
+                    type(e).__name__, e,
+                    " — " + detail.decode(errors="replace")[-300:]
+                    if detail else "")
+        return None
+    log.info("native parser in use: %s (%s from %s)", _SO,
+             "built just now" if built else "already built", src)
     _lib = lib
     return _lib
 

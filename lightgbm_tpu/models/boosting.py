@@ -280,7 +280,8 @@ class DART(GBDT):
                 leaf = replay_partition(rec, tb,
                                         self._meta)[:self._n_score]
                 self._scores = self._scores.at[k].set(add_leaf_outputs(
-                    self._scores[k], leaf, rec.leaf_output, -1.0))
+                    self._scores[k], leaf, rec.leaf_output, -1.0,
+                    **self._mesh_kw()))
         kdrop = len(self._drop_index)
         if not cfg.xgboost_dart_mode:
             self.shrinkage_rate = cfg.learning_rate / (1.0 + kdrop)
@@ -317,11 +318,12 @@ class DART(GBDT):
                     self._valid_scores[vi] = \
                         self._valid_scores[vi].at[k].set(add_leaf_outputs(
                             self._valid_scores[vi][k], vleaf, old_out,
-                            keep_scale - 1.0))
+                            keep_scale - 1.0, **self._mesh_kw()))
                 # train: was subtracted fully, add back keep_scale*old
                 leaf = replay_partition(rec, tb, self._meta)[:self._n_score]
                 self._scores = self._scores.at[k].set(add_leaf_outputs(
-                    self._scores[k], leaf, old_out, keep_scale))
+                    self._scores[k], leaf, old_out, keep_scale,
+                    **self._mesh_kw()))
                 self.records[t] = rec._replace(
                     leaf_output=old_out * keep_scale,
                     internal_value=rec.internal_value * keep_scale)

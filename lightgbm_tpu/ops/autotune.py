@@ -32,9 +32,13 @@ This module is the single place that knows about tiles:
    recompilation.
 
 Config surface: ``tpu_autotune`` (on / off / exhaustive) and
-``tpu_tuning_cache`` (cache file path; empty = the shared cache dir,
-io/dataset.py ``default_cache_dir``). Tuning only ever runs on a real
-TPU backend — CPU/interpret callers get the defaults for free.
+``tpu_tuning_cache`` (cache file path; empty = inside the compile-cache
+directory, ``default_tuning_cache_path``). Tuning only ever runs on a
+real TPU backend — CPU/interpret callers get the defaults for free. On
+that backend nothing is swallowed: a candidate the compiler rejects is
+warned about with the compiler's text and counted
+(``autotune/candidates_failed``), and a key whose every candidate
+failed is fatal (``Autotuner.best``).
 """
 from __future__ import annotations
 
@@ -51,12 +55,22 @@ from ..utils import log, timing
 # Shared VMEM constants and kernel block geometry
 # ---------------------------------------------------------------------------
 
+# VMEM capacity per TPU ``device_kind``, keyed by the string the chip
+# itself reports (a v5e says "TPU v5 lite") and taken from the TPU
+# compiler, not a datasheet: compiling an over-sized kernel for the
+# described v5e answers "Used 192.00M of 128.00M vmem" (libtpu 0.0.34).
+# The limit and budget below are fractions of THAT capacity, so a TPU
+# of a kind not listed here is an error (check_vmem_device), never
+# quietly priced as a v5e.
+TPU_VMEM_CAPACITY_BYTES = {"TPU v5 lite": 128 * 1024 * 1024}
 # scoped-VMEM cap passed to every Pallas hot-path kernel (CompilerParams
 # vmem_limit_bytes): the unrolled group loops' temporaries exceed the
-# 16 MB default; v5e has 128 MB physical VMEM
+# 16 MB default
 PALLAS_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 # working-set budget the tile guards/tuner admit against: headroom under
-# the limit for Mosaic's own temporaries
+# the limit for Mosaic's own temporaries (the compiler accepts every
+# histogram candidate priced inside it at the HIGGS/LRB widths, and
+# more — tests/test_tpu_compile.py)
 PALLAS_VMEM_BUDGET_BYTES = 72 * 1024 * 1024
 
 # default tiles (the pre-autotuner hardcoded values, kept as the
@@ -213,13 +227,30 @@ def fits_vmem(nbytes: int) -> bool:
     return nbytes <= PALLAS_VMEM_BUDGET_BYTES
 
 
+def check_vmem_device() -> None:
+    """Refuse to price kernels for a TPU whose VMEM nobody looked up:
+    the limit/budget above are fractions of the capacities in
+    TPU_VMEM_CAPACITY_BYTES. Off-TPU (interpret mode, AOT compiles for
+    a described chip) there is nothing to check."""
+    from ..utils.device import get_devices
+    d = get_devices()[0]
+    if d.platform != "tpu":
+        return
+    if d.device_kind not in TPU_VMEM_CAPACITY_BYTES:
+        log.fatal(f"no VMEM capacity on record for TPU device_kind "
+                  f"{d.device_kind!r} (known: "
+                  f"{sorted(TPU_VMEM_CAPACITY_BYTES)}); add what the "
+                  f"compiler reports for it to ops/autotune.py "
+                  f"TPU_VMEM_CAPACITY_BYTES")
+
+
 def tpu_compiler_params(*, vmem_limit_bytes: int = PALLAS_VMEM_LIMIT_BYTES):
-    """Version-portable pltpu CompilerParams (renamed from
-    TPUCompilerParams after jax 0.4.x)."""
+    """Mosaic CompilerParams every TPU hot-path kernel passes (the one
+    place the scoped-VMEM limit is applied — and checked against the
+    chip it is applied to)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(vmem_limit_bytes=vmem_limit_bytes)
+    check_vmem_device()
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -293,17 +324,10 @@ def fits_smem(nbytes: int) -> bool:
 
 
 def gpu_compiler_params(*, num_warps: int = 4, num_stages: int = 2):
-    """Version-portable Pallas-Triton CompilerParams, or None when the
-    Triton lowering is absent (interpret-mode callers pass None)."""
-    try:
-        from jax.experimental.pallas import triton as plgpu
-    except ImportError:
-        return None
-    cls = getattr(plgpu, "CompilerParams", None) \
-        or getattr(plgpu, "TritonCompilerParams", None)
-    if cls is None:
-        return None
-    return cls(num_warps=num_warps, num_stages=num_stages)
+    """Pallas-Triton CompilerParams of the GPU histogram/forest kernels
+    (interpret-mode callers pass None instead)."""
+    from jax.experimental.pallas import triton as plgpu
+    return plgpu.CompilerParams(num_warps=num_warps, num_stages=num_stages)
 
 
 @functools.lru_cache(maxsize=1)
@@ -354,10 +378,28 @@ def tune_hist_route(*, backend: Optional[str] = None,
 TUNING_CACHE_VERSION = 1
 
 
+def default_cache_dir() -> str:
+    """The ONE on-disk cache directory of a checkout: the persistent
+    XLA compile cache (ensure_compile_cache) and, inside it, the kernel
+    tuning cache. Fixed beside the package (``<checkout>/
+    .lgbm_tpu_cache``, git-ignored) — never a temp name, pid or time:
+    the path is part of the compile cache's key, so a directory that
+    moves never hits."""
+    d = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".lgbm_tpu_cache")
+    os.makedirs(d, exist_ok=True)
+    return d
+
+
 def default_tuning_cache_path() -> str:
-    from ..io.dataset import default_cache_dir
-    return os.path.join(default_cache_dir(),
-                        f"tuning_v{TUNING_CACHE_VERSION}.json")
+    """The tuning JSON sits inside the compile-cache directory in use —
+    the operator's (``JAX_COMPILATION_CACHE_DIR``) when one is placed,
+    else ``default_cache_dir()`` — so whatever carries one cache from
+    run to run carries the other."""
+    import jax
+    return os.path.join(
+        jax.config.jax_compilation_cache_dir or default_cache_dir(),
+        f"lgbm_tpu_tuning_v{TUNING_CACHE_VERSION}.json")
 
 
 class TuningCache:
@@ -442,8 +484,15 @@ class Autotuner:
         Callers whose candidate sets vary with non-key inputs must fold
         a candidate fingerprint into ``key``, or differently-shaped
         runs would perpetually overwrite each other's entries.
-        Candidates that fail to compile or run are skipped, not
-        fatal."""
+        A candidate that fails to compile or run is never silent: it
+        is counted (``autotune/candidates_failed``) and, on a TPU
+        backend, warned about with the compiler's text — there the
+        candidate set was priced against this chip's VMEM, so a Mosaic
+        rejection is a defect to report, and a key whose EVERY
+        candidate failed is fatal (training on a default nobody could
+        compile would only fail later, or worse, run as something
+        else). Off-TPU (injected timers, the never-run GPU arm) the
+        default is still served."""
         if not candidates:
             return default
         if self.mode == "off":
@@ -453,22 +502,31 @@ class Autotuner:
         if hit is not None and hit.get("choice") in candidates:
             obs.counter("autotune/cache_hits").add(1)
             return hit["choice"]
+        from ..utils.device import backend_kind
+        tpu = backend_kind() == "tpu"
         timings_ms: Dict[str, float] = {}
         best_c, best_t = None, float("inf")
         with timing.phase(f"autotune/{kernel}"):
             for cand in candidates:
                 try:
                     t = measure(cand)
-                except Exception as e:        # noqa: BLE001 — a candidate
-                    # that Mosaic rejects must not kill training
-                    log.debug("autotune[%s]: candidate %s failed: %s",
-                              kernel, cand, e)
+                except Exception as e:        # noqa: BLE001 — reported,
+                    # counted and (every candidate, on TPU) fatal below
+                    obs.counter("autotune/candidates_failed").add(1)
+                    (log.warning if tpu else log.info)(
+                        "autotune[%s]: candidate %s failed: %s: %s",
+                        kernel, cand, type(e).__name__, e)
                     continue
                 timings_ms[json.dumps(cand, sort_keys=True)] = round(
                     t * 1e3, 4)
                 if t < best_t:
                     best_c, best_t = cand, t
         if best_c is None:
+            if tpu:
+                log.fatal(f"autotune[{kernel}]: every candidate "
+                          f"{candidates} failed on the TPU backend for "
+                          f"key {key} (see the warnings above for the "
+                          f"compiler's text)")
             log.warning("autotune[%s]: every candidate failed; using the"
                         " default %s", kernel, default)
             return default if default is not None else candidates[0]
@@ -503,7 +561,8 @@ def tuner() -> Autotuner:
 
 
 def device_kind() -> str:
-    """Cache-key device identity (e.g. 'TPU v5e' / 'cpu')."""
+    """Cache-key device identity: the string the device itself reports
+    (a v5e says 'TPU v5 lite'; the CPU backend 'cpu')."""
     from ..utils.device import get_devices
     d = get_devices()[0]
     return str(getattr(d, "device_kind", None) or d.platform)
@@ -516,21 +575,16 @@ def device_kind() -> str:
 _compile_cache_done = False
 
 
-def _jax_version() -> tuple:
-    import jax
-    try:
-        return tuple(int(x) for x in jax.__version__.split(".")[:2])
-    except (AttributeError, ValueError):
-        return (0, 0)
-
-
-def ensure_compile_cache(path: Optional[str] = None,
-                         cpu_opt_in: bool = False,
-                         mode: Optional[int] = None) -> None:
+def ensure_compile_cache(mode: int = -1) -> None:
     """Wire jax's persistent compilation cache so the grower/predict
     kernels compile once per machine, not once per process (~tens of
-    seconds per distinct shape on TPU). Idempotent; an explicit
-    operator/test setting of jax_compilation_cache_dir is respected.
+    seconds per distinct shape on TPU). Idempotent, and the ONLY place
+    this repo sets ``jax_compilation_cache_dir``: when the operator
+    placed the cache (``JAX_COMPILATION_CACHE_DIR`` — jax reads it into
+    its own config — or an explicit config update) it is used as is and
+    nothing else is set; otherwise the cache goes to the one fixed
+    ``default_cache_dir()`` (the path is part of the cache key, so it
+    must never move between runs).
 
     ``mode`` is config.tpu_compile_cache's tri-state. The policy
     matrix (Design.md §5i):
@@ -540,51 +594,28 @@ def ensure_compile_cache(path: Optional[str] = None,
     ========  ==========  =======  ========
     tpu       on          off      on
     gpu       on          off      on
-    cpu       off         off      jax>=0.5
+    cpu       off         off      on
     ========  ==========  =======  ========
 
     TPU and GPU auto-enable: that is where the expensive Mosaic /
-    Triton compiles live, and their deserialization paths are sound.
-    The CPU backend stays opt-in because this image's jax 0.4.x
-    flakily segfaults while DESERIALIZING warm CPU cache entries
-    (observed ~1/3 of warm-cache test runs) — mode=1 on CPU is gated
-    on jax >= 0.5 where that path is fixed; on older jax it warns and
-    stays off. An operator can always set jax_compilation_cache_dir
-    explicitly (it is respected on any jax and any backend).
-    ``cpu_opt_in`` is the pre-rename kwarg (tpu_compile_cache_cpu),
-    kept for callers that predate ``mode``: True maps to mode=1."""
+    Triton compiles live. The CPU backend stays opt-in — the CPU test
+    suite compiles hundreds of small programs whose cache writes cost
+    more than they save."""
     global _compile_cache_done
     if _compile_cache_done:
         return
-    if mode is None:
-        mode = 1 if cpu_opt_in else -1
     import jax
-    try:
-        _compile_cache_done = True
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return                       # operator already configured it
-        from ..utils.device import backend_kind
-        backend = backend_kind()
-        if mode == 0 or (backend == "cpu" and mode != 1):
-            # NOT a terminal decision: a later booster may opt in
-            # (tpu_compile_cache=1), so leave the flag unset
-            _compile_cache_done = False
-            return
-        if backend == "cpu" and _jax_version() < (0, 5):
-            log.warning(
-                "tpu_compile_cache=1 on the CPU backend needs jax >= "
-                "0.5 (this jax %s flakily segfaults deserializing "
-                "warm CPU cache entries); leaving the persistent "
-                "compile cache off", jax.__version__)
-            return
-        from ..io.dataset import default_cache_dir
-        jax.config.update("jax_compilation_cache_dir",
-                          path or os.path.join(default_cache_dir(), "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
-    except Exception as e:               # noqa: BLE001 — the cache is an
-        # optimization; a jax without it must not break training
-        log.debug("persistent compile cache unavailable: %s", e)
+    if jax.config.jax_compilation_cache_dir:
+        _compile_cache_done = True       # operator already placed it
+        return
+    from ..utils.device import backend_kind
+    if mode == 0 or (backend_kind() == "cpu" and mode != 1):
+        # NOT a terminal decision: a later booster may opt in
+        # (tpu_compile_cache=1), so leave the flag unset
+        return
+    _compile_cache_done = True
+    jax.config.update("jax_compilation_cache_dir", default_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -926,15 +957,15 @@ def _psum_measure_fn(mesh, shape):
 
     # lazy: parallel.learners imports ops.wave_grower which imports
     # this module at top level
-    from ..parallel.learners import AXIS, _shard_map
+    from ..parallel.learners import AXIS
 
     def build(dtype):
         def body(x):
             return jax.lax.psum(x, AXIS)
         # jit-capture: ok(*) — throwaway psum microbenchmark body,
         # closes over nothing but the mesh axis; never cached
-        f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(P(),),
-                               out_specs=P(), check_vma=False))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                  out_specs=P(), check_vma=False))
         x = jnp.ones(shape, dtype)
         return functools.partial(f, x)
 
@@ -1019,7 +1050,7 @@ def _psum_slots_measure_fn(mesh, shape, wire: str):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.learners import _shard_map, _slot_psum
+    from ..parallel.learners import _slot_psum
 
     dtype = {"int8": jnp.int8, "int16": jnp.int16,
              "int32": jnp.int32}.get(wire, jnp.float32)
@@ -1029,8 +1060,8 @@ def _psum_slots_measure_fn(mesh, shape, wire: str):
             return _slot_psum(x, slots)
         # jit-capture: ok(*) — throwaway psum microbenchmark body,
         # closes over nothing but the mesh axis; never cached
-        f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(P(),),
-                               out_specs=P(), check_vma=False))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                  out_specs=P(), check_vma=False))
         x = jnp.ones(shape, dtype)
         return functools.partial(f, x)
 
@@ -1050,14 +1081,14 @@ def measure_psum_s(mesh, shape, dtype) -> float:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.learners import AXIS, _shard_map
+    from ..parallel.learners import AXIS
 
     def body(x):
         return jax.lax.psum(x, AXIS)
     # jit-capture: ok(*) — throwaway psum microbenchmark body, closes
     # over nothing but the mesh axis; never cached
-    f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))
     x = jnp.ones(shape, dtype)
     return float(timing.measure(functools.partial(f, x)))
 

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import triton as plgpu
 
 from . import autotune
 
@@ -1238,17 +1239,17 @@ def _gpu_wave_kernel(wl_ref, bins_ref, g_ref, h_ref, leaf_ref,
         if int8:
             gq = jnp.full((F,), g_ref[r].astype(i32))
             hq = jnp.full((F,), h_ref[r].astype(i32))
-            pl.atomic_add(hist_ref, (flat, 0), gq)
-            pl.atomic_add(hist_ref, (flat, 1), hq)
+            plgpu.atomic_add(hist_ref, (flat, 0), gq)
+            plgpu.atomic_add(hist_ref, (flat, 1), hq)
             if not count_proxy:
-                pl.atomic_add(hist_ref, (flat, 2),
+                plgpu.atomic_add(hist_ref, (flat, 2),
                               jnp.full((F,), jnp.int32(1)))
         else:
-            pl.atomic_add(hist_ref, (flat, 0),
+            plgpu.atomic_add(hist_ref, (flat, 0),
                           jnp.full((F,), g_ref[r]))
-            pl.atomic_add(hist_ref, (flat, 1),
+            plgpu.atomic_add(hist_ref, (flat, 1),
                           jnp.full((F,), h_ref[r]))
-            pl.atomic_add(hist_ref, (flat, 2),
+            plgpu.atomic_add(hist_ref, (flat, 2),
                           jnp.full((F,), jnp.float32(1.0)))
         return carry
 
@@ -1413,7 +1414,7 @@ def _gpu_fused_kernel(tbl_ref, bins_ref, g_ref, h_ref, mask_ref,
         # valued -> order-free exact, atomics or not)
         s = jnp.sum((moved & in_bag[None, :]).astype(jnp.float32),
                     axis=1)                                # [W]
-        pl.atomic_add(cnt_ref,
+        plgpu.atomic_add(cnt_ref,
                       (jax.lax.broadcasted_iota(i32, (W,), 0),), s)
 
     # ---- per-row atomic histogram scatter ----
@@ -1427,19 +1428,19 @@ def _gpu_fused_kernel(tbl_ref, bins_ref, g_ref, h_ref, mask_ref,
         flat = slot * (F * B) + offs + _gpu_unpack_row(
             bins_ref, r, F, packed4)
         if int8:
-            pl.atomic_add(hist_ref, (flat, 0),
+            plgpu.atomic_add(hist_ref, (flat, 0),
                           jnp.full((F,), g_ref[r].astype(i32)))
-            pl.atomic_add(hist_ref, (flat, 1),
+            plgpu.atomic_add(hist_ref, (flat, 1),
                           jnp.full((F,), h_ref[r].astype(i32)))
             if not count_proxy:
-                pl.atomic_add(hist_ref, (flat, 2),
+                plgpu.atomic_add(hist_ref, (flat, 2),
                               jnp.full((F,), jnp.int32(1)))
         else:
-            pl.atomic_add(hist_ref, (flat, 0),
+            plgpu.atomic_add(hist_ref, (flat, 0),
                           jnp.full((F,), g_ref[r]))
-            pl.atomic_add(hist_ref, (flat, 1),
+            plgpu.atomic_add(hist_ref, (flat, 1),
                           jnp.full((F,), h_ref[r]))
-            pl.atomic_add(hist_ref, (flat, 2),
+            plgpu.atomic_add(hist_ref, (flat, 2),
                           jnp.full((F,), jnp.float32(1.0)))
         return carry
 

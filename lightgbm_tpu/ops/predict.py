@@ -90,18 +90,39 @@ def leaf_gather_pallas(table, leaf_ids, *, interpret=False):
     return out.reshape(-1)[:n]
 
 
-def leaf_gather(table, leaf_ids):
-    """Dispatch: Pallas sweep on TPU, plain XLA gather elsewhere."""
+def leaf_gather(table, leaf_ids, mesh=None, row_sharded=False):
+    """Dispatch: Pallas sweep on TPU, plain XLA gather elsewhere.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"): inside a program that spans a device mesh — every
+    booster training under a parallel tree learner — the sweep must run
+    per shard. ``mesh`` is that mesh (None = a single-device program);
+    ``row_sharded`` says whether ``leaf_ids`` is split over the mesh's
+    row axis (data/voting learners) or replicated (feature learner).
+    Row counts the mesh does not divide take the XLA gather — jax only
+    shards evenly divisible axes (models/gbdt.py _place_scores)."""
     from ..utils.device import on_tpu
-    if on_tpu() and table.shape[0] <= 4096 and leaf_ids.shape[0] >= 8:
+    if not (on_tpu() and table.shape[0] <= 4096
+            and leaf_ids.shape[0] >= 8):
+        return table[leaf_ids]
+    if mesh is None:
         return leaf_gather_pallas(table, leaf_ids)
-    return table[leaf_ids]
+    if row_sharded and leaf_ids.shape[0] % mesh.devices.size:
+        return table[leaf_ids]
+    from jax.sharding import PartitionSpec as P
+    rows = P(mesh.axis_names[0]) if row_sharded else P()
+    return jax.shard_map(leaf_gather_pallas, mesh=mesh,
+                         in_specs=(P(), rows), out_specs=rows,
+                         check_vma=False)(table, leaf_ids)
 
 
-@jax.jit
-def add_leaf_outputs(scores, leaf_ids, leaf_output, shrinkage):
-    """score += shrinkage * leaf_output[leaf] (ScoreUpdater::AddScore)."""
-    return scores + shrinkage * leaf_gather(leaf_output, leaf_ids)
+@functools.partial(jax.jit, static_argnames=("mesh", "row_sharded"))
+def add_leaf_outputs(scores, leaf_ids, leaf_output, shrinkage, mesh=None,
+                     row_sharded=False):
+    """score += shrinkage * leaf_output[leaf] (ScoreUpdater::AddScore).
+    ``mesh``/``row_sharded``: see leaf_gather."""
+    return scores + shrinkage * leaf_gather(leaf_output, leaf_ids, mesh,
+                                            row_sharded)
 
 
 def predict_trees_binned(records, bins_t, meta: FeatureMeta,

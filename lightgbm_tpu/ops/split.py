@@ -151,6 +151,28 @@ def leaf_split_gain(sum_g, sum_h, l1, l2, max_delta_step):
     return leaf_split_gain_given_output(sum_g, sum_h, l1, l2, out)
 
 
+def _prefix_sums(contrib: jax.Array) -> jax.Array:
+    """Inclusive prefix sums of ``contrib`` [F, B, C] along the bin
+    axis as a lower-triangular matmul (prefix-sum = tril @ x): one MXU
+    pass instead of a lane-shift cumsum chain.
+
+    NOT pad-stable on every backend: a dot's accumulation order is the
+    backend's choice, and XLA:CPU (jax 0.9) picks a different
+    micro-kernel — a different K-axis grouping — as the operand shape
+    changes, so zero-padding the bin axis (the step registry's pow2
+    bin bucket) can move a prefix sum, and with it a leaf value, in
+    its last bits (observed: B 36 -> 64; 63 -> 64 happens not to). The
+    bound that holds is f32 rounding of a <= 256-term sum; see
+    docs/Design.md §5d and
+    tests/test_step_cache.py::test_geometry_bucketing_shares_across_data_shapes.
+    Whether the MXU's accumulation is pad-stable is what chip_smoke.py's
+    registry phase observes on the chip."""
+    B = contrib.shape[1]
+    tril = jnp.tril(jnp.ones((B, B), jnp.float32))
+    return jnp.einsum("bk,fkc->fbc", tril, contrib,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _candidate_tables(hist: jax.Array, sum_g, sum_h, num_data,
                       feature_mask: jax.Array, meta: FeatureMeta,
                       hp: SplitParams, can_split=True):
@@ -191,11 +213,7 @@ def _candidate_tables(hist: jax.Array, sum_g, sum_h, num_data,
     contrib_mask = (valid_bin & ~zero_bin & ~nan_bin).astype(f32)  # [F, B]
     contrib = hist * contrib_mask[:, :, None]                      # [F, B, 3]
 
-    # prefix sums as a lower-triangular matmul: one MXU pass instead
-    # of a lane-shift cumsum chain (prefix-sum = tril @ x)
-    tril = jnp.tril(jnp.ones((B, B), f32))
-    cum = jnp.einsum("bk,fkc->fbc", tril, contrib,
-                     precision=jax.lax.Precision.HIGHEST)  # [F, B, 3]
+    cum = _prefix_sums(contrib)                     # [F, B, 3]
     tot = cum[:, -1, :]                             # [F, 3]
 
     # --- dir = +1 : left accumulates from bin 0 (default right) ---------

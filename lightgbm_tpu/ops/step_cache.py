@@ -255,6 +255,8 @@ def _instrument(fn: Callable) -> Callable:
             return out
         return fn(*args)
 
+    # jit-object surface for introspection (GBDT.lower_step)
+    call.lower = getattr(fn, "lower", None)
     return call
 
 
@@ -288,7 +290,8 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
                      valid_slices: tuple, num_leaves: int,
                      grad_fn: Optional[Callable],
                      renew_alpha: Optional[float],
-                     sample_hook: Optional[Callable]) -> Callable:
+                     sample_hook: Optional[Callable],
+                     mesh=None, row_sharded: bool = False) -> Callable:
     """ONE jitted function for a full boosting iteration — the SINGLE
     step implementation (gradient -> K tree builds -> renew ->
     shrinkage -> score updates -> AddBias on the stored record) behind
@@ -310,6 +313,11 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
     One body, two callers: the stepcache parity suite
     (tests/test_step_cache.py) locks them together by construction
     instead of by a 60-line mirror.
+
+    ``mesh``/``row_sharded``: the device mesh a parallel tree learner
+    trains over, and whether iteration state is row-sharded on it —
+    the score updates' leaf-gather kernel must then run per shard
+    (ops/predict.py leaf_gather); None for the serial learner.
     """
     import jax
     import jax.numpy as jnp
@@ -387,11 +395,13 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
                 internal_value=rec.internal_value * shrink)
             # out-of-bag rows included: the partition covers ALL rows
             scores = scores.at[k].set(add_leaf_outputs(
-                scores[k], leaf_ids, rec.leaf_output, 1.0))
+                scores[k], leaf_ids, rec.leaf_output, 1.0,
+                mesh=mesh, row_sharded=row_sharded))
             for vi, (voff, vn) in enumerate(valid_slices):
                 vleaf = leaf_full[voff:voff + vn]
                 vs[vi] = vs[vi].at[k].set(add_leaf_outputs(
-                    vs[vi][k], vleaf, rec.leaf_output, 1.0))
+                    vs[vi][k], vleaf, rec.leaf_output, 1.0,
+                    mesh=mesh, row_sharded=row_sharded))
             # AddBias on the STORED record only (tree.h:151): the init
             # score already reached train/valid scores through
             # BoostFromAverage's AddScore, so the score updates above
@@ -405,9 +415,11 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
             recs.append(rec)
         return scores, tuple(vs), recs
 
-    # jit-capture: ok(grower, grad_fn, sample_hook) — the three
-    # callable seams. Registry-path callers pass callables that close
-    # only over config scalars/statics, all covered by the geometry
-    # key (obj.static_key(), _grower_cfg, learner mode); legacy
-    # callers jit per booster, so a capture is that booster's own.
+    # jit-capture: ok(grower, grad_fn, sample_hook, mesh) — the three
+    # callable seams and the training mesh (static: it only selects
+    # the leaf gather's shard_map). Registry-path callers pass
+    # callables that close only over config scalars/statics, all
+    # covered by the geometry key (obj.static_key(), _grower_cfg,
+    # learner mode, mesh device ids); legacy callers jit per booster,
+    # so a capture is that booster's own.
     return jax.jit(step, donate_argnums=(1, 2))

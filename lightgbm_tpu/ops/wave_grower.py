@@ -1071,7 +1071,15 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # the traced 6th argument (PR 5), and the step-cache geometry key
     # covers cfg + the meta signature, so a registry hit can never
     # see another booster's meta_const.
-    return jax.jit(grow) if jit else grow
+    out = jax.jit(grow) if jit else grow
+    # what this factory RESOLVED (not what the config asked for): the
+    # route, whether the fused Pallas kernel serves the wave passes and
+    # whether it runs interpreted — GBDT.device_report() reads it so a
+    # smoke run can assert the kernels were compiled, not stood in for
+    out.resolved = {"route": route, "fused_pallas": bool(use_fused),
+                    "fused_xla": bool(use_fused_xla),
+                    "interpret": bool(use_fused and fused_interpret)}
+    return out
 
 
 def apply_wave_splits(bins_t, leaf_ids, wl, new_ids, feat, tbin, dleft,

@@ -226,8 +226,7 @@ def initialize_from_config(config) -> bool:
     the live topology.
 
     MUST run before any other jax use in the process (the backend
-    client binds at first device access — the same constraint
-    ``dryrun_multichip`` documents for platform selection).
+    client binds at first device access).
     """
     world_n, rank_n, coord = _resolve_topology(config)
     _state["deadline_s"] = float(

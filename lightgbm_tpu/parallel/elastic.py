@@ -378,13 +378,16 @@ def launch_workers(spec_path: str, world: int, *,
     coordinator port. Every child gets a CLEAN platform env (CPU
     backend, ``local_devices`` virtual devices — NOT the parent's
     8-device test flag) and the fault spec is armed ONLY on
-    ``fault_rank`` (the drill's designated victim)."""
+    ``fault_rank`` (the drill's designated victim). The ranks are
+    pinned to ``JAX_PLATFORMS=cpu``: an accelerator belongs to ONE
+    process at a time, so these children never ask for it and cannot
+    collide with a parent that holds it (multi-chip training on one
+    host is one process over a mesh, models/gbdt.py)."""
     port = port or _free_port()
     procs = []
     for r in range(world):
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["LGBM_TPU_PLATFORM"] = "cpu"
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                             f"{local_devices}")
         env[cluster.ENV_COORDINATOR] = f"localhost:{port}"

@@ -47,17 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                   # jax >= 0.5
-    _shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def _shard_map(*args, **kwargs):
-        # the replication check was named check_rep before jax 0.5
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04(*args, **kwargs)
-
 from ..ops.hist_wave import wave_histogram
 from ..ops.split import (FeatureMeta, SplitResult, best_gain_per_feature,
                          find_best_split)
@@ -287,7 +276,7 @@ def make_data_parallel_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     meta_dev = FeatureMeta(*[jnp.asarray(a) for a in meta])
     meta_specs = FeatureMeta(*[P(*([None] * jnp.ndim(a)))
                                for a in meta_dev])
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         grow, mesh=mesh,
         in_specs=(P(None, AXIS), P(AXIS), P(AXIS), P(AXIS), P(None),
                   meta_specs),
@@ -308,6 +297,7 @@ def make_data_parallel_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         return jitted.lower(*(args if len(args) == 6
                               else args + (meta_dev,)))
     call.lower = lower
+    call.resolved = grow.resolved
     return call
 
 
@@ -345,7 +335,7 @@ def make_feature_parallel_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
 
     grow = make_wave_grower(cfg, meta, hist_fn=hist_fn, split_fn=split_fn,
                             jit=False)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         grow, mesh=mesh,
         in_specs=(P(None, None), P(None), P(None), P(None), P(None)),
         out_specs=(P(), P()),
@@ -402,7 +392,7 @@ def make_feature_parallel_bundled_grower(cfg: WaveGrowerConfig,
 
     grow = make_wave_grower(cfg, meta, hist_fn=hist_fn,
                             split_fn=split_fn, jit=False)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         grow, mesh=mesh,
         in_specs=(P(None, None), P(None), P(None), P(None), P(None)),
         out_specs=(P(), P()),
@@ -504,7 +494,7 @@ def make_voting_parallel_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                             reduce_fn=reduce_fn,
                             max_reduce_fn=lambda x: jax.lax.pmax(x, AXIS),
                             row_offset_fn=row_offset_fn, jit=False)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         grow, mesh=mesh,
         in_specs=(P(None, AXIS), P(AXIS), P(AXIS), P(AXIS), P(None)),
         out_specs=(P(), P(AXIS)),

@@ -1,22 +1,37 @@
 """Bounded retry with exponential backoff + jitter for transient
 failures.
 
-The transfer and ingest paths talk to a device runtime over RPC; under
-memory pressure or a busy tunnel those calls fail with *transient*
-errors (``RESOURCE_EXHAUSTED``, ``DEADLINE_EXCEEDED``, ``UNAVAILABLE``)
-that succeed moments later. This module is the one policy for
-absorbing them: retry with exponential backoff and deterministic
-jitter, give up after a bounded number of attempts, and count every
-decision in the obs registry (``retry/attempts``, ``retry/retries``,
-``retry/giveups``) so a live run's flakiness is visible in the
-Prometheus export instead of buried in logs.
+One policy for the seams that talk to something that can blip — the
+``jax.distributed`` coordination service at bootstrap
+(parallel/cluster.py), the fleet scoring daemon's HTTP socket
+(serve/client.py), and the fault-injection drills on the ingest and
+lrb window-train seams (utils/faults.py): retry with exponential
+backoff and deterministic jitter, give up after a bounded number of
+attempts, and count every decision in the obs registry
+(``retry/attempts``, ``retry/retries``, ``retry/giveups``) so a live
+run's flakiness is visible in the Prometheus export instead of buried
+in logs.
 
 Classification is conservative: only errors that *say* they are
-transient (the grpc/absl status strings above, the jax.distributed /
-DCN bootstrap strings — coordinator connect refused, barrier timeout,
-heartbeat loss — stdlib connection timeouts, or an injected
-``InjectedFault(transient=True)`` from utils/faults.py) are retried —
-a genuine bug fails fast on attempt 1.
+transient are retried — a genuine bug fails fast on attempt 1. What
+that means for a chip ATTACHED to this process (the only kind there
+is: jax runs the device in-process, no RPC sits between them), marker
+by marker:
+
+- ``RESOURCE_EXHAUSTED`` is NOT transient: from a local device it
+  means out of HBM (or a kernel over its VMEM) and is as true on the
+  fourth attempt as on the first — retrying only delays the report.
+  ``chip_smoke.py`` asserts ``retry/retries == 0``.
+- ``ABORTED`` / ``Connection reset`` / ``Socket closed`` described a
+  remote device runtime; a local one does not produce them, and a
+  coordination-service channel that drops says ``UNAVAILABLE``.
+- ``DEADLINE_EXCEEDED`` / ``UNAVAILABLE`` and the bootstrap phrases
+  below come from the coordination service's grpc channel — peers
+  still arriving, a coordinator still binding its port: worth backoff.
+- stdlib ``ConnectionError`` / ``TimeoutError`` and the two
+  http.client phrases are the fleet client's socket blips.
+- ``InjectedFault(transient=True)`` is the drills' way to exercise
+  this path on purpose.
 
 Stdlib + obs only; importing this module never touches jax.
 """
@@ -29,21 +44,18 @@ from typing import Callable, Optional
 from . import log
 from .faults import InjectedFault
 
-# substrings of transient device-runtime/RPC failures (grpc/absl status
-# names surface verbatim in XlaRuntimeError messages)
+# substrings of transient failures (grpc/absl status names surface
+# verbatim in XlaRuntimeError messages) — see the module docstring for
+# why each is here and why RESOURCE_EXHAUSTED is not
 TRANSIENT_MARKERS = (
-    "RESOURCE_EXHAUSTED",
-    "DEADLINE_EXCEEDED",
-    "UNAVAILABLE",
-    "ABORTED",
-    "Connection reset",
-    "Socket closed",
     # jax.distributed / DCN bootstrap blips (parallel/cluster.py): a
     # coordinator that is still binding its port, restarting after a
     # preemption, or mid-handshake surfaces these — worth backoff, not
     # an attempt-1 giveup. Kept SPECIFIC (full service/phrase strings),
     # so a genuine config error ("connection" in some unrelated text)
     # still fails fast.
+    "DEADLINE_EXCEEDED",
+    "UNAVAILABLE",
     "Connection refused",               # coordinator not listening yet
     "failed to connect to all addresses",   # grpc channel not up
     "Barrier timed out",                # peers still arriving

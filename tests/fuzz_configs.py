@@ -12,7 +12,7 @@ the targeted test suite samples only pointwise. ~1 min/case on one CPU
 core (XLA compiles dominate).
 """
 import os, sys, traceback
-os.environ["JAX_PLATFORMS"] = "cpu"; os.environ["LGBM_TPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 import numpy as np
 import lightgbm_tpu as lgb

@@ -36,6 +36,11 @@ TIMES = {json.dumps(c, sort_keys=True): t
 KEY = {"F": 28, "B": 64, "tier": "int8", "device": "test"}
 
 
+def _failed() -> int:
+    from lightgbm_tpu.obs import registry as obs
+    return obs.counter("autotune/candidates_failed").value
+
+
 class TestTuningCache:
     def test_roundtrip_no_retiming(self, tmp_path):
         path = str(tmp_path / "tuning.json")
@@ -107,9 +112,72 @@ class TestTuningCache:
             return TIMES[json.dumps(cand, sort_keys=True)]
 
         t = Autotuner("on", str(tmp_path / "t.json"))
+        failed0 = _failed()
         # 8192 (the true fastest) fails -> next best wins, not a crash
         assert t.best("fused_hist", KEY, CANDS, measure) == \
             {"chunk": 16384}
+        assert _failed() - failed0 == 1      # ... and it is counted
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_every_candidate_failing_is_fatal_on_tpu_only(
+            self, tmp_path, monkeypatch, backend):
+        """A rejected candidate is counted on every backend; on a TPU
+        backend it is warned about with the compiler's text and a key
+        whose EVERY candidate failed raises instead of quietly serving
+        a default nobody could compile. Off-TPU (injected timers) the
+        default is still served. The backend is steered here, through
+        the routing seam the tuner itself asks — no option exists for
+        it."""
+        from lightgbm_tpu.utils import device, log
+        monkeypatch.setattr(device, "backend_kind", lambda: backend)
+        lines = []
+        log.set_callback(lines.append)
+        try:
+            t = Autotuner("on", str(tmp_path / "t.json"))
+            failed0 = _failed()
+
+            def measure(cand):
+                raise RuntimeError("Mosaic says: scoped vmem exceeded")
+
+            if backend == "tpu":
+                with pytest.raises(log.LightGBMError,
+                                   match="every candidate"):
+                    t.best("fused_hist", KEY, CANDS, measure,
+                           default={"chunk": 16384})
+                assert sum("scoped vmem exceeded" in ln
+                           and "Warning" in ln for ln in lines) \
+                    == len(CANDS)
+            else:
+                assert t.best("fused_hist", KEY, CANDS, measure,
+                              default={"chunk": 16384}) \
+                    == {"chunk": 16384}
+            assert _failed() - failed0 == len(CANDS)
+        finally:
+            log.set_callback(None)
+
+    def test_tune_hist_chunk_rejection_is_fatal_on_tpu(
+            self, tmp_path, monkeypatch):
+        """The same rule through the seam the trainer calls
+        (tune_hist_chunk with an injected ``_measure``)."""
+        from lightgbm_tpu.utils import device, log
+        monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+        monkeypatch.setattr(autotune, "device_kind",
+                            lambda: "TPU v5 lite")
+        autotune.configure("on", str(tmp_path / "t.json"))
+        try:
+            failed0 = _failed()
+
+            def measure(cand):
+                raise RuntimeError("Mosaic rejected this tiling")
+
+            with pytest.raises(log.LightGBMError,
+                               match="every candidate"):
+                autotune.tune_hist_chunk(
+                    fused=True, F=28, B=64, W=64, precision="int8",
+                    count_proxy=True, n_rows=1 << 20, _measure=measure)
+            assert _failed() - failed0 == 4   # 4096 .. 32768
+        finally:
+            autotune.configure("on", None)
 
 
 class TestVmemPredicate:
@@ -397,18 +465,90 @@ def test_measure_median_with_sync():
     assert 0.0 < t < 10.0
 
 
-def test_ensure_compile_cache_cpu_backend_leaves_config_alone(
-        monkeypatch):
-    """The persistent compile cache auto-wires only for the TPU
-    backend (this image's jax 0.4.x CPU backend flakily segfaults
-    deserializing warm entries); on the CPU test backend the jax
-    config must come through untouched. The once-guard is reset so the
-    gate itself is exercised (earlier tests' GBDT.init already tripped
-    it, which would make this assertion vacuous)."""
+class _FakeJaxConfig:
+    """Stands in for ``jax.config`` while ensure_compile_cache runs:
+    records what the one setter would write without touching the real
+    process-wide config (no test sets the cache directory for real)."""
+
+    def __init__(self, real, cache_dir=None):
+        self._real = real
+        self.jax_compilation_cache_dir = cache_dir
+        self.updates = {}
+
+    def update(self, key, value):
+        self.updates[key] = value
+        if key == "jax_compilation_cache_dir":
+            self.jax_compilation_cache_dir = value
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
+@pytest.fixture
+def cache_rule(monkeypatch):
+    """(autotune module, fake-config factory) with ensure_compile_cache's
+    once-guard reset (earlier tests' GBDT.init tripped it)."""
     import jax
 
     from lightgbm_tpu.ops import autotune as at
     monkeypatch.setattr(at, "_compile_cache_done", False)
-    before = jax.config.jax_compilation_cache_dir
+
+    def install(cache_dir=None):
+        fake = _FakeJaxConfig(jax.config, cache_dir)
+        monkeypatch.setattr(jax, "config", fake)
+        return fake
+
+    return at, install
+
+
+def test_compile_cache_cpu_backend_leaves_config_alone(cache_rule):
+    """The persistent compile cache auto-wires only for accelerator
+    backends; on the CPU test backend the jax config comes through
+    untouched (mode 0 likewise), and the decision is not latched — a
+    later booster may still opt in with tpu_compile_cache=1."""
+    at, install = cache_rule
+    cfg = install()
     at.ensure_compile_cache()
-    assert jax.config.jax_compilation_cache_dir == before
+    at.ensure_compile_cache(mode=0)
+    assert cfg.updates == {}
+    assert at._compile_cache_done is False
+    at.ensure_compile_cache(mode=1)
+    assert cfg.jax_compilation_cache_dir == at.default_cache_dir()
+
+
+def test_compile_cache_operator_placement_wins(cache_rule, monkeypatch,
+                                               tmp_path):
+    """A cache directory the operator placed — JAX_COMPILATION_CACHE_DIR,
+    which jax reads into this very config value — is used as is and
+    NOTHING else is set, even on a TPU backend; the tuning cache then
+    sits inside it."""
+    from lightgbm_tpu.utils import device
+    at, install = cache_rule
+    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+    placed = str(tmp_path / "operator_cache")
+    cfg = install(placed)
+    at.ensure_compile_cache()
+    assert cfg.updates == {}
+    assert cfg.jax_compilation_cache_dir == placed
+    assert at.default_tuning_cache_path().startswith(placed + "/")
+
+
+def test_compile_cache_unset_goes_to_the_fixed_checkout_path(
+        cache_rule, monkeypatch):
+    """Unset, on a TPU backend: ONE fixed directory inside the checkout
+    (git-ignored) — never a temp name, pid or time, because the path is
+    part of the cache key — with the tuning cache inside it."""
+    import os
+
+    from lightgbm_tpu.utils import device
+    at, install = cache_rule
+    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+    cfg = install()
+    at.ensure_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".lgbm_tpu_cache")
+    assert cfg.jax_compilation_cache_dir == want
+    assert at.default_cache_dir() == want
+    assert os.path.dirname(at.default_tuning_cache_path()) == want
+    with open(os.path.join(repo, ".gitignore")) as fh:
+        assert ".lgbm_tpu_cache/" in fh.read().split()

@@ -53,15 +53,30 @@ def test_trajectory_orders_numerically(tmp_path):
     assert names == ["BENCH_r2.json", "BENCH_r9.json", "BENCH_r10.json"]
 
 
-def test_repo_trajectory_loads_and_self_passes():
-    """The repo's own BENCH_r0x files normalize, and the latest point
-    compared against itself passes (the tool's identity check)."""
-    points = cbr.trajectory(REPO)
-    assert len(points) >= 2, "BENCH_r0x trajectory missing from repo"
+def test_repo_trajectory_loads_and_self_passes(tmp_path):
+    """A trajectory of driver-shaped wrapper records ({"parsed": ...,
+    "tail": ...} — the shape the repo's round-1..5 records had)
+    normalizes, and the latest point compared against itself passes
+    (the tool's identity check). Written here: the repo no longer
+    carries on-chip records from a retired backend."""
+    metric = ("HIGGS-class GBDT training throughput (11000000 rows x "
+              "28 feat, 255 leaves, 63 bins, 500 iters, 1 chip(s))")
+    for n, (value, tr, te) in enumerate(
+            [(21.3, 0.9310, 0.9262), (48.954, 0.93202, 0.92726)], 1):
+        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps({
+            "n": n, "rc": 0,
+            "tail": f"# compile+iter0: 18.8s\n# 500 iters in 112.1s  "
+                    f"train-AUC={tr}  test-AUC={te}  (holdout predict "
+                    f"500000 rows x 500 trees: 16.5s)\n",
+            "parsed": {"metric": metric, "value": value,
+                       "unit": "M row-iters/s", "vs_baseline": 2.1}}))
+    points = cbr.trajectory(str(tmp_path))
+    assert len(points) == 2
     latest = cbr.load_bench(points[-1])
     assert not cbr.check_schema(latest)
-    assert latest.get("test_auc") is not None, \
-        "tail AUC recovery failed on the real trajectory"
+    assert latest["value"] == 48.954
+    assert latest.get("test_auc") == pytest.approx(0.92726), \
+        "tail AUC recovery failed on the wrapper trajectory"
     assert cbr.compare(latest, latest) == []
 
 
@@ -1064,31 +1079,22 @@ def test_multichip_r06_artifact_passes_gate():
     assert doc["world_sizes"] == {"train": 2, "resume": 1}
 
 
-# -- end-to-end (slow): a real quick bench through the gate ------------------
+# -- the device gate ---------------------------------------------------------
 
-@pytest.mark.slow
-def test_quick_bench_json_schema_end_to_end(tmp_path):
-    """``bench.py --quick`` emits a JSON line whose predict-latency
-    p50/p95/p99 come from the log-bucketed histogram, and the gate's
-    schema check accepts it (a quick run is NOT comparable to the
-    full-size trajectory — that is exactly what --schema-only is
-    for)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--quick"],
-        capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = proc.stdout.strip().splitlines()[-1]
-    doc = json.loads(line)
-    lat = doc["predict_latency"]
-    assert lat["batches"] > 10
-    for q in ("p50_ms", "p95_ms", "p99_ms"):
-        assert lat[q] > 0
-    assert lat["p50_ms"] <= lat["p95_ms"] <= lat["p99_ms"]
-    assert 0.5 < doc["test_auc"] <= 1.0
-    fresh = tmp_path / "fresh.json"
-    fresh.write_text(line)
-    assert cbr.main([str(fresh), "--schema-only"]) == 0
-    # and the full-size gate refuses the shape mismatch instead of
-    # comparing apples to oranges
-    assert cbr.main([str(fresh), "--baseline-dir", REPO]) == 2
+def test_bench_refuses_to_run_without_a_tpu():
+    """bench.py prints device metrics: on a process whose jax backend
+    is not a TPU it must exit non-zero BEFORE generating data or
+    training anything, and print no JSON result line (a CPU container
+    once wrote a whole trajectory point under device-metric names).
+    The --sparse/--rank parents stay off jax; their route children hit
+    the same gate."""
+    for extra in ([], ["--sparse-route", "csr"]):
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "bench.py"), "--quick",
+             *extra],
+            capture_output=True, text=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        assert proc.returncode != 0
+        assert "refusing to run" in proc.stderr
+        assert "not a TPU" in proc.stderr
+        assert proc.stdout.strip() == ""

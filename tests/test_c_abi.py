@@ -165,8 +165,7 @@ def test_fork_driver_flow_links_and_runs(capi_so, tmp_path):
     import site
     pypath = ":".join([str(REPO)] + site.getsitepackages())
     env = {"PYTHONPATH": pypath, "PATH": "/usr/bin:/bin",
-           "JAX_PLATFORMS": "cpu", "LGBM_TPU_PLATFORM": "cpu",
-           "HOME": "/tmp"}
+           "JAX_PLATFORMS": "cpu", "HOME": "/tmp"}
     run = subprocess.run([str(exe), str(tmp_path)], env=env,
                          capture_output=True, text=True, timeout=560)
     assert "C-ABI-OK" in run.stdout, (run.stdout, run.stderr)

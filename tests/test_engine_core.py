@@ -226,20 +226,24 @@ class TestFeatureImportance:
 
 def test_device_type_routing():
     """Explicit device_type routes the framework's device selection
-    (the reference's CPU/GPU switch); the operator env pin is never
-    touched, unknown values fatal, tpu clears a prior cpu routing."""
-    import os
+    (the reference's CPU/GPU switch): cpu installs the host routing,
+    an accelerator request clears it and is FATAL on a process whose
+    jax backend is the CPU (never a quiet CPU run under a TPU request),
+    unknown values fatal."""
     import pytest as _pytest
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.utils import device
     from lightgbm_tpu.utils.log import LightGBMError
-    before = os.environ.get("LGBM_TPU_PLATFORM")
     try:
         Config().set({"device_type": "cpu"})
         assert device._config_platform == "cpu"
-        assert os.environ.get("LGBM_TPU_PLATFORM") == before
-        Config().set({"device_type": "tpu"})
-        assert device._config_platform is None
+        for accel in ("tpu", "gpu", "cuda"):
+            device.set_config_platform("cpu")
+            with _pytest.raises(LightGBMError, match="no accelerator"):
+                Config().set({"device_type": accel})
+            # the cpu routing is cleared BEFORE the refusal: the request
+            # is never silently served by the previous booster's routing
+            assert device._config_platform is None
         with _pytest.raises(LightGBMError):
             Config().set({"device_type": "banana"})
     finally:

@@ -301,6 +301,55 @@ class TestReporting:
         assert bst.num_devices == len(get_devices())
 
 
+    @pytest.mark.parametrize("learner", ["serial", "data"])
+    def test_device_report_says_what_the_booster_resolved_to(
+            self, learner):
+        """Booster.device_report() (what chip_smoke.py asserts on): the
+        route and tier from the booster's own state, the bin matrix's
+        shards one per mesh device for the data learner, and
+        GBDT.lower_step() exposing the compiled step's collective."""
+        import lightgbm_tpu as lgb
+        X, y = make_binary(1280, seed=12)
+        bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                         "max_bin": 31, "tree_learner": learner,
+                         "tpu_ingest": 1, "verbosity": -1},
+                        lgb.Dataset(X, label=y), num_boost_round=2,
+                        keep_training_booster=True)
+        rep = bst.device_report()
+        D = len(get_devices()) if learner == "data" else 1
+        assert rep["platform"] == "cpu" and rep["device_kind"] == "cpu"
+        assert (rep["learner_mode"], rep["num_devices"]) == (learner, D)
+        # the CPU route: fused XLA twin, no Pallas, nothing interpreted
+        assert rep["route"] == "fused-xla" and rep["fused_xla"]
+        assert not rep["fused_pallas"] and not rep["interpret"]
+        assert rep["precision"] == "highest" and rep["device_ingest"]
+        assert rep["num_data"] == 1280 <= rep["score_rows"]
+        shards = rep["bins_shards"]
+        assert len(shards) == D == len({dev for dev, _ in shards})
+        assert all(shape == (rep["bins_shape"][0],
+                             rep["bins_shape"][1] // D)
+                   for _, shape in shards)
+        hlo = bst._gbdt.lower_step().compile().as_text()
+        assert ("all-reduce" in hlo) == (learner == "data")
+
+    def test_one_device_data_learner_fallback_is_counted(
+            self, monkeypatch):
+        """tree_learner=data on a mesh of one keeps falling back to
+        serial — but visibly: learner_mode/num_devices say so and
+        learner/serial_fallbacks counts it (a multi-chip smoke fails on
+        it instead of passing as a one-chip run)."""
+        from lightgbm_tpu.obs import registry as obs
+        from lightgbm_tpu.parallel import learners
+        monkeypatch.setattr(learners, "training_mesh", lambda cfg: None)
+        before = obs.counter("learner/serial_fallbacks").value
+        X, y = make_binary(640, seed=13)
+        g = fit_gbdt(X, y, {"objective": "binary",
+                            "tree_learner": "data"}, num_round=1)
+        assert (g.learner_mode, g.num_devices) == ("serial", 1)
+        assert obs.counter("learner/serial_fallbacks").value \
+            == before + 1
+
+
 class TestConfigFallback:
     def test_unknown_tree_learner_warns_to_serial(self):
         cfg = Config().set({"tree_learner": "bogus"})

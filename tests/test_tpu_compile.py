@@ -1,0 +1,338 @@
+"""Ask the TPU's own compiler, without the chip: AOT-compile the Pallas
+hot-path kernels at real widths for a DESCRIBED v5e.
+
+Interpret mode (the rest of the suite) proves the kernels' arithmetic;
+it cannot vouch for Mosaic legality — slices not aligned to the tiling,
+more VMEM than a kernel may use, ops the TPU lowering lacks. The TPU
+compiler is installed here and compiles for a topology that is
+described, not attached (on-chip-measurement guide §2), so these cases
+guard every later PR at no chip time. Nothing runs: a pass says "the
+compiler accepts it", never "it is right" or "it is fast" —
+``chip_smoke.py`` is the run on the chip.
+
+Widths are the deployed ones: HIGGS (28 features -> 32 with the step
+registry's mult-of-8 pad, 63 bins -> 64, 255 leaves, the wave width of
+each tier) and the LRB window model (53 -> 56 features, 255 bins ->
+256, 31 leaves). Row counts and chunks are cut — compile time grows
+with the chunk, legality does not change with the row count. The edge
+shapes of the retired ``tests/tpu_shape_sweep.py`` (F=1, 4-bin,
+odd-F packed4, multiclass, sub-chunk N) ride as further cases.
+
+The topology is described ONLY inside the module-scoped fixture below:
+never at import time and never from a child process — one process at a
+time may load the TPU library, and every xdist worker imports this
+file.
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """ShapeDtypeStruct factory placed on ONE described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+
+def _mosaic(compiled) -> int:
+    """Number of Mosaic kernels the compiled program carries."""
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_described_chip_reports_the_kind_the_vmem_table_knows(topo):
+    """The VMEM limit/budget (ops/autotune.py) are fractions of a
+    capacity keyed by the string the chip itself reports — a v5e says
+    'TPU v5 lite', not 'TPU v5e' — and an unknown TPU kind is an
+    error, not a v5e."""
+    from lightgbm_tpu.ops import autotune
+    kind = topo.devices[0].device_kind
+    assert kind in autotune.TPU_VMEM_CAPACITY_BYTES, kind
+    cap = autotune.TPU_VMEM_CAPACITY_BYTES[kind]
+    assert (autotune.PALLAS_VMEM_BUDGET_BYTES
+            < autotune.PALLAS_VMEM_LIMIT_BYTES <= cap)
+
+
+# (F, B, W, precision, variant, count_proxy, packed4, any_cat, N, chunk)
+_HIST_CASES = {
+    # HIGGS widths, every tier the trainer can resolve to
+    "higgs-int8-proxy-W64": (32, 64, 64, "int8", None, True, False,
+                             False, 1 << 17, 4096),
+    "higgs-int8-3ch-W40": (32, 64, 40, "int8", None, False, False,
+                           False, 1 << 17, 4096),
+    "higgs-hilo5-W24": (32, 64, 24, "highest", "hilo5", False, False,
+                        False, 1 << 17, 4096),
+    "higgs-hilo4-W32": (32, 64, 32, "highest", "hilo4", False, False,
+                        False, 1 << 17, 4096),
+    "higgs-hilo3-W40": (32, 64, 40, "highest", "hilo3", False, False,
+                        False, 1 << 17, 4096),
+    # LRB window model: 53 features (+3 pad), 255 bins, 31 leaves
+    "lrb-hilo4-W30": (56, 256, 30, "highest", "hilo4", False, False,
+                      False, 1 << 14, 1024),
+    # edge shapes (the retired on-chip shape sweep)
+    "edge-F1": (1, 64, 14, "int8", None, True, False, False, 8192, 4096),
+    "edge-4bin-packed4-oddF": (27, 16, 64, "int8", None, True, True,
+                               False, 1 << 15, 4096),
+    "edge-4bin-unpacked": (6, 4, 14, "int8", None, True, False, False,
+                           8192, 4096),
+    "edge-categorical": (8, 64, 14, "highest", "hilo5", False, False,
+                         True, 8192, 4096),
+    "edge-sub-chunk-N": (8, 64, 14, "highest", "hilo4", False, False,
+                         False, 900, 4096),
+}
+
+
+def _hist_args(spec, case, fused):
+    F, B, W, prec, variant, proxy, packed4, any_cat, N, chunk = case
+    f_rows = (F + 1) // 2 if packed4 else F
+    kw = dict(num_bins=B, chunk=chunk, precision=prec,
+              gh_scale=(1.0, 1.0) if prec == "int8" else None,
+              count_proxy=proxy, packed4=packed4,
+              num_features=F if packed4 else None,
+              variant=variant or "hilo5")
+    rows = (spec((f_rows, N), jnp.uint8), spec((N,), jnp.float32),
+            spec((N,), jnp.float32))
+    if fused:
+        kw["any_cat"] = any_cat
+        return rows + (spec((N,), jnp.float32), spec((N,), jnp.int32),
+                       spec((18, W), jnp.int32)), kw
+    return rows + (spec((N,), jnp.int32), spec((W,), jnp.int32)), kw
+
+
+@pytest.mark.parametrize("name", sorted(_HIST_CASES))
+def test_fused_partition_histogram_kernel_compiles(spec, name):
+    from lightgbm_tpu.ops.hist_wave import \
+        fused_partition_histogram_pallas
+    args, kw = _hist_args(spec, _HIST_CASES[name], fused=True)
+    compiled = jax.jit(functools.partial(
+        fused_partition_histogram_pallas, **kw)).lower(*args).compile()
+    assert _mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize("name", ["higgs-int8-proxy-W64",
+                                  "higgs-hilo4-W32", "lrb-hilo4-W30",
+                                  "edge-4bin-packed4-oddF"])
+def test_wave_histogram_kernel_compiles(spec, name):
+    """The partition-free wave kernel: every tree's root pass."""
+    from lightgbm_tpu.ops.hist_wave import wave_histogram_pallas
+    args, kw = _hist_args(spec, _HIST_CASES[name], fused=False)
+    compiled = jax.jit(functools.partial(
+        wave_histogram_pallas, **kw)).lower(*args).compile()
+    assert _mosaic(compiled) == 1
+
+
+def test_largest_offered_chunk_is_priced_inside_what_compiles(spec):
+    """The tuner's VMEM pricing against the compiler: the LARGEST
+    chunk hist_chunk_candidates offers a proxy-tier geometry (priced
+    under the 72 MB budget) compiles under the 100 MB limit the
+    kernels ask for. F is cut to 8 — compile time grows with the
+    feature groups, the per-chunk working set barely does; the full
+    candidate grid at F=28 (and the 65536-row chunk the pricing
+    refuses) was compiled once in the PR-22 rehearsal."""
+    from lightgbm_tpu.ops import autotune
+    from lightgbm_tpu.ops.hist_wave import \
+        fused_partition_histogram_pallas
+    cands = autotune.hist_chunk_candidates(
+        F=8, B=64, W=64, fused=True, int8=True, count_proxy=True,
+        n_rows=1 << 20)
+    top = max(c["chunk"] for c in cands)
+    assert top == 32768
+    case = (8, 64, 64, "int8", None, True, False, False, 1 << 16, top)
+    args, kw = _hist_args(spec, case, fused=True)
+    compiled = jax.jit(functools.partial(
+        fused_partition_histogram_pallas, **kw)).lower(*args).compile()
+    assert _mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize("num_leaves", [255, 31])
+def test_leaf_gather_kernel_compiles(spec, num_leaves):
+    from lightgbm_tpu.ops.predict import leaf_gather_pallas
+    compiled = leaf_gather_pallas.lower(
+        spec((num_leaves,), jnp.float32),
+        spec((1 << 20,), jnp.int32)).compile()
+    assert _mosaic(compiled) == 1
+
+
+# (F, per-feature table width, trees, leaves, classes)
+_FOREST_CASES = {
+    "higgs-500x255": (28, 72, 500, 255, 1),
+    "lrb-50x31": (53, 96, 50, 31, 1),
+    "edge-multiclass-K3": (5, 64, 12, 15, 3),
+}
+
+
+@pytest.mark.parametrize("from_x", [False, True],
+                         ids=["codes", "from_x"])
+@pytest.mark.parametrize("name", sorted(_FOREST_CASES))
+def test_forest_kernel_compiles_at_the_tiles_the_guard_picks(
+        spec, name, from_x):
+    """The fused forest kernel at the stack shapes
+    ``_device_arrays_pallas`` produces and the tree chunk ``_pallas_tc``
+    picks for them (TC = 16 at the bench shape), both entry points:
+    pre-binned codes and the device-binning ``forest_predict_from_x``."""
+    from lightgbm_tpu.ops.stacked_predict import (
+        StackedModel, forest_predict_from_x, forest_predict_pallas)
+    F, per, T, L, K = _FOREST_CASES[name]
+    row_tile, N = 512, 1 << 14
+    offs = tuple(range(0, F * per + 1, per))
+    sm = StackedModel.__new__(StackedModel)
+    sm._S, sm._L, sm._Wtot, sm.num_class = L - 1, L, F * per, K
+    sm._offsets = np.asarray(offs)
+    tc = min(sm._pallas_tc(row_tile), T)
+    assert tc == min(16, T), "VMEM guard no longer admits the bench tile"
+    Sp, Lp = -(-(L - 1) // 128) * 128, -(-L // 128) * 128
+    steps = -(-T // tc)
+    stacks = (spec((steps, F * per, tc * Sp), jnp.int8),
+              spec((steps, tc, Sp, Lp), jnp.int8),
+              spec((steps, tc, Lp), jnp.int32),
+              spec((steps, tc, Lp), jnp.float32),
+              spec((steps, tc, K), jnp.float32))
+    if from_x:
+        lowered = forest_predict_from_x.lower(
+            spec((N, F), jnp.float32), spec((F, per - 2), jnp.float32),
+            spec((F,), jnp.int32), spec((F,), jnp.int32), *stacks,
+            offsets=offs, row_tile=row_tile)
+    else:
+        lowered = forest_predict_pallas.lower(
+            spec((F, N), jnp.int32), *stacks, offsets=offs,
+            row_tile=row_tile)
+    assert _mosaic(lowered.compile()) == 1
+
+
+def _step_under_test(monkeypatch, tier, mesh=None):
+    """(jitted step, grower config pieces) of ops/step_cache.py
+    build_train_step at HIGGS widths. The grower factory asks
+    utils/device which backend it is on (it would take its interpret
+    branch on this CPU host), so the test steers that — here, not
+    through an option of the program."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.ops import autotune, step_cache
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+    from lightgbm_tpu.ops.wave_grower import (WaveGrowerConfig,
+                                              make_wave_grower)
+    from lightgbm_tpu.parallel.learners import make_data_parallel_grower
+    from lightgbm_tpu.utils import device
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+
+    N, nvalid, F, B, L = 1 << 17, 1 << 14, 32, 64, 255
+    if tier == "proxy":
+        gcfg = WaveGrowerConfig(
+            num_leaves=L, num_bins=B, wave_size=64, chunk=4096,
+            precision="int8", count_proxy=True, route="pallas-tpu",
+            quant_psum=mesh is not None,
+            hp=SplitParams(has_cat=False, count_lb=True))
+    else:
+        gcfg = WaveGrowerConfig(
+            num_leaves=L, num_bins=B,
+            wave_size=autotune.EXACT_TIER_CAPS[tier], chunk=4096,
+            precision="highest", exact_variant=tier,
+            route="pallas-tpu", hp=SplitParams(has_cat=False))
+    meta = FeatureMeta(
+        num_bin=np.full(F, B, np.int32),
+        missing_type=np.zeros(F, np.int32),
+        default_bin=np.zeros(F, np.int32),
+        monotone=np.zeros(F, np.int32),
+        penalty=np.ones(F, np.float32),
+        is_cat=np.zeros(F, np.int32))
+    grower = (make_wave_grower(gcfg, meta) if mesh is None
+              else make_data_parallel_grower(gcfg, meta, mesh))
+    assert grower.resolved == {"route": "pallas-tpu",
+                               "fused_pallas": True, "fused_xla": False,
+                               "interpret": False}
+    obj = create_objective("binary", Config().set(
+        {"objective": "binary"}))
+    obj.init(Metadata(label=(np.arange(N) % 2).astype(np.float32)), N)
+    step = step_cache.build_train_step(
+        grower=grower, K=1, n_score=N, n_total=N + nvalid,
+        valid_slices=((N, nvalid),), num_leaves=L,
+        grad_fn=obj.gradient_builder(), renew_alpha=None,
+        sample_hook=None, mesh=mesh, row_sharded=mesh is not None)
+    return step, (N, nvalid, F), meta, obj.gradient_aux()
+
+
+def _step_args(spec_rows, spec_rep, dims, meta, obj_aux):
+    """The step's argument specs: ``spec_rows`` places arrays whose
+    LAST axis is the row axis, ``spec_rep`` everything else."""
+    from lightgbm_tpu.ops.split import FeatureMeta
+    N, nvalid, F = dims
+
+    def like(a):
+        a = np.asarray(a)
+        rows = a.ndim and a.shape[-1] == N
+        return (spec_rows if rows else spec_rep)(a.shape, a.dtype)
+
+    return (spec_rows((F, N + nvalid), jnp.uint8),
+            spec_rows((1, N), jnp.float32),
+            (spec_rows((1, nvalid), jnp.float32),),
+            spec_rows((N + nvalid,), jnp.float32),
+            spec_rep((F,), jnp.bool_), spec_rep((), jnp.float32),
+            spec_rep((1,), jnp.float32), spec_rep((1, 1), jnp.float32),
+            spec_rep((1, 1), jnp.float32), spec_rep((2,), jnp.uint32),
+            spec_rows((N,), jnp.bool_),
+            FeatureMeta(*[like(a) for a in meta]),
+            {"obj": jax.tree_util.tree_map(like, obj_aux),
+             "renew": None})
+
+
+@pytest.mark.parametrize("tier", ["proxy", "hilo4"])
+def test_whole_training_step_compiles(spec, monkeypatch, tier):
+    """ops/step_cache.py build_train_step end to end — gradients, the
+    wave grower (root wave kernel + fused kernel in the while loop),
+    leaf gathers, score updates — as ONE program for the described
+    chip."""
+    step, dims, meta, aux = _step_under_test(monkeypatch, tier)
+    compiled = step.lower(*_step_args(spec, spec, dims, meta,
+                                      aux)).compile()
+    # root wave kernel + fused kernel + the train/valid leaf gathers
+    assert _mosaic(compiled) == 4
+
+
+@pytest.mark.parametrize("tier", ["proxy", "hilo4"])
+def test_data_parallel_step_compiles_over_the_four_chip_mesh(
+        topo, monkeypatch, tier):
+    """tree_learner=data over all four chips of the described host: the
+    same step with row-sharded state. GSPMD cannot partition a Mosaic
+    kernel, so EVERY kernel must sit under a shard_map — the grower's
+    do; the score updates' leaf gather did not until PR 22 (this
+    compile refused the step: "Mosaic kernels cannot be automatically
+    partitioned"). The histogram psum must come out as an
+    all-reduce."""
+    from lightgbm_tpu.parallel.learners import AXIS
+    mesh = Mesh(np.asarray(topo.devices), (AXIS,))
+
+    def placed(*axes):
+        return lambda shape, dt: jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P(
+                *([None] * (len(shape) - len(axes)) + list(axes)))))
+
+    step, dims, meta, aux = _step_under_test(monkeypatch, tier, mesh)
+    compiled = step.lower(*_step_args(placed(AXIS), placed(), dims,
+                                      meta, aux)).compile()
+    assert _mosaic(compiled) == 4
+    assert "all-reduce" in compiled.as_text()
+    # each chip holds a quarter of the row-sharded state
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    N, nvalid, F = dims
+    assert per_chip < (F * (N + nvalid)) // 2

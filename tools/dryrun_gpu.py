@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""GPU-backend dry run — the Triton twin of ``dryrun_multichip``.
+"""GPU-backend dry run — the Triton tier's counterpart of ``chip_smoke.py``.
 
 Two phases, each recorded in the one-line verdict so the artifact
 cannot drift from the test suite:
@@ -25,7 +25,6 @@ Exit 0 = every phase that could run passed; skips are not failures.
 import os
 import subprocess
 import sys
-import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,8 +58,9 @@ def _gpu_smoke() -> str:
     from lightgbm_tpu.objectives import create_objective
     from lightgbm_tpu.ops import autotune, step_cache
 
-    cache_dir = tempfile.mkdtemp(prefix="lgbm_tpu_gpu_cache_")
-    autotune.ensure_compile_cache(cache_dir)   # auto-on for GPU
+    import jax
+    autotune.ensure_compile_cache()            # auto-on for GPU
+    cache_dir = jax.config.jax_compilation_cache_dir
 
     r = np.random.default_rng(0)
     X = r.normal(size=(4096, 8))
