@@ -211,9 +211,9 @@ class TpuDataset:
         elif mappers is not None:
             self._set_mappers(mappers)
         else:
-            with timing.phase("binning/find_bins"):
+            with timing.phase("binning/find_bins", mem_peak=True):
                 self._construct_mappers(X, set(categorical))
-        with timing.phase("binning/bin_matrix") as ph:
+        with timing.phase("binning/bin_matrix", mem_peak=True) as ph:
             self._bin_matrix(X, efb_possible=(mappers is None
                                               and reference is None),
                              ring=ring)
@@ -282,7 +282,7 @@ class TpuDataset:
         elif mappers is not None:
             self._set_mappers(mappers)
         else:
-            with timing.phase("binning/find_bins"):
+            with timing.phase("binning/find_bins", mem_peak=True):
                 self._set_mappers(sp.find_column_mappers_sparse(
                     sm, cfg, set(categorical)))
         self.sparse_nnz = sm.nnz
@@ -295,7 +295,7 @@ class TpuDataset:
         keep_coords = (sp.want_coords(cfg, sm.density)
                        and reference is None)
         efb_possible = mappers is None and reference is None
-        with timing.phase("binning/bin_matrix") as ph:
+        with timing.phase("binning/bin_matrix", mem_peak=True) as ph:
             self._bin_sparse(sm, keep_coords, efb_possible)
             if self.bins_t_dev is not None:
                 ph.watch(self.bins_t_dev)

@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import threading
 from typing import List, Optional, Sequence
 
@@ -187,12 +188,20 @@ class PrefetchError(RuntimeError):
 
 
 def prefetch(thunks, depth: int = 2, what: str = "chunk",
-             policy=None):
+             policy=None,
+             wait_span: Optional[str] = "ingest/prefetch_wait"):
     """Evaluate an iterator of zero-arg callables on ONE worker thread
     with a bounded lookahead, yielding results in order — the host
     half of the double buffer: while the device chews on chunk k, the
     worker slices/keys chunk k+1. One thread is deliberate: host prep
     is memory-bandwidth bound and the results must stay ordered.
+
+    Spans: the worker's spans name the span open here when their thunk
+    was queued as their ``cause`` (obs/trace.carry), and the consumer's
+    wait for the worker is a ``wait_span`` span — its timer against the
+    worker's own (``ingest/prep_chunk``) says which side sets the pace.
+    A caller that is not the ingest passes ``wait_span=None`` and emits
+    none: the timer is the ingest's alone.
 
     Fault tolerance: each thunk runs under the bounded-backoff retry
     policy (utils/retry.py; ``policy`` — e.g. the DeviceBinner's
@@ -218,7 +227,7 @@ def prefetch(thunks, depth: int = 2, what: str = "chunk",
             idx = submitted
             submitted += 1
             q.append((idx, ex.submit(
-                retry.call, thunk, what=f"{what} {idx}",
+                trace.carry(retry.call), thunk, what=f"{what} {idx}",
                 policy=policy)))
             return True
 
@@ -230,7 +239,10 @@ def prefetch(thunks, depth: int = 2, what: str = "chunk",
                 idx, fut = q.popleft()
                 submit()
                 try:
-                    yield fut.result()
+                    with (trace.span(wait_span, cat="ingest") if wait_span
+                          else contextlib.nullcontext()):
+                        res = fut.result()
+                    yield res
                 except Exception as e:  # noqa: BLE001 — annotate+stop
                     raise PrefetchError(
                         f"{what} {idx} failed after retries "

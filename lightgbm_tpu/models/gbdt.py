@@ -235,7 +235,7 @@ class GBDT:
                 log.info("4-bit packed bins: %.1f MB HBM "
                          "(vs %.1f MB unpacked)",
                          bins_t.nbytes / 1e6, 2 * bins_t.nbytes / 1e6)
-        with timing.phase("init/upload_bins") as ph:
+        with timing.phase("init/upload_bins", mem_peak=True) as ph:
             # grower-facing matrix: train rows (+ alignment) with every
             # valid set's rows appended as weight-0 passengers (see
             # _rebuild_grower_bins); no valids yet at init. The train
@@ -296,7 +296,6 @@ class GBDT:
         rv = np.zeros(self._n_score, bool)
         rv[:self._n] = True
         self._rvalid_dev = self._place_step_rows(rv)
-        self._step_dispatched = False
 
     def _setup_grower(self):
         cfg = self.config
@@ -1587,20 +1586,43 @@ class GBDT:
         a periodic host check (every ``tpu_stop_check_interval``
         iterations).
         """
-        from ..obs import trace
-        tracer = trace.active()
-        if tracer is not None:
-            # iteration span at the single choke point EVERY driver
-            # passes through (gbdt.train, engine/Booster.update, the
-            # capi/lrb per-window loop, bench) — dispatch-issue wall,
-            # like the phase clocks; queued device time drains in the
-            # periodic queue_drain spans
-            with tracer.span("iteration", cat="iteration",
-                             args={"it": self.iter_ + 1}):
-                return self._train_one_iter_inner(grad, hess)
-        return self._train_one_iter_inner(grad, hess)
+        # iteration span at the single choke point EVERY driver passes
+        # through (gbdt.train, engine/Booster.update, the capi/lrb
+        # per-window loop, bench) — dispatch-issue wall, like the phase
+        # clocks; queued device time drains in the periodic
+        # queue_drain spans. Its children cover the whole body, so its
+        # self time is this function's own Python.
+        with timing.phase("train/iteration", cat="iteration",
+                          args={"it": self.iter_ + 1}):
+            return self._train_one_iter_inner(grad, hess)
 
     def _train_one_iter_inner(self, grad, hess) -> bool:
+        with timing.phase("train/prepare"):
+            step, args, init_scores = self._prepare_iteration(grad, hess)
+        with timing.phase("train/step_dispatch"):
+            self._scores, new_valids, recs = step(*args)
+        with timing.phase("train/record"):
+            self._record_iteration(new_valids, recs, init_scores)
+        sync_iv = self._dispatch_sync_interval
+        if sync_iv > 0 and self.iter_ % sync_iv == 0:
+            # drain the dispatch queue with ONE scalar readback, so
+            # async dispatch never runs more than sync_iv iterations
+            # ahead of the device (config.tpu_dispatch_sync_interval:
+            # introduced for a retired backend, not re-checked on the
+            # in-process chip). The readback — not block_until_ready —
+            # is kept for the same reason: it is ordered behind every
+            # queued step on any backend.
+            with timing.phase("train/queue_drain"):
+                np.asarray(recs[-1].num_leaves)
+        if self.iter_ % self._stop_check_interval == 0:
+            with timing.phase("train/stop_check"):
+                return self._check_stop()
+        return False
+
+    def _prepare_iteration(self, grad, hess):
+        """Host half of an iteration before the dispatch: init scores,
+        bagging and feature masks, bias, the step and its PRNG key.
+        -> (step, its arguments, init_scores)."""
         from ..parallel import cluster
         if cluster.is_multiprocess():
             # progress stamp for the no-hang watchdog
@@ -1658,26 +1680,16 @@ class GBDT:
             key = jax.random.PRNGKey(self._hook_rng.integers(1, 2**31))
         else:
             key = self._dummy_key
-        first_dispatch = not getattr(self, "_step_dispatched", True)
-        if first_dispatch:
-            import time as _time
-            t0 = _time.monotonic()
-        with timing.phase("train/step_dispatch"):
-            self._scores, new_valids, recs = step(
-                self._step_bins(),
-                self._scores, tuple(self._valid_scores), mask, fmask,
-                jnp.float32(self.shrinkage_rate), init_bias, g_in, h_in,
-                key)
-        if first_dispatch:
-            # per-booster compile span: the first dispatch pays
-            # trace+compile on a registry miss and ~nothing on a hit —
-            # the spread of this timer across boosters IS the
-            # amortization the step cache buys (run reports pick the
-            # registry totals up via meta.step_cache)
-            self._step_dispatched = True
-            from ..obs import registry as obs
-            obs.timer("step_cache/first_step_s").add(
-                _time.monotonic() - t0)
+        return step, (
+            self._step_bins(),
+            self._scores, tuple(self._valid_scores), mask, fmask,
+            jnp.float32(self.shrinkage_rate), init_bias, g_in, h_in,
+            key), init_scores
+
+    def _record_iteration(self, new_valids, recs, init_scores) -> None:
+        """Host half after the dispatch: keep the step's (still
+        in-flight) outputs as this iteration's records."""
+        first_iteration = not self.models
         self._valid_scores = list(new_valids)
         for k, rec in enumerate(recs):
             shrinkage_for_file = self.shrinkage_rate
@@ -1686,23 +1698,8 @@ class GBDT:
             self.records.append(rec)
             self.models.append(None)
             self._tree_shrinkage.append(shrinkage_for_file)
-
         self.iter_ += 1
         self._bump_model_gen()
-        sync_iv = self._dispatch_sync_interval
-        if sync_iv > 0 and self.iter_ % sync_iv == 0:
-            # drain the dispatch queue with ONE scalar readback, so
-            # async dispatch never runs more than sync_iv iterations
-            # ahead of the device (config.tpu_dispatch_sync_interval:
-            # introduced for a retired backend, not re-checked on the
-            # in-process chip). The readback — not block_until_ready —
-            # is kept for the same reason: it is ordered behind every
-            # queued step on any backend.
-            with timing.phase("train/queue_drain"):
-                np.asarray(recs[-1].num_leaves)
-        if self.iter_ % self._stop_check_interval == 0:
-            return self._check_stop()
-        return False
 
 
     def leaves_and_waves(self, start_group: int = 0):

@@ -1,5 +1,14 @@
 """Observability subsystem: metrics registry, run reports, profiling.
 
+One span API: ``obs.trace.span`` (``utils/timing.phase`` is the same
+thing plus ``.watch``). A span records name, start, end, thread, the
+enclosing span of its thread (``parent``) and the span that handed its
+work over from another thread (``cause``); it always adds to the
+registry timer of its name, and it is a
+``jax.profiler.TraceAnnotation("lgbm/<name>")`` inside ANY open profiler
+session — a benchmark's, ``tpu_profile_dir``'s, an operator's — with
+nothing to switch on.
+
 - ``obs.registry`` — thread-safe counters/gauges/histograms/timers; the
   phase accounting in utils/timing.py stores here, the ingest pipeline
   counts transfer bytes here (io/ingest.py), and everything lands in
@@ -8,13 +17,13 @@
   JSON/JSONL run-report artifact (config ``tpu_run_report``), the
   slow-iteration watchdog (``tpu_watchdog_factor``), and the
   ``[t+12.3s it=140]`` log prefix.
-- ``obs.profiler`` — jax profiler integration: TraceAnnotation wrapping
-  for timing phases and the ``tpu_profile_dir``/``tpu_profile_iters``
-  iteration-window trace bracket.
-- ``obs.trace`` — cross-thread span tracer (config ``tpu_trace``/
-  ``tpu_trace_buffer``): ring-buffered Chrome trace-event JSON showing
-  the ingest worker, the training iterations, step-cache compiles and
-  the lrb window phases on one Perfetto timeline.
+- ``obs.profiler`` — the ``tpu_profile_dir``/``tpu_profile_iters``
+  iteration-window bracket round ``jax.profiler.start_trace``/
+  ``stop_trace``.
+- ``obs.trace`` — the span site, and the cross-thread tracer (config
+  ``tpu_trace``/``tpu_trace_buffer``): ring-buffered Chrome trace-event
+  JSON showing the ingest worker, the training iterations, step-cache
+  compiles and the lrb window phases on one Perfetto timeline.
 - ``obs.export`` — live metrics exporter (``tpu_metrics_export``/
   ``tpu_metrics_interval_s``/``tpu_metrics_port``): a daemon that
   snapshots the default registry to Prometheus text + JSONL on an

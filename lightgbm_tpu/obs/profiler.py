@@ -5,17 +5,18 @@ Brackets training iterations with ``jax.profiler.start_trace`` /
 traces the whole boosting loop (the pre-existing engine.train
 behavior); ``N > 0`` traces exactly N iterations starting at iteration
 2, skipping the compile-dominated first iteration so the capture shows
-steady-state device work. While a window is open, utils/timing.py
-emits a ``jax.profiler.TraceAnnotation`` around every phase
-(set_trace_annotations), so the engine's phase names appear as spans
-inside the capture.
+steady-state device work. The window only opens and closes the
+profiler session: every span of the program (obs/trace.span,
+utils/timing.phase) is a ``TraceAnnotation`` inside whatever session
+is open, this one or anyone else's, so the engine's span names appear
+in the capture as ``lgbm/<name>`` with nothing to switch on.
 
 Resilient by design: a jax without the profiler, or a backend where
 tracing fails, logs a warning and training proceeds untraced.
 """
 from __future__ import annotations
 
-from ..utils import log, timing
+from ..utils import log
 
 
 def profiler_available() -> bool:
@@ -32,9 +33,7 @@ class ProfileWindow:
 
     Drivers call ``iter_begin(it)`` / ``iter_end(it)`` with 1-based
     iteration numbers and ``close()`` after the loop (idempotent; also
-    the safety net for early stops while the trace is open). While a
-    window is configured, timing.phase emits TraceAnnotations so the
-    engine's phase names appear inside the captured trace.
+    the safety net for early stops while the trace is open).
     """
 
     def __init__(self, trace_dir: str = "", iters: int = 0):
@@ -42,7 +41,6 @@ class ProfileWindow:
         self.iters = max(int(iters or 0), 0)
         self._active = False
         self._done = False
-        self._annotations_installed = False
         if self.trace_dir and not profiler_available():
             log.warning("tpu_profile_dir=%s set but jax.profiler is "
                         "unavailable; tracing disabled", self.trace_dir)
@@ -71,8 +69,6 @@ class ProfileWindow:
             self.trace_dir = ""
             return
         self._active = True
-        timing.set_trace_annotations(True)
-        self._annotations_installed = True
         log.info("profiler trace started (dir=%s, window=%s)",
                  self.trace_dir,
                  "whole run" if self.iters == 0
@@ -88,9 +84,6 @@ class ProfileWindow:
     def close(self) -> None:
         if self._active:
             self._stop()
-        if self._annotations_installed:
-            timing.set_trace_annotations(False)
-            self._annotations_installed = False
 
     def _stop(self) -> None:
         try:

@@ -1,5 +1,24 @@
-"""Cross-thread span tracing: a ring-buffered Chrome trace-event
-recorder for the whole pipeline.
+"""The span site, and a ring-buffered Chrome trace-event recorder for
+the whole pipeline.
+
+**One span API.** ``span(name)`` (and ``utils/timing.phase``, which is
+``span`` plus ``.watch``) is the only way the program marks a stretch
+of its own work. A span records its name, start and end, the thread it
+ran on, the span that enclosed it on that thread (``parent``) and, for
+work another thread handed over, the span that submitted it (``cause``,
+carried by ``carry``); ``args`` ride along (an iteration span carries
+``it``). Every span, always, adds its seconds to the registry timer of
+its name (obs/registry.py: what ``timing.seconds`` and the run report
+read) and, while a profiler session is open, is a
+``jax.profiler.TraceAnnotation("lgbm/<name>")`` in the profiler's
+trace, on the device trace's clock — whoever opened the session: a
+benchmark, ``tpu_profile_dir``, an operator's own
+``jax.profiler.start_trace``. (An annotation made outside a session is
+inert, so none is made: the span asks the profiler and goes on.) Where
+jax has no profiler the span does without. With ``tpu_trace`` set
+(below) the span is also an event in this module's ring; registered
+sinks (the flight recorder) are fed by the ring and, with no ring, by
+the ``span`` sites directly (``Span.to_sinks``).
 
 The run report (obs/recorder.py) answers "how long did iteration 140
 take"; this module answers "what was every thread DOING while it ran".
@@ -32,11 +51,12 @@ Design constraints (the registry's rules, obs/registry.py):
   config.py): a million-iteration serving loop keeps the LAST N events
   instead of growing without bound; ``dropped_events`` counts what the
   ring evicted (surfaced in the written file's metadata).
-- **Dependency-free.** Standard library only — utils/timing.py imports
-  this module at load time, exactly like the registry.
-- **Off is free.** ``enabled()`` is a module-attribute read; every
-  record call no-ops without taking the lock when no tracer is
-  installed.
+- **Importable without jax.** Standard library only at load time —
+  utils/timing.py imports this module exactly like the registry;
+  ``jax.profiler`` is looked up on the first span.
+- **Off is cheap.** With no tracer, no sink and no profiler session a
+  span is two clock reads, a timer add and one look at the profiler
+  (about 3 us on the host, PERF.md); no ring lock is taken.
 
 The module-global tracer is installed by ``configure`` (drivers call
 ``ensure_from_config`` with any Config/dict carrying ``tpu_trace``) and
@@ -48,6 +68,7 @@ disk), and at interpreter exit as a safety net.
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import os
 import threading
@@ -59,11 +80,12 @@ from typing import Optional
 from ..analysis import lockorder
 from ..utils.fileio import atomic_write
 from . import identity
+from . import registry as _registry
 
 __all__ = [
-    "Tracer", "configure", "ensure_from_config", "stop", "active",
-    "enabled", "span", "instant", "write", "config_get",
-    "add_sink", "remove_sink",
+    "Tracer", "Span", "configure", "ensure_from_config", "stop",
+    "active", "enabled", "span", "current", "carry", "instant",
+    "write", "config_get", "add_sink", "remove_sink",
 ]
 
 
@@ -203,13 +225,14 @@ class Tracer:
                 self._threads.setdefault(tid, name)
 
     def complete(self, name: str, cat: str, start_us: float,
-                 args: Optional[dict] = None) -> None:
-        """Record a finished span [start_us, now] on the CALLING
-        thread (complete events pair begin/end in one record, so
-        cross-thread spans can never mis-nest)."""
+                 args: Optional[dict] = None,
+                 end_us: Optional[float] = None) -> None:
+        """Record a finished span [start_us, end_us or now] on the
+        CALLING thread (complete events pair begin/end in one record,
+        so cross-thread spans can never mis-nest)."""
         tid = _native_tid()
         self._register_thread(tid)
-        end = self.now_us()
+        end = self.now_us() if end_us is None else end_us
         ev = {"name": name, "cat": cat, "ph": "X",
               "ts": round(start_us, 3),
               "dur": round(max(end - start_us, 0.0), 3),
@@ -358,31 +381,164 @@ def enabled() -> bool:
     return _tracer is not None
 
 
-@contextmanager
-def span(name: str, cat: str = "phase", args: Optional[dict] = None):
-    """Record a span on the global tracer; free no-op when tracing is
-    off (the hot-path callers — timing.phase, the ingest worker —
-    guard on ``enabled()`` first, but this is safe bare too). With no
-    tracer but registered sinks (the always-on flight ring), the event
-    still reaches the sinks — the black box keeps span evidence even
-    when ``tpu_trace`` is off."""
-    tr = _tracer
-    if tr is None:
-        if not _sinks:
-            yield
-            return
-        t0 = _sink_now_us()
-        try:
-            yield
-        finally:
-            _sink_only_event(name, cat, "X", t0,
-                             dur_us=_sink_now_us() - t0, args=args)
-        return
-    t0 = tr.now_us()
+# ---------------------------------------------------------------------------
+# the span site
+# ---------------------------------------------------------------------------
+
+_tls = threading.local()        # .stack: open spans; .cause: see carry()
+_ids = itertools.count(1)       # next() is atomic under the GIL
+_annotation_cls = None          # unresolved; False = jax has no profiler
+# a TraceMe name carries its arguments as "name#k=v,k=v#"
+_ANN_UNSAFE = str.maketrans({"#": "_", ",": ";", "=": ":"})
+
+
+def _resolve_annotation_cls():
+    """``jax.profiler.TraceAnnotation``, looked up once; False where jax
+    (or its profiler) is missing — the span then does without."""
+    global _annotation_cls
     try:
-        yield
-    finally:
-        tr.complete(name, cat, t0, args)
+        from jax.profiler import TraceAnnotation as cls
+    except Exception:                   # noqa: BLE001 — absence == off
+        cls = False
+    _annotation_cls = cls
+    return cls
+
+
+def _annotation(sp: "Span"):
+    """An entered ``TraceAnnotation("lgbm/<name>")`` carrying the span's
+    links and scalar arguments. Called only while a profiler session is
+    open: an annotation made outside one would be inert."""
+    kw = sp.links()
+    for k, v in (sp.args or {}).items():
+        if isinstance(v, str):
+            kw[k] = v.translate(_ANN_UNSAFE)
+        elif isinstance(v, (int, float, bool)):
+            kw[k] = v
+    ann = _annotation_cls("lgbm/" + sp.name, **kw)
+    ann.__enter__()
+    return ann
+
+
+class Span:
+    """One stretch of the program's own work; a context manager.
+
+    ``parent`` is the span open on this thread when this one began,
+    ``cause`` the span that handed the work to this thread (``carry``),
+    set on a thread's outermost spans only — an inner span reaches it
+    through ``parent``. ``args`` may be added to while the span is
+    open; they ride on the ring/sink event written at exit.
+
+    ``to_sinks``: whether the span reaches the sinks when NO tracer is
+    installed. True here, so the always-on flight ring keeps the coarse
+    spans (ingest chunks, step compiles, lrb windows, requests) as
+    evidence; utils/timing.phase — per-iteration accounting — turns it
+    off, or a 256-slot ring would hold nothing but the last iterations.
+    Under ``tpu_trace`` every span is in the ring, and the ring feeds the
+    sinks."""
+    __slots__ = ("name", "cat", "args", "id", "parent", "cause", "tid",
+                 "t0_ns", "t1_ns", "_ann")
+    to_sinks = True
+
+    def __init__(self, name: str, cat: str = "phase",
+                 args: Optional[dict] = None):
+        self.name, self.cat, self.args = name, cat, args
+        self.id = 0
+        self.parent = self.cause = self._ann = None
+        self.tid = self.t0_ns = self.t1_ns = 0
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1_ns - self.t0_ns) / 1e9
+
+    def links(self) -> dict:
+        """{"id", "parent", "cause"} as span ids, the latter two only
+        where there is one."""
+        out = {"id": self.id}
+        if self.parent is not None:
+            out["parent"] = self.parent.id
+        if self.cause is not None:
+            out["cause"] = self.cause.id
+        return out
+
+    def __enter__(self):
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+        self.id = next(_ids)
+        self.tid = threading.get_native_id()
+        if stack:
+            self.parent = stack[-1]
+        else:
+            self.cause = getattr(_tls, "cause", None)
+        stack.append(self)
+        cls = _annotation_cls
+        if cls is None:
+            cls = _resolve_annotation_cls()
+        if cls and cls.is_enabled():
+            self._ann = _annotation(self)
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.t1_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _tls.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:             # exits out of order (a generator
+            stack.remove(self)          # closed late): keep the rest sound
+        # bounded-cardinality: span names are call-site string literals
+        _registry.timer(self.name).add((t1 - self.t0_ns) / 1e9)
+        tr = _tracer
+        if tr is not None or (_sinks and self.to_sinks):
+            args = self.links()
+            if self.args:
+                args.update(self.args)
+            if tr is not None:
+                tr.complete(self.name, self.cat,
+                            (self.t0_ns - tr._t0_ns) / 1000.0, args,
+                            end_us=(t1 - tr._t0_ns) / 1000.0)
+            else:
+                _sink_only_event(
+                    self.name, self.cat, "X",
+                    (self.t0_ns - _sink_t0_ns) / 1000.0,
+                    dur_us=(t1 - self.t0_ns) / 1000.0, args=args)
+        return False
+
+
+def span(name: str, cat: str = "phase",
+         args: Optional[dict] = None) -> Span:
+    """``with span("ingest/chunk", cat="ingest", args={...}):`` — THE
+    span site (module docstring). Always a registry timer; an
+    annotation in any open profiler session; an event in the ring under
+    ``tpu_trace`` and in every sink (the always-on flight ring keeps
+    span evidence even with ``tpu_trace`` off)."""
+    return Span(name, cat, args)
+
+
+def current() -> Optional[Span]:
+    """The innermost span open on the calling thread."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
+
+
+def carry(fn):
+    """Bind the calling thread's open span to ``fn`` as the ``cause``
+    of the spans ``fn`` opens on whichever thread runs it: hand
+    ``carry(work)`` to an executor and the worker's spans name the span
+    that queued them."""
+    cause = current()
+
+    def run(*a, **kw):
+        prev = getattr(_tls, "cause", None)
+        _tls.cause = cause
+        try:
+            return fn(*a, **kw)
+        finally:
+            _tls.cause = prev
+    return run
 
 
 def instant(name: str, cat: str = "event",

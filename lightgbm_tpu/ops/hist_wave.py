@@ -587,6 +587,7 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
         # the unrolled group loop's temporaries exceed the 16 MB default
         # scoped-vmem cap; v5e has 128 MB physical VMEM
         compiler_params=autotune.tpu_compiler_params(),
+        name="wave_histogram_pallas",
         interpret=interpret,
     )(wl, bins_t, ghl)
     out = outs[0] if variant == "hilo4" else outs
@@ -1117,6 +1118,7 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         compiler_params=autotune.tpu_compiler_params(),
+        name="fused_partition_histogram_pallas",
         interpret=interpret,
     )(tblT, bins_t, ghm, leaf2d)
     hist, leaf_out = outs[0], outs[1]
@@ -1330,6 +1332,7 @@ def wave_histogram_pallas_gpu(bins_t, g, h, leaf_ids, wave_leaves, *,
         input_output_aliases={5: 0},
         compiler_params=(None if interpret
                          else autotune.gpu_compiler_params()),
+        name="wave_histogram_pallas_gpu",
         interpret=interpret,
     )(wave_leaves.astype(jnp.int32), bins_t,
       g.astype(jnp.float32), h.astype(jnp.float32),
@@ -1541,6 +1544,7 @@ def fused_partition_histogram_pallas_gpu(bins_t, g, h, sample_mask,
         input_output_aliases={6: 0, 7: 2},
         compiler_params=(None if interpret
                          else autotune.gpu_compiler_params()),
+        name="fused_partition_histogram_pallas_gpu",
         interpret=interpret,
     )(tbl18, bins_t, g.astype(jnp.float32), h.astype(jnp.float32),
       sample_mask.astype(jnp.float32), leaf_ids.astype(jnp.int32),

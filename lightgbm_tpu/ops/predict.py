@@ -85,6 +85,7 @@ def leaf_gather_pallas(table, leaf_ids, *, interpret=False):
         out_specs=pl.BlockSpec((8, chunk), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(lv.shape, jnp.float32),
+        name="leaf_gather_pallas",
         interpret=interpret,
     )(tbl, lv)
     return out.reshape(-1)[:n]

@@ -543,8 +543,9 @@ class StackedModel:
             parts = [prep(0)]
         else:
             from ..io.ingest import prefetch
-            parts = prefetch((lambda c0=c0: prep(c0))
-                             for c0 in range(0, N, chunk))
+            parts = prefetch(((lambda c0=c0: prep(c0))
+                              for c0 in range(0, N, chunk)),
+                             wait_span=None)
         return [(runner(part), nrows) for part, nrows in parts]
 
     def warmup(self, rows: int = 1) -> bool:
@@ -1100,6 +1101,7 @@ def forest_predict_pallas(codes_t, W, P, tgt, leaf, cls, *, offsets,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, K), jnp.float32),
         compiler_params=autotune.tpu_compiler_params(),
+        name="forest_predict_pallas",
         interpret=interpret,
     )(codes_t, W, P, tgt, leaf, cls)
     return acc[:N]
@@ -1203,6 +1205,7 @@ def forest_predict_pallas_gpu(codes_t, W, P, tgt, leaf, cls, *,
         out_shape=jax.ShapeDtypeStruct((n_pad, K), jnp.float32),
         compiler_params=(None if interpret
                          else autotune.gpu_compiler_params()),
+        name="forest_predict_pallas_gpu",
         interpret=interpret,
     )(codes_t,
       W.reshape(steps * Wtot, TCSp),

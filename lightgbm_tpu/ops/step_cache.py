@@ -54,21 +54,22 @@ arrays — both production modes hit the registry on same-geometry
 retrains.
 
 Counters land in the obs registry (``step_cache/hits|misses|
-evictions``, ``step_cache/compile`` timer with per-key first-dispatch
-wall time, ``step_cache/first_step_s`` per-booster spans recorded by
-gbdt) and ``stats()`` is snapshotted into run reports
-(``meta.step_cache``) and bench JSON.
+evictions``; the ``step_cache/compile`` span: per-key first-dispatch
+wall time, with jax's own compile events of that dispatch attributed
+to it — timer ``step_cache/backend_compile``, counters
+``step_cache/persistent_hits|persistent_misses``) and ``stats()`` is
+snapshotted into run reports (``meta.step_cache``) and bench JSON.
 """
 from __future__ import annotations
 
+import hashlib
 import threading
-import time
 from collections import OrderedDict
 from typing import Callable, Dict, Optional
 
 from ..obs import registry as obs
 from ..obs import trace
-from ..utils import log
+from ..utils import log, timing
 
 # bounded registry: one entry per distinct training geometry; an LRU
 # evict keeps pathological sweeps (e.g. a num_leaves grid search) from
@@ -221,7 +222,7 @@ def get_step(key: tuple, builder: Callable[[], Callable]) -> Callable:
             return fn
     obs.counter("step_cache/misses").add(1)
     trace.instant("step_cache/miss", cat="cache")
-    fn = _instrument(builder())
+    fn = _instrument(builder(), key)
     with _lock:
         # lost race: another thread built it first — keep theirs
         # (functionally identical by key construction)
@@ -235,23 +236,99 @@ def get_step(key: tuple, builder: Callable[[], Callable]) -> Callable:
     return fn
 
 
-def _instrument(fn: Callable) -> Callable:
-    """Record the wall time of the first dispatch of a cached step —
-    jit compiles synchronously on first call while the result stays
-    async, so this span is trace+compile time to within dispatch
-    noise."""
+COMPILE_SPAN = "step_cache/compile"
+_listening = False
+_fetch = threading.local()      # .pending: this thread's last compile
+                                # request was served by the persistent cache
+
+
+def _compile_span() -> Optional[trace.Span]:
+    """The ``step_cache/compile`` span open on the calling thread (jit
+    compiles on the thread that dispatches), else None."""
+    sp = trace.current()
+    while sp is not None and sp.name != COMPILE_SPAN:
+        sp = sp.parent
+    return sp
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    sp = _compile_span()
+    if sp is None:
+        return
+    if event == "/jax/compilation_cache/cache_hits":
+        obs.counter("step_cache/persistent_hits").add(1)
+        sp.args["persistent_hits"] += 1
+        _fetch.pending = True
+    elif event == "/jax/compilation_cache/cache_misses":
+        obs.counter("step_cache/persistent_misses").add(1)
+        sp.args["persistent_misses"] += 1
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    sp = _compile_span()
+    if sp is None:
+        return
+    # jax brackets compile-or-fetch with this event; a fetch from the
+    # persistent cache (its hit event comes first) compiled nothing
+    if getattr(_fetch, "pending", False):
+        _fetch.pending = False
+        secs = 0.0
+    obs.timer("step_cache/backend_compile").add(secs)
+    sp.args["backend_compile_s"] = round(
+        sp.args["backend_compile_s"] + secs, 3)
+
+
+def _listen() -> None:
+    """Register the two jax.monitoring listeners once. They are
+    process-wide by jax's design, so each attributes an event only to
+    a ``step_cache/compile`` span open on ITS thread: compiles of other
+    programs in the process (a caller's reference, the autotuner, a
+    predictor) are never counted."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _instrument(fn: Callable, key: tuple) -> Callable:
+    """Span the first dispatch of a cached step — jit compiles
+    synchronously on first call while the result stays async, so the
+    span is trace + lower + compile (or its fetch from the persistent
+    cache) to within dispatch noise. The span's arguments say which
+    step it was (a digest of the geometry key and the key itself) and
+    what the backend did for it."""
     state = {"first": True}
 
     def call(*args):
         if state["first"]:
             state["first"] = False
-            t0 = time.monotonic()
-            with trace.span("step_cache/compile", cat="cache"):
-                out = fn(*args)
-            dt = time.monotonic() - t0
-            obs.timer("step_cache/compile").add(dt)
-            log.debug("step cache: compiled a new fused step in %.2fs",
-                      dt)
+            _listen()
+            geometry = repr(key)
+            span_args = {
+                "geometry": hashlib.sha1(geometry.encode()).hexdigest()[:12],
+                "key": geometry, "backend_compile_s": 0.0,
+                "persistent_hits": 0, "persistent_misses": 0}
+            try:
+                with trace.span(COMPILE_SPAN, cat="cache",
+                                args=span_args) as sp:
+                    out = fn(*args)
+            finally:
+                # a hit event that no duration event followed must not
+                # zero this thread's next real compile
+                _fetch.pending = False
+            timing.mark_mem_peak(COMPILE_SPAN)
+            log.debug("step cache: first dispatch of step %s took %.2fs "
+                      "(backend compile %.2fs, persistent cache %d hit / "
+                      "%d miss)", span_args["geometry"], sp.seconds,
+                      span_args["backend_compile_s"],
+                      span_args["persistent_hits"],
+                      span_args["persistent_misses"])
             return out
         return fn(*args)
 
@@ -331,29 +408,30 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
 
     def step(bins, scores, valid_scores, mask, fmask, shrink,
              init_bias, g_in, h_in, key, rvalid, meta, aux):
-        if grad_fn is None:
-            g_all, h_all = g_in, h_in
-        else:
-            g_all, h_all = grad_fn(scores if K > 1 else scores[0],
-                                   aux["obj"])
-            if K == 1:
-                g_all, h_all = g_all[None, :], h_all[None, :]
-        if rvalid is not None:
-            # pad rows: exact +0.0 g/h (a multiply by the zero mask
-            # would produce -0.0 for negative gradients, perturbing the
-            # integer bit-sum salt of the quantized stochastic-rounding
-            # stream)
-            g_all = jnp.where(rvalid[None, :], g_all, 0.0)
-            h_all = jnp.where(rvalid[None, :], h_all, 0.0)
-        if sample_hook is not None:
-            # in-jit gradient-based sampling (GOSS): may amplify g/h
-            # and shrink the bagging mask, all device-side. The hook
-            # receives rvalid (None on the legacy route) so the hashed
-            # sampler derives the REAL row count from the traced
-            # validity mask instead of a closure int — the registry
-            # path stays pure in its geometry.
-            g_all, h_all, mask = sample_hook(g_all, h_all, mask, key,
-                                             rvalid)
+        with jax.named_scope("lgbm/gradients"):
+            if grad_fn is None:
+                g_all, h_all = g_in, h_in
+            else:
+                g_all, h_all = grad_fn(scores if K > 1 else scores[0],
+                                       aux["obj"])
+                if K == 1:
+                    g_all, h_all = g_all[None, :], h_all[None, :]
+            if rvalid is not None:
+                # pad rows: exact +0.0 g/h (a multiply by the zero mask
+                # would produce -0.0 for negative gradients, perturbing the
+                # integer bit-sum salt of the quantized stochastic-rounding
+                # stream)
+                g_all = jnp.where(rvalid[None, :], g_all, 0.0)
+                h_all = jnp.where(rvalid[None, :], h_all, 0.0)
+            if sample_hook is not None:
+                # in-jit gradient-based sampling (GOSS): may amplify g/h
+                # and shrink the bagging mask, all device-side. The hook
+                # receives rvalid (None on the legacy route) so the hashed
+                # sampler derives the REAL row count from the traced
+                # validity mask instead of a closure int — the registry
+                # path stays pure in its geometry.
+                g_all, h_all, mask = sample_hook(g_all, h_all, mask, key,
+                                                 rvalid)
         recs = []
         vs = list(valid_scores)
         for k in range(K):
@@ -368,40 +446,43 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
                 rec, leaf_full = grower(bins, g_k, h_k, mask, fmask,
                                         meta)
             leaf_ids = leaf_full[:n_score]
-            if renew:
-                # objective-driven leaf refit
-                # (serial_tree_learner.cpp:780-818) against the
-                # PRE-update scores; splitless trees stay all-zero (the
-                # reference never renews a tree it is about to discard,
-                # gbdt.cpp:393-409); bucket-pad rows carry zero weight
-                # through ``mask`` and cannot shift the percentiles
-                residual = aux["renew"]["label"] - scores[k]
-                new_out = renew_leaf_outputs(
-                    leaf_ids, residual, aux["renew"].get("w"),
-                    num_leaves, renew_alpha, rec.leaf_output,
-                    mask[:n_score])
-                new_out = jnp.where(rec.num_leaves > 1, new_out,
-                                    rec.leaf_output)
-                rec = rec._replace(leaf_output=new_out)
-            # fold shrinkage (Tree::Shrinkage, gbdt.cpp:371).
-            # NOTE for resume/replay authors: XLA freely re-fuses this
-            # fold into the score gather-add (contraction skips the
-            # intermediate rounding), so the live score state is NOT
-            # reproducible by replaying the saved leaf outputs —
-            # checkpoint resume (utils/checkpoint.py) therefore saves
-            # the score buffers themselves instead of replaying trees.
-            rec = rec._replace(
-                leaf_output=rec.leaf_output * shrink,
-                internal_value=rec.internal_value * shrink)
+            with jax.named_scope("lgbm/leaf_values"):
+                if renew:
+                    # objective-driven leaf refit
+                    # (serial_tree_learner.cpp:780-818) against the
+                    # PRE-update scores; splitless trees stay all-zero (the
+                    # reference never renews a tree it is about to discard,
+                    # gbdt.cpp:393-409); bucket-pad rows carry zero weight
+                    # through ``mask`` and cannot shift the percentiles
+                    residual = aux["renew"]["label"] - scores[k]
+                    new_out = renew_leaf_outputs(
+                        leaf_ids, residual, aux["renew"].get("w"),
+                        num_leaves, renew_alpha, rec.leaf_output,
+                        mask[:n_score])
+                    new_out = jnp.where(rec.num_leaves > 1, new_out,
+                                        rec.leaf_output)
+                    rec = rec._replace(leaf_output=new_out)
+                # fold shrinkage (Tree::Shrinkage, gbdt.cpp:371).
+                # NOTE for resume/replay authors: XLA freely re-fuses this
+                # fold into the score gather-add (contraction skips the
+                # intermediate rounding), so the live score state is NOT
+                # reproducible by replaying the saved leaf outputs —
+                # checkpoint resume (utils/checkpoint.py) therefore saves
+                # the score buffers themselves instead of replaying trees.
+                rec = rec._replace(
+                    leaf_output=rec.leaf_output * shrink,
+                    internal_value=rec.internal_value * shrink)
             # out-of-bag rows included: the partition covers ALL rows
-            scores = scores.at[k].set(add_leaf_outputs(
-                scores[k], leaf_ids, rec.leaf_output, 1.0,
-                mesh=mesh, row_sharded=row_sharded))
-            for vi, (voff, vn) in enumerate(valid_slices):
-                vleaf = leaf_full[voff:voff + vn]
-                vs[vi] = vs[vi].at[k].set(add_leaf_outputs(
-                    vs[vi][k], vleaf, rec.leaf_output, 1.0,
+            with jax.named_scope("lgbm/score_update"):
+                scores = scores.at[k].set(add_leaf_outputs(
+                    scores[k], leaf_ids, rec.leaf_output, 1.0,
                     mesh=mesh, row_sharded=row_sharded))
+            with jax.named_scope("lgbm/valid_scores"):
+                for vi, (voff, vn) in enumerate(valid_slices):
+                    vleaf = leaf_full[voff:voff + vn]
+                    vs[vi] = vs[vi].at[k].set(add_leaf_outputs(
+                        vs[vi][k], vleaf, rec.leaf_output, 1.0,
+                        mesh=mesh, row_sharded=row_sharded))
             # AddBias on the STORED record only (tree.h:151): the init
             # score already reached train/valid scores through
             # BoostFromAverage's AddScore, so the score updates above
@@ -409,9 +490,10 @@ def build_train_step(*, grower, K: int, n_score: int, n_total: int,
             # this also yields the reference's constant tree
             # (leaf0 = init, gbdt.cpp:378-396); biasing unused leaf
             # slots is harmless (leaf_ids never reference them).
-            rec = rec._replace(
-                leaf_output=rec.leaf_output + init_bias[k],
-                internal_value=rec.internal_value + init_bias[k])
+            with jax.named_scope("lgbm/leaf_values"):
+                rec = rec._replace(
+                    leaf_output=rec.leaf_output + init_bias[k],
+                    internal_value=rec.internal_value + init_bias[k])
             recs.append(rec)
         return scores, tuple(vs), recs
 

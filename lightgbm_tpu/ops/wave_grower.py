@@ -604,71 +604,73 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             return jnp.where(in_bag, leaf_ids, -1)
 
         # root: wave histogram with one active slot = leaf 0
-        root_wl = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32), jnp.full(W - 1, -1, jnp.int32)])
-        leaf0 = jnp.zeros(n, jnp.int32)
-        if use_fused and (proxy or cfg.packed4):
-            # proxy/packed4 root: the partition-free wave kernel in the
-            # matching tier — no partition logic to pay for on an
-            # unsplit tree, and (packed4) the default hist_fn never
-            # sees the packed byte rows the fused path keeps in HBM
-            from .hist_wave import (wave_histogram_pallas,
-                                    wave_histogram_pallas_gpu)
-            wave_kernel = (wave_histogram_pallas_gpu if gpu_hist
-                           else wave_histogram_pallas)
-            root_chunk = cfg.chunk or (
-                autotune.DEFAULT_GPU_HIST_CHUNK if gpu_hist
-                else DEFAULT_HIST_CHUNK)
-            local_root = wave_kernel(
-                bins_t, hg, hh, bag_mask_ids(leaf0), root_wl,
-                num_bins=B, chunk=root_chunk,
-                interpret=fused_interpret, precision=cfg.precision,
-                gh_scale=gh_scale, count_proxy=proxy,
-                packed4=cfg.packed4,
-                num_features=F if cfg.packed4 else None,
-                dequant=not defer, variant=cfg.exact_variant)
-        else:
-            local_root = call_hist(hsrc, bag_mask_ids(leaf0),
-                                   root_wl)              # [W, F, B, 3]
-        root_hist = dq(hist_reduce_fn(local_root))
-        F_h = root_hist.shape[1]
-        if quant:
-            # root aggregates as dequantized sums of the SAME integer
-            # g/h the histogram passes consume, so later subtractions
-            # stay internally consistent — computed directly from
-            # hg/hq rather than a histogram column: a hist_fn that
-            # zero-pads unowned features (the EFB x feature-parallel
-            # seam expands only the local bundle slice) would make a
-            # column-derived sum device-dependent. Local sum then the
-            # scalar reducer: one collective in every mode. The LOCAL
-            # sum accumulates in int32 when the shard's row count
-            # provably cannot wrap it (|v| <= 127 so the total is
-            # bounded by 127*n < 2^31 — the same bound the Pallas
-            # kernels' overflow guard enforces; the XLA fallback path
-            # has no such guard, so bigger shards keep the old f32
-            # sum, which rounds but never wraps). The exact per-shard
-            # total converts to f32 BEFORE the reducer: an int32 psum
-            # across D shards could wrap even when every shard is
-            # within bound, while the f32 psum of D already-exact
-            # totals rounds only D-1 additions.
-            if 127 * n < 2 ** 31:
-                def acc(v):
-                    return jnp.sum(v.astype(jnp.int32)).astype(f32)
+        with jax.named_scope("lgbm/root_hist"):
+            root_wl = jnp.concatenate(
+                [jnp.zeros(1, jnp.int32), jnp.full(W - 1, -1, jnp.int32)])
+            leaf0 = jnp.zeros(n, jnp.int32)
+            if use_fused and (proxy or cfg.packed4):
+                # proxy/packed4 root: the partition-free wave kernel in the
+                # matching tier — no partition logic to pay for on an
+                # unsplit tree, and (packed4) the default hist_fn never
+                # sees the packed byte rows the fused path keeps in HBM
+                from .hist_wave import (wave_histogram_pallas,
+                                        wave_histogram_pallas_gpu)
+                wave_kernel = (wave_histogram_pallas_gpu if gpu_hist
+                               else wave_histogram_pallas)
+                root_chunk = cfg.chunk or (
+                    autotune.DEFAULT_GPU_HIST_CHUNK if gpu_hist
+                    else DEFAULT_HIST_CHUNK)
+                local_root = wave_kernel(
+                    bins_t, hg, hh, bag_mask_ids(leaf0), root_wl,
+                    num_bins=B, chunk=root_chunk,
+                    interpret=fused_interpret, precision=cfg.precision,
+                    gh_scale=gh_scale, count_proxy=proxy,
+                    packed4=cfg.packed4,
+                    num_features=F if cfg.packed4 else None,
+                    dequant=not defer, variant=cfg.exact_variant)
             else:
-                acc = _stable_sum
-            root_g = reduce_fn(acc(hg)) * gh_scale[0]
-            root_h = reduce_fn(acc(hh)) * gh_scale[1]
-        else:
-            # shape-stable sums: bucket-padded and exact-shape boosters
-            # must agree bit-for-bit (ops/step_cache.py row bucketing)
-            root_g = reduce_fn(_stable_sum(grad))
-            root_h = reduce_fn(_stable_sum(hess))
-        root_c = reduce_fn(jnp.sum(sample_mask))
-        if proxy:
-            root_hist = bound_counts(root_hist, gh_scale)
-        root_split = split_fn(
-            root_hist[:1], root_g[None], root_h[None], root_c[None],
-            feature_mask, depth_ok(jnp.zeros(1, jnp.int32)), meta)
+                local_root = call_hist(hsrc, bag_mask_ids(leaf0),
+                                       root_wl)              # [W, F, B, 3]
+            root_hist = dq(hist_reduce_fn(local_root))
+            F_h = root_hist.shape[1]
+            if quant:
+                # root aggregates as dequantized sums of the SAME integer
+                # g/h the histogram passes consume, so later subtractions
+                # stay internally consistent — computed directly from
+                # hg/hq rather than a histogram column: a hist_fn that
+                # zero-pads unowned features (the EFB x feature-parallel
+                # seam expands only the local bundle slice) would make a
+                # column-derived sum device-dependent. Local sum then the
+                # scalar reducer: one collective in every mode. The LOCAL
+                # sum accumulates in int32 when the shard's row count
+                # provably cannot wrap it (|v| <= 127 so the total is
+                # bounded by 127*n < 2^31 — the same bound the Pallas
+                # kernels' overflow guard enforces; the XLA fallback path
+                # has no such guard, so bigger shards keep the old f32
+                # sum, which rounds but never wraps). The exact per-shard
+                # total converts to f32 BEFORE the reducer: an int32 psum
+                # across D shards could wrap even when every shard is
+                # within bound, while the f32 psum of D already-exact
+                # totals rounds only D-1 additions.
+                if 127 * n < 2 ** 31:
+                    def acc(v):
+                        return jnp.sum(v.astype(jnp.int32)).astype(f32)
+                else:
+                    acc = _stable_sum
+                root_g = reduce_fn(acc(hg)) * gh_scale[0]
+                root_h = reduce_fn(acc(hh)) * gh_scale[1]
+            else:
+                # shape-stable sums: bucket-padded and exact-shape boosters
+                # must agree bit-for-bit (ops/step_cache.py row bucketing)
+                root_g = reduce_fn(_stable_sum(grad))
+                root_h = reduce_fn(_stable_sum(hess))
+            root_c = reduce_fn(jnp.sum(sample_mask))
+            if proxy:
+                root_hist = bound_counts(root_hist, gh_scale)
+        with jax.named_scope("lgbm/wave/split_find"):
+            root_split = split_fn(
+                root_hist[:1], root_g[None], root_h[None], root_c[None],
+                feature_mask, depth_ok(jnp.zeros(1, jnp.int32)), meta)
 
         def set0(arr, v):
             return arr.at[0].set(v[0] if v.ndim else v)
@@ -722,205 +724,210 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
 
         def body(state: _State) -> _State:
             f32 = jnp.float32
-            # 1. elect the wave: top-W leaves by gain, capped by budget
-            top_gain, wl = jax.lax.top_k(state.t_gain, W)   # [W]
-            wl = wl.astype(jnp.int32)
-            budget = (L - state.num_leaves).astype(jnp.int32)
-            rank = jnp.arange(W, dtype=jnp.int32)
-            active = (top_gain > 0.0) & (rank < budget)
-            n_act = jnp.sum(active.astype(jnp.int32))
-            prefix = jnp.cumsum(active.astype(jnp.int32)) - active
-            new_ids = jnp.where(active, state.num_leaves + prefix, -1)
-            wl = jnp.where(active, wl, -1)
-            # scatter-safe slot indices: OOB-high sentinel so that
-            # mode="drop" really drops inactive slots (negative indices
-            # would wrap python-style and corrupt the last entries)
-            wl_s = jnp.where(active, wl, L)
-            new_s = jnp.where(active, new_ids, L)
+            with jax.named_scope("lgbm/wave/bookkeep"):
+                # 1. elect the wave: top-W leaves by gain, capped by budget
+                top_gain, wl = jax.lax.top_k(state.t_gain, W)   # [W]
+                wl = wl.astype(jnp.int32)
+                budget = (L - state.num_leaves).astype(jnp.int32)
+                rank = jnp.arange(W, dtype=jnp.int32)
+                active = (top_gain > 0.0) & (rank < budget)
+                n_act = jnp.sum(active.astype(jnp.int32))
+                prefix = jnp.cumsum(active.astype(jnp.int32)) - active
+                new_ids = jnp.where(active, state.num_leaves + prefix, -1)
+                wl = jnp.where(active, wl, -1)
+                # scatter-safe slot indices: OOB-high sentinel so that
+                # mode="drop" really drops inactive slots (negative indices
+                # would wrap python-style and corrupt the last entries)
+                wl_s = jnp.where(active, wl, L)
+                new_s = jnp.where(active, new_ids, L)
 
-            # 2. per-slot split params from the table (drop-safe gathers)
-            feat = state.t_feature[wl]
-            tbin = state.t_bin[wl]
-            dleft = state.t_default_left[wl]
-            iscat = state.t_is_cat[wl]
-            catw = state.t_cat_words[wl]               # [W, 8]
-            lcnt = state.t_left_count[wl]
-            rcnt = state.t_right_count[wl]
-            lg, lh = state.t_left_sum_g[wl], state.t_left_sum_h[wl]
-            rg, rh = state.t_right_sum_g[wl], state.t_right_sum_h[wl]
-            lo, ro = state.t_left_output[wl], state.t_right_output[wl]
+                # 2. per-slot split params from the table (drop-safe gathers)
+                feat = state.t_feature[wl]
+                tbin = state.t_bin[wl]
+                dleft = state.t_default_left[wl]
+                iscat = state.t_is_cat[wl]
+                catw = state.t_cat_words[wl]               # [W, 8]
+                lcnt = state.t_left_count[wl]
+                rcnt = state.t_right_count[wl]
+                lg, lh = state.t_left_sum_g[wl], state.t_left_sum_h[wl]
+                rg, rh = state.t_right_sum_g[wl], state.t_right_sum_h[wl]
+                lo, ro = state.t_left_output[wl], state.t_right_output[wl]
 
-            # 3+4. partition, then smaller-child histograms; siblings by
-            # subtraction from the pooled parent histogram. The fused
-            # Pallas path does both in ONE data pass (ocl/histogram256's
-            # partition-then-accumulate per workgroup, without the W
-            # separate partition passes).
-            left_smaller = lcnt <= rcnt
-            small_ids = jnp.where(left_smaller, wl, new_ids)
-            small_ids = jnp.where(active, small_ids, -1)
-            if use_fused:
-                safe_feat = jnp.maximum(feat, 0)
-                tbl = jnp.concatenate([jnp.stack([
-                    wl, new_ids, safe_feat, tbin,
-                    dleft.astype(jnp.int32),
-                    meta.missing_type[safe_feat],
-                    meta.default_bin[safe_feat],
-                    meta.num_bin[safe_feat], small_ids,
-                    iscat.astype(jnp.int32)]), catw.T])      # [18, W]
-                fused_out = fused_kernel_fn(
-                    bins_t, hg, hh, sample_mask,
-                    state.leaf_ids, tbl, num_bins=B,
-                    chunk=fused_chunk,
-                    interpret=fused_interpret,
-                    precision=cfg.precision, gh_scale=gh_scale,
-                    any_cat=bool(hp.has_cat), count_proxy=proxy,
-                    packed4=cfg.packed4,
-                    num_features=F if cfg.packed4 else None,
-                    dequant=not defer, variant=cfg.exact_variant)
-                leaf_ids, hist_small = fused_out[0], fused_out[1]
-                hist_small = dq(hist_reduce_fn(hist_small))
+            with jax.named_scope("lgbm/wave/hist"):
+                # 3+4. partition, then smaller-child histograms; siblings by
+                # subtraction from the pooled parent histogram. The fused
+                # Pallas path does both in ONE data pass (ocl/histogram256's
+                # partition-then-accumulate per workgroup, without the W
+                # separate partition passes).
+                left_smaller = lcnt <= rcnt
+                small_ids = jnp.where(left_smaller, wl, new_ids)
+                small_ids = jnp.where(active, small_ids, -1)
+                if use_fused:
+                    safe_feat = jnp.maximum(feat, 0)
+                    tbl = jnp.concatenate([jnp.stack([
+                        wl, new_ids, safe_feat, tbin,
+                        dleft.astype(jnp.int32),
+                        meta.missing_type[safe_feat],
+                        meta.default_bin[safe_feat],
+                        meta.num_bin[safe_feat], small_ids,
+                        iscat.astype(jnp.int32)]), catw.T])      # [18, W]
+                    fused_out = fused_kernel_fn(
+                        bins_t, hg, hh, sample_mask,
+                        state.leaf_ids, tbl, num_bins=B,
+                        chunk=fused_chunk,
+                        interpret=fused_interpret,
+                        precision=cfg.precision, gh_scale=gh_scale,
+                        any_cat=bool(hp.has_cat), count_proxy=proxy,
+                        packed4=cfg.packed4,
+                        num_features=F if cfg.packed4 else None,
+                        dequant=not defer, variant=cfg.exact_variant)
+                    leaf_ids, hist_small = fused_out[0], fused_out[1]
+                    hist_small = dq(hist_reduce_fn(hist_small))
+                    if proxy:
+                        cnt_r = reduce_fn(fused_out[2])
+                    # out-of-bag rows partition too; their g/h are pre-masked
+                    # and the count channel rides on sample_mask
+                elif use_fused_xla:
+                    # off-TPU fused route: one traced partition+histogram
+                    # region reusing the membership compares and the
+                    # combined 3-channel scatter — bit-identical to the
+                    # legacy [partition_fn -> call_hist] pipeline below
+                    safe_feat = jnp.maximum(feat, 0)
+                    fx = fused_partition_histogram_xla(
+                        bins_t, hg, hh, sample_mask, state.leaf_ids,
+                        wl, new_ids, feat, tbin, dleft, iscat, catw,
+                        small_ids,
+                        meta.missing_type[safe_feat],
+                        meta.default_bin[safe_feat],
+                        meta.num_bin[safe_feat],
+                        num_bins=B, count_proxy=proxy,
+                        gh_scale=gh_scale if quant else None,
+                        dequant=not defer)
+                    leaf_ids = fx[0]
+                    hist_small = dq(hist_reduce_fn(fx[1]))
+                    if proxy:
+                        cnt_r = reduce_fn(fx[2])
+                else:
+                    leaf_ids = partition_fn(bins_t, state.leaf_ids, wl,
+                                            new_ids, feat, tbin, dleft,
+                                            active, meta, iscat, catw)
+                    hist_small = dq(hist_reduce_fn(
+                        call_hist(hsrc, bag_mask_ids(leaf_ids),
+                                  small_ids)))
+                    if proxy:
+                        # exact in-bag right-child counts (XLA fallback for
+                        # the Pallas kernel's partition-mask counting)
+                        cnt_r = reduce_fn(jnp.sum(
+                            ((leaf_ids[None, :] == new_ids[:, None])
+                             & in_bag[None, :]).astype(jnp.float32),
+                            axis=1))
                 if proxy:
-                    cnt_r = reduce_fn(fused_out[2])
-                # out-of-bag rows partition too; their g/h are pre-masked
-                # and the count channel rides on sample_mask
-            elif use_fused_xla:
-                # off-TPU fused route: one traced partition+histogram
-                # region reusing the membership compares and the
-                # combined 3-channel scatter — bit-identical to the
-                # legacy [partition_fn -> call_hist] pipeline below
-                safe_feat = jnp.maximum(feat, 0)
-                fx = fused_partition_histogram_xla(
-                    bins_t, hg, hh, sample_mask, state.leaf_ids,
-                    wl, new_ids, feat, tbin, dleft, iscat, catw,
-                    small_ids,
-                    meta.missing_type[safe_feat],
-                    meta.default_bin[safe_feat],
-                    meta.num_bin[safe_feat],
-                    num_bins=B, count_proxy=proxy,
-                    gh_scale=gh_scale if quant else None,
-                    dequant=not defer)
-                leaf_ids = fx[0]
-                hist_small = dq(hist_reduce_fn(fx[1]))
+                    parent_cnt = state.leaf_count[wl]
+                    lcnt_x = parent_cnt - cnt_r          # exact (partition)
+                    rcnt_x = cnt_r
+                    hist_small = bound_counts(hist_small, gh_scale)
+                else:
+                    lcnt_x, rcnt_x = lcnt, rcnt
+                parent_hist = state.hist[wl]                 # [W, F, B, 3]
+                hist_large = parent_hist - hist_small
                 if proxy:
-                    cnt_r = reduce_fn(fx[2])
-            else:
-                leaf_ids = partition_fn(bins_t, state.leaf_ids, wl,
-                                        new_ids, feat, tbin, dleft,
-                                        active, meta, iscat, catw)
-                hist_small = dq(hist_reduce_fn(
-                    call_hist(hsrc, bag_mask_ids(leaf_ids),
-                              small_ids)))
-                if proxy:
-                    # exact in-bag right-child counts (XLA fallback for
-                    # the Pallas kernel's partition-mask counting)
-                    cnt_r = reduce_fn(jnp.sum(
-                        ((leaf_ids[None, :] == new_ids[:, None])
-                         & in_bag[None, :]).astype(jnp.float32),
-                        axis=1))
-            if proxy:
-                parent_cnt = state.leaf_count[wl]
-                lcnt_x = parent_cnt - cnt_r          # exact (partition)
-                rcnt_x = cnt_r
-                hist_small = bound_counts(hist_small, gh_scale)
-            else:
-                lcnt_x, rcnt_x = lcnt, rcnt
-            parent_hist = state.hist[wl]                 # [W, F, B, 3]
-            hist_large = parent_hist - hist_small
-            if proxy:
-                # the count channel holds lower bounds, which do NOT
-                # survive subtraction — recompute from the large
-                # child's own (exact) g/h sums
-                hist_large = bound_counts(hist_large, gh_scale)
-            ls4 = left_smaller[:, None, None, None]
-            hist_left = jnp.where(ls4, hist_small, hist_large)
-            hist_right = jnp.where(ls4, hist_large, hist_small)
-            pool = state.hist
-            pool = pool.at[wl_s].set(hist_left, mode="drop")
-            pool = pool.at[new_s].set(hist_right, mode="drop")
+                    # the count channel holds lower bounds, which do NOT
+                    # survive subtraction — recompute from the large
+                    # child's own (exact) g/h sums
+                    hist_large = bound_counts(hist_large, gh_scale)
+                ls4 = left_smaller[:, None, None, None]
+                hist_left = jnp.where(ls4, hist_small, hist_large)
+                hist_right = jnp.where(ls4, hist_large, hist_small)
+                pool = state.hist
+                pool = pool.at[wl_s].set(hist_left, mode="drop")
+                pool = pool.at[new_s].set(hist_right, mode="drop")
 
-            # 5. record the wave's splits at positions n_splits + prefix
-            pos = jnp.where(active, state.n_splits + prefix, L - 1)
-            parent_out = calculate_leaf_output(
-                state.leaf_sum_g[wl], state.leaf_sum_h[wl],
-                hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
-            rec = state.rec
-            rec = rec._replace(
-                num_leaves=rec.num_leaves + n_act,
-                split_leaf=rec.split_leaf.at[pos].set(wl, mode="drop"),
-                split_feature=rec.split_feature.at[pos].set(
-                    feat, mode="drop"),
-                split_bin=rec.split_bin.at[pos].set(tbin, mode="drop"),
-                split_gain=rec.split_gain.at[pos].set(
-                    jnp.where(active, top_gain, 0.0), mode="drop"),
-                split_default_left=rec.split_default_left.at[pos].set(
-                    dleft, mode="drop"),
-                split_is_cat=rec.split_is_cat.at[pos].set(
-                    iscat, mode="drop"),
-                split_cat_words=rec.split_cat_words.at[pos].set(
-                    catw, mode="drop"),
-                internal_value=rec.internal_value.at[pos].set(
-                    parent_out, mode="drop"),
-                internal_count=rec.internal_count.at[pos].set(
-                    state.leaf_count[wl], mode="drop"),
-            )
+            with jax.named_scope("lgbm/wave/bookkeep"):
+                # 5. record the wave's splits at positions n_splits + prefix
+                pos = jnp.where(active, state.n_splits + prefix, L - 1)
+                parent_out = calculate_leaf_output(
+                    state.leaf_sum_g[wl], state.leaf_sum_h[wl],
+                    hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
+                rec = state.rec
+                rec = rec._replace(
+                    num_leaves=rec.num_leaves + n_act,
+                    split_leaf=rec.split_leaf.at[pos].set(wl, mode="drop"),
+                    split_feature=rec.split_feature.at[pos].set(
+                        feat, mode="drop"),
+                    split_bin=rec.split_bin.at[pos].set(tbin, mode="drop"),
+                    split_gain=rec.split_gain.at[pos].set(
+                        jnp.where(active, top_gain, 0.0), mode="drop"),
+                    split_default_left=rec.split_default_left.at[pos].set(
+                        dleft, mode="drop"),
+                    split_is_cat=rec.split_is_cat.at[pos].set(
+                        iscat, mode="drop"),
+                    split_cat_words=rec.split_cat_words.at[pos].set(
+                        catw, mode="drop"),
+                    internal_value=rec.internal_value.at[pos].set(
+                        parent_out, mode="drop"),
+                    internal_count=rec.internal_count.at[pos].set(
+                        state.leaf_count[wl], mode="drop"),
+                )
 
-            # 6. per-leaf aggregate updates (left child keeps parent id)
-            child_depth = state.leaf_depth[wl] + 1
+                # 6. per-leaf aggregate updates (left child keeps parent id)
+                child_depth = state.leaf_depth[wl] + 1
 
-            def upd(arr, lvals, rvals):
-                arr = arr.at[wl_s].set(lvals, mode="drop")
-                return arr.at[new_s].set(rvals, mode="drop")
+                def upd(arr, lvals, rvals):
+                    arr = arr.at[wl_s].set(lvals, mode="drop")
+                    return arr.at[new_s].set(rvals, mode="drop")
 
-            leaf_output = upd(state.leaf_output, lo, ro)
-            # proxy mode: lcnt_x/rcnt_x are the partition-mask EXACT
-            # counts, so per-leaf bookkeeping (and the model file's
-            # leaf_count/internal_count) matches the exact path
-            leaf_count = upd(state.leaf_count, lcnt_x, rcnt_x)
-            leaf_sum_g = upd(state.leaf_sum_g, lg, rg)
-            leaf_sum_h = upd(state.leaf_sum_h, lh, rh)
-            leaf_depth = upd(state.leaf_depth, child_depth, child_depth)
+                leaf_output = upd(state.leaf_output, lo, ro)
+                # proxy mode: lcnt_x/rcnt_x are the partition-mask EXACT
+                # counts, so per-leaf bookkeeping (and the model file's
+                # leaf_count/internal_count) matches the exact path
+                leaf_count = upd(state.leaf_count, lcnt_x, rcnt_x)
+                leaf_sum_g = upd(state.leaf_sum_g, lg, rg)
+                leaf_sum_h = upd(state.leaf_sum_h, lh, rh)
+                leaf_depth = upd(state.leaf_depth, child_depth, child_depth)
 
-            # 7. best splits for the 2W children
-            hists2 = jnp.concatenate([hist_left, hist_right], axis=0)
-            sg2 = jnp.concatenate([lg, rg])
-            sh2 = jnp.concatenate([lh, rh])
-            nd2 = jnp.concatenate([lcnt_x, rcnt_x])
-            can2 = jnp.concatenate([active & depth_ok(child_depth)] * 2)
-            res = split_fn(hists2, sg2, sh2, nd2, feature_mask, can2,
-                           meta)
-            gain2 = jnp.where(jnp.isfinite(res.gain), res.gain,
-                              KMIN_SCORE)
-            idx2 = jnp.concatenate([wl_s, new_s])
-            act2 = jnp.concatenate([active] * 2)
+            with jax.named_scope("lgbm/wave/split_find"):
+                # 7. best splits for the 2W children
+                hists2 = jnp.concatenate([hist_left, hist_right], axis=0)
+                sg2 = jnp.concatenate([lg, rg])
+                sh2 = jnp.concatenate([lh, rh])
+                nd2 = jnp.concatenate([lcnt_x, rcnt_x])
+                can2 = jnp.concatenate([active & depth_ok(child_depth)] * 2)
+                res = split_fn(hists2, sg2, sh2, nd2, feature_mask, can2,
+                               meta)
+                gain2 = jnp.where(jnp.isfinite(res.gain), res.gain,
+                                  KMIN_SCORE)
+            with jax.named_scope("lgbm/wave/bookkeep"):
+                idx2 = jnp.concatenate([wl_s, new_s])
+                act2 = jnp.concatenate([active] * 2)
 
-            st = lambda tbl, v: _store_batch(tbl, idx2, v, act2)
-            state = state._replace(
-                leaf_ids=leaf_ids,
-                hist=pool,
-                t_gain=st(state.t_gain, gain2),
-                t_feature=st(state.t_feature, res.feature),
-                t_bin=st(state.t_bin, res.threshold_bin),
-                t_default_left=st(state.t_default_left, res.default_left),
-                t_left_output=st(state.t_left_output, res.left_output),
-                t_right_output=st(state.t_right_output, res.right_output),
-                t_left_count=st(state.t_left_count, res.left_count),
-                t_right_count=st(state.t_right_count, res.right_count),
-                t_left_sum_g=st(state.t_left_sum_g, res.left_sum_g),
-                t_left_sum_h=st(state.t_left_sum_h, res.left_sum_h),
-                t_right_sum_g=st(state.t_right_sum_g, res.right_sum_g),
-                t_right_sum_h=st(state.t_right_sum_h, res.right_sum_h),
-                t_is_cat=st(state.t_is_cat, res.is_cat),
-                t_cat_words=st(state.t_cat_words, res.cat_words),
-                leaf_output=leaf_output,
-                leaf_count=leaf_count,
-                leaf_sum_g=leaf_sum_g,
-                leaf_sum_h=leaf_sum_h,
-                leaf_depth=leaf_depth,
-                num_leaves=state.num_leaves + n_act,
-                n_splits=state.n_splits + n_act,
-                go_on=(n_act > 0) & (state.num_leaves + n_act < L),
-                rec=rec,
-            )
+                st = lambda tbl, v: _store_batch(tbl, idx2, v, act2)
+                state = state._replace(
+                    leaf_ids=leaf_ids,
+                    hist=pool,
+                    t_gain=st(state.t_gain, gain2),
+                    t_feature=st(state.t_feature, res.feature),
+                    t_bin=st(state.t_bin, res.threshold_bin),
+                    t_default_left=st(state.t_default_left, res.default_left),
+                    t_left_output=st(state.t_left_output, res.left_output),
+                    t_right_output=st(state.t_right_output, res.right_output),
+                    t_left_count=st(state.t_left_count, res.left_count),
+                    t_right_count=st(state.t_right_count, res.right_count),
+                    t_left_sum_g=st(state.t_left_sum_g, res.left_sum_g),
+                    t_left_sum_h=st(state.t_left_sum_h, res.left_sum_h),
+                    t_right_sum_g=st(state.t_right_sum_g, res.right_sum_g),
+                    t_right_sum_h=st(state.t_right_sum_h, res.right_sum_h),
+                    t_is_cat=st(state.t_is_cat, res.is_cat),
+                    t_cat_words=st(state.t_cat_words, res.cat_words),
+                    leaf_output=leaf_output,
+                    leaf_count=leaf_count,
+                    leaf_sum_g=leaf_sum_g,
+                    leaf_sum_h=leaf_sum_h,
+                    leaf_depth=leaf_depth,
+                    num_leaves=state.num_leaves + n_act,
+                    n_splits=state.n_splits + n_act,
+                    go_on=(n_act > 0) & (state.num_leaves + n_act < L),
+                    rec=rec,
+                )
             return state
 
         # ---- forced-split prefix (ForceSplits) ----
@@ -942,114 +949,118 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             active = wl >= 0
             iscat0 = jnp.zeros(W, bool)
             catw0 = jnp.zeros((W, 8), jnp.int32)
-            leaf_ids = partition_fn(bins_t, state.leaf_ids, wl, new_ids,
-                                    feat, tbin, dleft, active, meta,
-                                    iscat0, catw0)
-            # left child keeps the parent id: histogram it directly,
-            # sibling by subtraction (sizes don't matter here)
-            hist_left = dq(hist_reduce_fn(
-                call_hist(hsrc, bag_mask_ids(leaf_ids), wl)))
-            parent_hist = state.hist[wl]
-            hist_right = parent_hist - hist_left
-            wl_s = jnp.where(active, wl, L)
-            new_s = jnp.where(active, new_ids, L)
-            pool = state.hist.at[wl_s].set(hist_left, mode="drop")
-            pool = pool.at[new_s].set(hist_right, mode="drop")
-            # child sums from any one feature's bins (every row lands
-            # in exactly one bin per feature)
-            lg = hist_left[:, 0, :, 0].sum(axis=1)
-            lh = hist_left[:, 0, :, 1].sum(axis=1)
-            lcnt = hist_left[:, 0, :, 2].sum(axis=1)
-            rg = state.leaf_sum_g[wl] - lg
-            rh = state.leaf_sum_h[wl] - lh
-            rcnt = state.leaf_count[wl] - lcnt
-            parent_out = calculate_leaf_output(
-                state.leaf_sum_g[wl], state.leaf_sum_h[wl],
-                hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
-            # real gain like the reference's GatherInfoForThreshold:
-            # children's split gains minus the parent's
-            from .split import leaf_split_gain
-            forced_gain = (
-                leaf_split_gain(lg, lh + 1e-15, hp.lambda_l1,
-                                hp.lambda_l2, hp.max_delta_step)
-                + leaf_split_gain(rg, rh + 1e-15, hp.lambda_l1,
-                                  hp.lambda_l2, hp.max_delta_step)
-                - leaf_split_gain(state.leaf_sum_g[wl],
-                                  state.leaf_sum_h[wl] + 2e-15,
-                                  hp.lambda_l1, hp.lambda_l2,
-                                  hp.max_delta_step))
-            pos = jnp.where(active, state.n_splits, L - 1)
-            rec = state.rec
-            rec = rec._replace(
-                num_leaves=rec.num_leaves + 1,
-                split_leaf=rec.split_leaf.at[pos].set(wl, mode="drop"),
-                split_feature=rec.split_feature.at[pos].set(
-                    feat, mode="drop"),
-                split_bin=rec.split_bin.at[pos].set(tbin, mode="drop"),
-                split_gain=rec.split_gain.at[pos].set(
-                    forced_gain, mode="drop"),
-                split_default_left=rec.split_default_left.at[pos].set(
-                    dleft, mode="drop"),
-                internal_value=rec.internal_value.at[pos].set(
-                    parent_out, mode="drop"),
-                internal_count=rec.internal_count.at[pos].set(
-                    state.leaf_count[wl], mode="drop"),
-            )
-            child_depth = state.leaf_depth[wl] + 1
+            with jax.named_scope("lgbm/wave/hist"):
+                leaf_ids = partition_fn(bins_t, state.leaf_ids, wl, new_ids,
+                                        feat, tbin, dleft, active, meta,
+                                        iscat0, catw0)
+                # left child keeps the parent id: histogram it directly,
+                # sibling by subtraction (sizes don't matter here)
+                hist_left = dq(hist_reduce_fn(
+                    call_hist(hsrc, bag_mask_ids(leaf_ids), wl)))
+                parent_hist = state.hist[wl]
+                hist_right = parent_hist - hist_left
+                wl_s = jnp.where(active, wl, L)
+                new_s = jnp.where(active, new_ids, L)
+                pool = state.hist.at[wl_s].set(hist_left, mode="drop")
+                pool = pool.at[new_s].set(hist_right, mode="drop")
+            with jax.named_scope("lgbm/wave/bookkeep"):
+                # child sums from any one feature's bins (every row lands
+                # in exactly one bin per feature)
+                lg = hist_left[:, 0, :, 0].sum(axis=1)
+                lh = hist_left[:, 0, :, 1].sum(axis=1)
+                lcnt = hist_left[:, 0, :, 2].sum(axis=1)
+                rg = state.leaf_sum_g[wl] - lg
+                rh = state.leaf_sum_h[wl] - lh
+                rcnt = state.leaf_count[wl] - lcnt
+                parent_out = calculate_leaf_output(
+                    state.leaf_sum_g[wl], state.leaf_sum_h[wl],
+                    hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
+                # real gain like the reference's GatherInfoForThreshold:
+                # children's split gains minus the parent's
+                from .split import leaf_split_gain
+                forced_gain = (
+                    leaf_split_gain(lg, lh + 1e-15, hp.lambda_l1,
+                                    hp.lambda_l2, hp.max_delta_step)
+                    + leaf_split_gain(rg, rh + 1e-15, hp.lambda_l1,
+                                      hp.lambda_l2, hp.max_delta_step)
+                    - leaf_split_gain(state.leaf_sum_g[wl],
+                                      state.leaf_sum_h[wl] + 2e-15,
+                                      hp.lambda_l1, hp.lambda_l2,
+                                      hp.max_delta_step))
+                pos = jnp.where(active, state.n_splits, L - 1)
+                rec = state.rec
+                rec = rec._replace(
+                    num_leaves=rec.num_leaves + 1,
+                    split_leaf=rec.split_leaf.at[pos].set(wl, mode="drop"),
+                    split_feature=rec.split_feature.at[pos].set(
+                        feat, mode="drop"),
+                    split_bin=rec.split_bin.at[pos].set(tbin, mode="drop"),
+                    split_gain=rec.split_gain.at[pos].set(
+                        forced_gain, mode="drop"),
+                    split_default_left=rec.split_default_left.at[pos].set(
+                        dleft, mode="drop"),
+                    internal_value=rec.internal_value.at[pos].set(
+                        parent_out, mode="drop"),
+                    internal_count=rec.internal_count.at[pos].set(
+                        state.leaf_count[wl], mode="drop"),
+                )
+                child_depth = state.leaf_depth[wl] + 1
 
-            def updf(arr, lv, rv):
-                arr = arr.at[wl_s].set(lv, mode="drop")
-                return arr.at[new_s].set(rv, mode="drop")
-            # empty-child guard: the reference refuses degenerate
-            # forced splits (ForceSplits count checks); here the empty
-            # side just gets a zero output instead of -0/0 = NaN
-            lo = jnp.where(lcnt > 0, calculate_leaf_output(
-                lg, lh + 1e-15, hp.lambda_l1, hp.lambda_l2,
-                hp.max_delta_step), 0.0)
-            ro = jnp.where(rcnt > 0, calculate_leaf_output(
-                rg, rh + 1e-15, hp.lambda_l1, hp.lambda_l2,
-                hp.max_delta_step), 0.0)
-            hists2 = jnp.concatenate([hist_left, hist_right], axis=0)
-            sg2 = jnp.concatenate([lg, rg])
-            sh2 = jnp.concatenate([lh, rh])
-            nd2 = jnp.concatenate([lcnt, rcnt])
-            can2 = jnp.concatenate([active & depth_ok(child_depth)] * 2)
-            res = split_fn(hists2, sg2, sh2, nd2, feature_mask, can2,
-                           meta)
-            gain2 = jnp.where(jnp.isfinite(res.gain), res.gain,
-                              KMIN_SCORE)
-            idx2 = jnp.concatenate([wl_s, new_s])
-            act2 = jnp.concatenate([active] * 2)
-            st = lambda tbl, v: _store_batch(tbl, idx2, v, act2)
-            state = state._replace(
-                leaf_ids=leaf_ids,
-                hist=pool,
-                t_gain=st(state.t_gain, gain2),
-                t_feature=st(state.t_feature, res.feature),
-                t_bin=st(state.t_bin, res.threshold_bin),
-                t_default_left=st(state.t_default_left,
-                                  res.default_left),
-                t_left_output=st(state.t_left_output, res.left_output),
-                t_right_output=st(state.t_right_output,
-                                  res.right_output),
-                t_left_count=st(state.t_left_count, res.left_count),
-                t_right_count=st(state.t_right_count, res.right_count),
-                t_left_sum_g=st(state.t_left_sum_g, res.left_sum_g),
-                t_left_sum_h=st(state.t_left_sum_h, res.left_sum_h),
-                t_right_sum_g=st(state.t_right_sum_g, res.right_sum_g),
-                t_right_sum_h=st(state.t_right_sum_h, res.right_sum_h),
-                t_is_cat=st(state.t_is_cat, res.is_cat),
-                t_cat_words=st(state.t_cat_words, res.cat_words),
-                leaf_output=updf(state.leaf_output, lo, ro),
-                leaf_count=updf(state.leaf_count, lcnt, rcnt),
-                leaf_sum_g=updf(state.leaf_sum_g, lg, rg),
-                leaf_sum_h=updf(state.leaf_sum_h, lh, rh),
-                leaf_depth=updf(state.leaf_depth, child_depth,
-                                child_depth),
-                num_leaves=state.num_leaves + 1,
-                n_splits=state.n_splits + 1,
-                rec=rec,
-            )
+                def updf(arr, lv, rv):
+                    arr = arr.at[wl_s].set(lv, mode="drop")
+                    return arr.at[new_s].set(rv, mode="drop")
+                # empty-child guard: the reference refuses degenerate
+                # forced splits (ForceSplits count checks); here the empty
+                # side just gets a zero output instead of -0/0 = NaN
+                lo = jnp.where(lcnt > 0, calculate_leaf_output(
+                    lg, lh + 1e-15, hp.lambda_l1, hp.lambda_l2,
+                    hp.max_delta_step), 0.0)
+                ro = jnp.where(rcnt > 0, calculate_leaf_output(
+                    rg, rh + 1e-15, hp.lambda_l1, hp.lambda_l2,
+                    hp.max_delta_step), 0.0)
+            with jax.named_scope("lgbm/wave/split_find"):
+                hists2 = jnp.concatenate([hist_left, hist_right], axis=0)
+                sg2 = jnp.concatenate([lg, rg])
+                sh2 = jnp.concatenate([lh, rh])
+                nd2 = jnp.concatenate([lcnt, rcnt])
+                can2 = jnp.concatenate([active & depth_ok(child_depth)] * 2)
+                res = split_fn(hists2, sg2, sh2, nd2, feature_mask, can2,
+                               meta)
+                gain2 = jnp.where(jnp.isfinite(res.gain), res.gain,
+                                  KMIN_SCORE)
+            with jax.named_scope("lgbm/wave/bookkeep"):
+                idx2 = jnp.concatenate([wl_s, new_s])
+                act2 = jnp.concatenate([active] * 2)
+                st = lambda tbl, v: _store_batch(tbl, idx2, v, act2)
+                state = state._replace(
+                    leaf_ids=leaf_ids,
+                    hist=pool,
+                    t_gain=st(state.t_gain, gain2),
+                    t_feature=st(state.t_feature, res.feature),
+                    t_bin=st(state.t_bin, res.threshold_bin),
+                    t_default_left=st(state.t_default_left,
+                                      res.default_left),
+                    t_left_output=st(state.t_left_output, res.left_output),
+                    t_right_output=st(state.t_right_output,
+                                      res.right_output),
+                    t_left_count=st(state.t_left_count, res.left_count),
+                    t_right_count=st(state.t_right_count, res.right_count),
+                    t_left_sum_g=st(state.t_left_sum_g, res.left_sum_g),
+                    t_left_sum_h=st(state.t_left_sum_h, res.left_sum_h),
+                    t_right_sum_g=st(state.t_right_sum_g, res.right_sum_g),
+                    t_right_sum_h=st(state.t_right_sum_h, res.right_sum_h),
+                    t_is_cat=st(state.t_is_cat, res.is_cat),
+                    t_cat_words=st(state.t_cat_words, res.cat_words),
+                    leaf_output=updf(state.leaf_output, lo, ro),
+                    leaf_count=updf(state.leaf_count, lcnt, rcnt),
+                    leaf_sum_g=updf(state.leaf_sum_g, lg, rg),
+                    leaf_sum_h=updf(state.leaf_sum_h, lh, rh),
+                    leaf_depth=updf(state.leaf_depth, child_depth,
+                                    child_depth),
+                    num_leaves=state.num_leaves + 1,
+                    n_splits=state.n_splits + 1,
+                    rec=rec,
+                )
 
         state = jax.lax.while_loop(lambda s: s.go_on, body, state)
         rec = state.rec._replace(

@@ -3,50 +3,44 @@
 TPU-native counterpart of the reference's TIMETAG instrumentation
 (reference: src/treelearner/serial_tree_learner.cpp:14-41 init/hist/
 split timers, src/boosting/gbdt.cpp:253-256 per-iteration elapsed).
-Phase accumulation lives in the obs metrics registry
-(obs/registry.py) — thread-safe, so the ingest prefetch worker can
-record from off-thread while the main thread accumulates training
-phases — and every phase lands in the run report's phase table
-(obs/recorder.py). jax dispatch is async, so a phase's bucket holds the
-HOST time it spent issuing work; queued device time lands in whichever
-later phase first synchronizes. Callers that need exact device
-attribution ``.watch(out)`` their output (sync at phase exit).
 
-When profiling is active (obs/profiler.py ProfileWindow), each phase
-additionally wraps its block in a ``jax.profiler.TraceAnnotation`` so
-the engine's phase names show up as spans in XLA/Perfetto traces.
-When the engine's own tracer is active (obs/trace.py, config
-``tpu_trace``), each phase also records a span on the calling thread's
-trace row — one file shows the ingest worker's phases interleaved with
-the main thread's.
+``phase(name)`` IS ``obs/trace.span(name)`` — the program's one span
+site — plus ``.watch``: it records name, start, end, thread, parent
+and cause, adds its seconds to the registry timer of its name
+(obs/registry.py — thread-safe, so the ingest prefetch worker records
+from off-thread while the main thread accumulates training phases;
+``seconds()``/``report()`` and the run report's phase table read
+those timers), is a ``jax.profiler.TraceAnnotation("lgbm/<name>")``
+inside ANY open profiler session (a benchmark's, ``tpu_profile_dir``'s,
+an operator's own), and an event in the ``tpu_trace`` ring (and,
+through the ring only, the flight sink) where one is configured. jax
+dispatch is async, so a
+phase's timer holds the HOST time it spent issuing work; queued device
+time lands in whichever later phase first synchronizes. Callers that
+need exact device attribution ``.watch(out)`` their output (sync at
+phase exit).
 """
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 
 from ..obs import registry as _obs
 from ..obs import trace as _trace
 from . import log
 
-# emit jax TraceAnnotations around phases (toggled by the profiler
-# window; off by default — the annotation objects are cheap but not
-# free, and most runs are not being traced)
-_annotate = False
 
+class _Phase(_trace.Span):
+    """A span that can wait for its device output before its clock
+    stops, and can mark where the device's memory peak last rose.
+    Accounting-grade: it reaches the flight sink through the
+    ``tpu_trace`` ring only (obs/trace.Span.to_sinks)."""
+    __slots__ = ("out", "mem_peak")
+    to_sinks = False
 
-def set_trace_annotations(on: bool) -> None:
-    global _annotate
-    _annotate = bool(on)
-
-
-class _PhaseHandle:
-    """Yielded by ``phase``; lets device phases register the output
-    whose completion the phase should wait for at exit."""
-    __slots__ = ("out",)
-
-    def __init__(self):
+    def __init__(self, name, cat, args, mem_peak):
+        super().__init__(name, cat, args)
         self.out = None
+        self.mem_peak = mem_peak
 
     def watch(self, out):
         """Register a (pytree of) device array(s): the phase blocks on
@@ -55,47 +49,61 @@ class _PhaseHandle:
         self.out = out
         return out
 
+    def __exit__(self, *exc):
+        if self.out is not None:
+            _sync(self.out)
+        swallow = super().__exit__(*exc)
+        if self.mem_peak:           # after the clock has stopped
+            mark_mem_peak(self.name)
+        return swallow
 
-@contextmanager
-def phase(name: str):
-    """Accumulate the wall time spent inside the block.
+
+def phase(name: str, cat: str = "phase", args=None,
+          mem_peak: bool = False) -> _Phase:
+    """``with phase("binning/bin_matrix") as ph:`` — a span
+    (obs/trace.span) whose handle can ``.watch(out)``.
 
     jax dispatch is async: a phase that merely ISSUES device work
     records only the issue time, and the device time lands in whichever
     later phase first synchronizes — silently misattributed. Device
     phases therefore ``.watch(out)`` their output on the yielded
     handle, which forces completion at phase exit, before the clock
-    stops."""
-    ann = None
-    if _annotate:
-        try:
-            import jax
-            ann = jax.profiler.TraceAnnotation(f"lgbm/{name}")
-            ann.__enter__()
-        except Exception:               # noqa: BLE001 — annotation is
-            ann = None                  # an aid, never a failure mode
-    tracer = _trace.active()
-    span_t0 = tracer.now_us() if tracer is not None else 0.0
-    t0 = time.monotonic()
-    h = _PhaseHandle()
+    stops.
+
+    ``mem_peak`` (the coarse set-up phases only) sets the gauge
+    ``mem/peak_bytes@<name>`` at exit from the fullest local device's
+    ``peak_bytes_in_use``: the high-water mark is monotone, so the
+    phase at which it last rose is where the process's peak comes
+    from."""
+    return _Phase(name, cat, args, mem_peak)
+
+
+def mark_mem_peak(name: str) -> None:
+    """Set ``mem/peak_bytes@<name>`` (``phase(mem_peak=True)`` does it at
+    its exit; a ``trace.span`` site calls it after its block)."""
+    peak = _device_peak_bytes()
+    if peak is not None:
+        # bounded-cardinality: the four coarse set-up phases
+        _obs.gauge(f"mem/peak_bytes@{name}").set(peak)
+
+
+def _device_peak_bytes():
+    """``peak_bytes_in_use`` of the fullest local device; None where
+    the backend keeps no memory statistics (the CPU), and while jax
+    has brought no backend up yet — a host-only phase must not be the
+    one that initializes it (jax.distributed has to come first)."""
     try:
-        yield h
-    finally:
-        if h.out is not None:
-            _sync(h.out)
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:           # noqa: BLE001
-                pass
-        # bounded-cardinality: phase names are call-site string
-        # literals (the timing.phase sites in this repo)
-        _obs.timer(name).add(time.monotonic() - t0)
-        if tracer is not None:
-            # same block, same clock stop: every phase is also a span
-            # in the cross-thread trace (obs/trace.py) — the ingest
-            # worker's phases land on their own tid row
-            tracer.complete(name, "phase", span_t0)
+        import jax
+        # no public probe says "is a backend up" without bringing one up
+        from jax._src.xla_bridge import backends_are_initialized
+    except ImportError:             # the gauge is an aid: without the
+        return None                 # probe it stays unset, nothing breaks
+    if not backends_are_initialized():
+        return None
+    peaks = [s["peak_bytes_in_use"]
+             for s in (d.memory_stats() for d in jax.local_devices())
+             if s and "peak_bytes_in_use" in s]
+    return max(peaks) if peaks else None
 
 
 def add(name: str, seconds: float) -> None:

@@ -873,7 +873,7 @@ def test_run_report_meta_gains_trace_path(tmp_path):
         doc = json.load(open(trace_path))
         names = {e["name"] for e in doc["traceEvents"]
                  if e["ph"] == "X"}
-        assert "iteration" in names
+        assert "train/iteration" in names
         assert "train/step_dispatch" in names
     finally:
         trace.stop()

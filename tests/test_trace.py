@@ -332,10 +332,15 @@ def test_lrb_two_window_trace_end_to_end(tmp_path):
     assert len({e["tid"] for e in spans}) >= 2, \
         "expected spans from main + ingest worker threads"
     names = {e["name"] for e in spans}
-    assert {"window", "lrb/derive", "lrb/train", "iteration",
+    assert {"window", "lrb/derive", "lrb/train", "train/iteration",
             "ingest/prep_chunk", "ingest/chunk"} <= names, names
     # the ingest worker's spans are on a different tid than the window
     win_tids = {e["tid"] for e in spans if e["name"] == "window"}
     prep_tids = {e["tid"] for e in spans
                  if e["name"] == "ingest/prep_chunk"}
     assert prep_tids and not (prep_tids & win_tids)
+    # ... and each names the bin_matrix span that queued it as its cause
+    bin_ids = {e["args"]["id"] for e in spans
+               if e["name"] == "binning/bin_matrix"}
+    assert {e["args"]["cause"] for e in spans
+            if e["name"] == "ingest/prep_chunk"} <= bin_ids
