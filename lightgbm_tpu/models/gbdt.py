@@ -54,6 +54,14 @@ from .tree import Tree, tree_from_record
 K_MODEL_VERSION = "v2"     # gbdt.h kModelVersion
 
 
+@jax.jit
+def _tail_summary(num_leaves, wave_work):
+    """[R, 3] int32 rows (num_leaves, wave_work) of R records' scalars
+    and [2] vectors, stacked on the device for one download."""
+    return jnp.concatenate([jnp.stack(num_leaves)[:, None],
+                            jnp.stack(wave_work)], axis=1)
+
+
 class GBDT:
     """Gradient Boosting Decision Tree driver (boosting.h:22 interface)."""
 
@@ -283,6 +291,12 @@ class GBDT:
         # number of leading iteration-groups already verified productive,
         # so each periodic stop check scans only the new tail
         self._clean_groups = 0
+        # compile the stop check's one stacked download here, in set-up,
+        # not an interval into training: it always stacks an interval's
+        # records, a shorter tail padded (_tail_host; a mesh's replicated
+        # records compile their own at the first check)
+        self._tail_pad = (jnp.zeros((), jnp.int32), jnp.zeros(2, jnp.int32))
+        self._tail_host([])
         # fused-step state (see _get_step_fn)
         self._step_key = None
         self._zero_bias = jnp.zeros(self.num_tree_per_iteration,
@@ -1711,7 +1725,7 @@ class GBDT:
         recs = self.records[start_group * K:]
         if not recs:
             return [], []
-        nl = self._num_leaves_host(recs)
+        nl = self._tail_host(recs)[0]
         leaves = nl.reshape(-1, K).tolist()
         W = max(self._grower_cfg.wave_size, 1)
         waves = [sum(max(-(-(int(l) - 1) // W), 1) for l in grp)
@@ -1822,12 +1836,21 @@ class GBDT:
         K = self.num_tree_per_iteration
         return [(int(w) + K) * per_pass for w in waves]
 
-    def _num_leaves_host(self, records) -> np.ndarray:
-        """Download num_leaves for a list of records in ONE transfer."""
-        if not records:
-            return np.zeros(0, np.int32)
-        stacked = jnp.stack([r.num_leaves for r in records])
-        return np.asarray(stacked)
+    def _tail_host(self, records):
+        """(num_leaves [R], wave_work [R, 2]) of a list of records in
+        ONE transfer: what the stop check reads."""
+        R = self._stop_check_interval * self.num_tree_per_iteration
+        parts = []
+        for i in range(0, max(len(records), 1), R):
+            part = records[i:i + R]
+            pad = R - len(part)
+            parts.append(np.asarray(_tail_summary(
+                tuple(r.num_leaves for r in part)
+                + (self._tail_pad[0],) * pad,
+                tuple(r.wave_work for r in part)
+                + (self._tail_pad[1],) * pad))[:len(part)])
+        a = np.concatenate(parts)
+        return a[:, 0], a[:, 1:]
 
     def _drop_last_iterations(self, n_groups: int) -> None:
         """Remove the last ``n_groups`` boosting iterations AND subtract
@@ -1864,13 +1887,22 @@ class GBDT:
         if num_groups <= self._clean_groups:
             return None
         tail = self.records[self._clean_groups * K:num_groups * K]
-        nl = self._num_leaves_host(tail)
+        nl, work = self._tail_host(tail)
         groups = nl.reshape(-1, K)
-        for i in range(len(groups)):
-            if (groups[i] <= 1).all():
-                return self._clean_groups + i
-            self._clean_groups += 1
-        return None
+        splitless = [i for i in range(len(groups))
+                     if (groups[i] <= 1).all()]
+        n_clean = splitless[0] if splitless else len(groups)
+        first = self._clean_groups + n_clean if splitless else None
+        self._clean_groups += n_clean
+        # the verified trees' wave-pass work rode the same download
+        from ..obs import registry as obs
+        from ..ops.hist_wave import COMPACT_TILE_UNIT
+        rows = work[:n_clean * K].astype(np.int64).sum(axis=0) \
+            * COMPACT_TILE_UNIT
+        obs.counter("hist/rows_scanned").add(int(rows[0]))
+        obs.counter("hist/rows_dotted").add(int(rows[1]))
+        obs.counter("hist/trees_counted").add(n_clean * K)
+        return first
 
     def _trim_at_splitless(self, gi: int) -> None:
         """Drop the splitless iteration ``gi`` and everything after it.

@@ -121,10 +121,52 @@ def wave_hist_block_shapes(*, chunk: int, geom: Dict[str, int]
     }
 
 
+# rows of one dotted tile, and of one sub-tile of the scan, of the fused
+# kernel's row compaction (ops/hist_wave.py _fused_kernel)
+HIST_COMPACT_TILE = 512
+# The compaction pays where a row through the one-hot dot is dear
+# against a row through the scan (a [2T, T] one-hot and two small dots a
+# sub-tile, ~2 ns a row): the dot's MACs a row, groups x gb x 128 (the
+# int8 tiers' at half, the MXU's int8 rate being twice its bf16 rate),
+# at or above this. Measured on a v5e (PERF.md section 6, PR 27, calls
+# A and B; masked ns a row against scan + dot x share of rows that
+# contribute): 2,195,456 MACs (67 features x 255 bins, hilo5) 24.2
+# against 2.7 + 22.7 x share; 917,504 (28 x 255) 10.9 against 2.2 +
+# 9.6 x share; 229,376 (28 x 63, hilo5) 2.87 against 1.87 + 2.59 x
+# share: ahead only under a share of 0.39, which a small tree's waves
+# pass; 114,688 (28 x 63, int8) 2.16 against 2.02 + 1.69 x share: behind
+# from 0.08 on. The threshold asks for a break-even share of a half or
+# more (a wave's smaller children never hold more than half the rows).
+HIST_COMPACT_MIN_MACS = 1 << 19
+# rows of the compaction's payload block ahead of the bin rows (one
+# packed bf16 sublane tile; ops/hist_wave.py _PAY_ROWS)
+HIST_COMPACT_PAY_ROWS = 16
+
+
+def hist_compact_tile(*, geom: Dict[str, int], chunk: int,
+                      bins_bytes: int = 1, int8: bool = False,
+                      force: Optional[bool] = None) -> int:
+    """Rows T of the tiles the fused kernel dots after compacting a
+    chunk's contributing rows, or 0 where it dots the whole chunk
+    under zero weights instead. A trace-time choice from what the
+    shapes say — the dot's MACs a row against HIST_COMPACT_MIN_MACS —
+    and from what the gather can carry exactly: byte bins of at most
+    256 levels (bf16-exact). ``force`` overrides the cost rule only."""
+    T = math.gcd(chunk, HIST_COMPACT_TILE)
+    if bins_bytes != 1 or geom["Bp"] > 256 or T < 128:
+        return 0
+    if force is None:
+        force = (geom["groups"] * geom["gb"] * 128 // (2 if int8 else 1)
+                 >= HIST_COMPACT_MIN_MACS)
+    return T if force else 0
+
+
 def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
-                            tbl_rows: int) -> Dict[str, tuple]:
-    """VMEM block shapes of fused_partition_histogram_pallas."""
-    return {
+                            tbl_rows: int, compact_tile: int = 0
+                            ) -> Dict[str, tuple]:
+    """VMEM block shapes of fused_partition_histogram_pallas; with
+    ``compact_tile`` also its compaction scratch."""
+    s = {
         "tbl": (128, tbl_rows),                           # i32 const
         "bins": (geom["F_rows"], chunk),                  # grid-indexed
         "ghm": (4, chunk),                                # grid-indexed
@@ -133,6 +175,14 @@ def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
         "leaf_out": (1, chunk),                           # grid-indexed
         "cnt": (geom["wp"], 128),                         # accumulator
     }
+    if compact_tile:
+        c = HIST_COMPACT_PAY_ROWS + _round_up(geom["F_rows"], 16)
+        s.update({
+            "x": (c, chunk),                 # bf16 payload + bin rows
+            "sel": (1, chunk),               # f32 0/1 contributes
+            "staged": (c, 2 * compact_tile),  # f32, across grid steps
+        })
+    return s
 
 
 def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
@@ -147,18 +197,25 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
     accumulator, and — fused — the [W, chunk] partition intermediates).
     ``variant="hilo4"`` adds the second histogram-shaped count
     accumulator (and its per-group matmul result) the exact-tier
-    count dot writes.
+    count dot writes. Where the fused kernel compacts
+    (hist_compact_tile) the one-hot tile and weight rows are T wide,
+    not chunk wide, and the compaction's scratch and temporaries (the
+    payload rows, the [2T, T] one-hot with its i32 compare, the rank
+    matrix, the gathered [C, 2T] result) are added.
     """
     oh_bytes = 1 if int8 else 2                  # int8 / bf16 one-hot
     acc_bytes = 4                                # i32 / f32 accumulator
+    n_dot = chunk                                # rows of one dot
     if fused:
         if tbl_rows is None:
             # the kernel's split-table row count is the kernel's to
             # define (lazy: hist_wave imports this module at top level)
             from .hist_wave import TBL_ROWS
             tbl_rows = TBL_ROWS
+        T = hist_compact_tile(geom=geom, chunk=chunk,
+                              bins_bytes=bins_bytes, int8=int8)
         s = fused_hist_block_shapes(chunk=chunk, geom=geom,
-                                    tbl_rows=tbl_rows)
+                                    tbl_rows=tbl_rows, compact_tile=T)
         b = (2 * _nelem(s["bins"]) * bins_bytes
              + 2 * _nelem(s["ghm"]) * 4
              + 2 * _nelem(s["leaf"]) * 4
@@ -169,21 +226,31 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
         # partition temporaries: cols / sentinel compares / moved, all
         # [W, chunk] i32-grade, ~4 live at once
         b += 4 * W * chunk * 4
+        if T:
+            n_dot = T
+            b += (_nelem(s["x"]) * 2 + 8 * chunk * 4   # sel: 8 sublanes
+                  + _nelem(s["staged"]) * 4
+                  # payload rows (f32, then bf16), the bins' i32 + bf16
+                  + HIST_COMPACT_PAY_ROWS * chunk * 6
+                  + geom["F_rows"] * chunk * 6
+                  + T * T * 6                          # rank matrix
+                  + 2 * T * T * 6                      # [2T, T] one-hot
+                  + _nelem(s["staged"]) * 4)           # gathered result
     else:
         s = wave_hist_block_shapes(chunk=chunk, geom=geom)
         b = (2 * _nelem(s["bins"]) * bins_bytes
              + 2 * _nelem(s["ghl"]) * 4
              + _nelem(s["wl"]) * 4
              + _nelem(s["hist"]) * acc_bytes)
-    b += (geom["gb"] * chunk * oh_bytes          # one-hot tile
-          + 128 * chunk * 4                      # weight rows
+    b += (geom["gb"] * n_dot * oh_bytes          # one-hot tile
+          + 128 * n_dot * 4                      # weight rows
           + geom["gb_pad"] * 128 * acc_bytes)    # per-group matmul acc
     if variant == "hilo4":
         # the count dot's accumulator ref + per-group result + the
         # f32 membership rows it contracts against
         b += (_nelem((geom["groups"], geom["gb_pad"], 128)) * 4
               + geom["gb_pad"] * 128 * 4
-              + 128 * chunk * 4)
+              + 128 * n_dot * 4)
     return b
 
 
@@ -375,7 +442,11 @@ def tune_hist_route(*, backend: Optional[str] = None,
 # Tuning cache (versioned JSON on disk)
 # ---------------------------------------------------------------------------
 
-TUNING_CACHE_VERSION = 1
+# 3: the fused kernel compacts rows ahead of its dot, and the tuner times
+# it over 2^20 rows at a stated share of contributing rows (2 was that
+# kernel timed over 65,536 rows: files of it exist on machines PR 27
+# measured on, and hold the wrong exact-tier layout)
+TUNING_CACHE_VERSION = 3
 
 
 def default_cache_dir() -> str:
@@ -825,7 +896,11 @@ def _exact_tier_measure_fn(*, F, B, any_cat, bins_bytes, n_rows):
     """measure(candidate) for the exact-tier layouts: the fused kernel
     at the candidate's own wave cap, per-split-normalized (wall / W) —
     a layout that spends 1.5x the MXU per pass but buys 1.33x the wave
-    width must win or lose on the quotient, not the raw wall."""
+    width must win or lose on the quotient, not the raw wall. The rows
+    that contribute are W / 96 of all (a quarter at hilo5's 24): a
+    tree's smaller children hold the same rows whatever the wave
+    width, so a wider wave's pass holds proportionally more of them,
+    and wall / W then reads scan / W + dot x rows / 96."""
     def measure(cand):
         v = cand["variant"]
         W = EXACT_TIER_CAPS[v]
@@ -835,7 +910,7 @@ def _exact_tier_measure_fn(*, F, B, any_cat, bins_bytes, n_rows):
             count_proxy=False, packed4=False, any_cat=any_cat,
             bins_bytes=bins_bytes,
             n_meas=_hist_measure_rows(chunk_c, F, bins_bytes),
-            variant=v)
+            variant=v, share=W / 96.0)
         return fn(chunk_c[0]) / W
     return measure
 
@@ -1095,9 +1170,14 @@ def measure_psum_s(mesh, shape, dtype) -> float:
 
 def _hist_measure_rows(cands: List[dict], F: int, bins_bytes: int) -> int:
     """Measurement row count: a multiple of every candidate chunk,
-    capped so the synthetic bin matrix stays small."""
+    capped so the synthetic bin matrix stays small — and 2^20 rows
+    where that allows, so that what a pass costs a row outweighs what
+    it costs once (the accumulators' write-back and re-layout): at
+    65,536 rows the exact-tier layouts timed within noise of each
+    other and a cold tune took the one that runs an iteration 1.74x
+    slower (PERF.md section 6, PR 27, call C)."""
     top = max(c["chunk"] for c in cands)
-    n = max(top, 65536)
+    n = max(top, 1 << 20)
     while n > top and F * n * bins_bytes > (512 << 20):
         n //= 2
     return n
@@ -1106,11 +1186,12 @@ def _hist_measure_rows(cands: List[dict], F: int, bins_bytes: int) -> int:
 def _hist_measure_fn(*, fused: bool, F: int, B: int, W: int,
                      precision: str, count_proxy: bool, packed4: bool,
                      any_cat: bool, bins_bytes: int, n_meas: int,
-                     variant: str = "hilo5"):
+                     variant: str = "hilo5", share: float = 0.25):
     """Build measure(candidate) for the histogram kernels: synthetic
     data of the real (F, B, tier) shape, one warm-up call per candidate
     (compiles; the persistent compile cache makes reruns cheap), then
-    median-of-k wall time with a device-sync readback."""
+    median-of-k wall time with a device-sync readback. ``share`` of
+    the rows contribute to the fused pass's histograms."""
     import numpy as np
 
     import jax.numpy as jnp
@@ -1136,17 +1217,20 @@ def _hist_measure_fn(*, fused: bool, F: int, B: int, W: int,
     leaf_ids = jnp.zeros(n_meas, jnp.int32)
     if fused:
         mask = jnp.ones(n_meas, jnp.float32)
-        # one active slot splitting leaf 0 at mid-bin — representative
-        # work (the MXU dots are dense regardless of slot activity)
+        # one active slot splitting leaf 0 ``share`` of the way up the
+        # (uniform) bins; the left child keeps the parent's id and is
+        # the smaller one, so about that share of the rows contribute:
+        # where the kernel compacts, its time hangs on it (a 255-leaf
+        # tree's wave passes average 0.19 at 24 slots, its first 0.5)
         col = np.full(W, -1, np.int32)
         tbl = np.zeros((18, W), np.int32)
         tbl[0] = col                     # TBL_PARENT
         tbl[1] = col                     # TBL_NEW
         tbl[0, 0], tbl[1, 0] = 0, 1
-        tbl[3, 0] = B // 2               # TBL_BIN
+        tbl[3, 0] = max(int(B * share) - 1, 0)   # TBL_BIN
         tbl[7] = B                       # TBL_NUMBIN
         tbl[8] = col                     # TBL_SMALL
-        tbl[8, 0] = 1
+        tbl[8, 0] = 0
         tbl_d = jnp.asarray(tbl)
 
         def run(chunk):

@@ -59,6 +59,12 @@ class TreeRecord(NamedTuple):
     internal_count: jax.Array      # [L-1]
     split_is_cat: jax.Array        # [L-1] bool categorical split flag
     split_cat_words: jax.Array     # [L-1, 8] int32 left-set bin bitset
+    # [2] int32: rows the fused TPU kernel's wave passes scanned, and
+    # rows they put through the one-hot dot, for this tree, both in
+    # units of hist_wave.COMPACT_TILE_UNIT rows (zeros on every other
+    # route). Not part of the model: pack_record leaves it out; the
+    # stop check reads it beside num_leaves (models/gbdt.py).
+    wave_work: jax.Array
 
 
 @jax.jit
@@ -290,6 +296,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 internal_count=jnp.zeros(L - 1, f32),
                 split_is_cat=jnp.zeros(L - 1, bool),
                 split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
+                wave_work=jnp.zeros(2, jnp.int32),
             ),
         )
 

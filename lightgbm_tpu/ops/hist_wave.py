@@ -49,16 +49,17 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _feature_row(bins_ref, f: int, cache: dict, packed4: bool):
+def _feature_row(get_row, f: int, cache: dict, packed4: bool):
     """Logical feature ``f``'s bin row as i32 lanes (shared by the wave
-    and fused kernels). 4-bit tier: two features per byte row (feature
-    2p in the low nibble of row p); each byte row is widened once per
-    kernel invocation via ``cache``."""
+    and fused kernels). ``get_row(r)`` reads stored bin row ``r`` as
+    i32 lanes. 4-bit tier: two features per byte row (feature 2p in
+    the low nibble of row p); each byte row is widened once per kernel
+    invocation via ``cache``."""
     if not packed4:
-        return bins_ref[f, :].astype(jnp.int32)
+        return get_row(f)
     pr = f // 2
     if pr not in cache:
-        cache[pr] = bins_ref[pr, :].astype(jnp.int32)
+        cache[pr] = get_row(pr)
     r = cache[pr]
     return (jax.lax.shift_right_logical(r, 4) if f % 2
             else jnp.bitwise_and(r, 15))
@@ -429,7 +430,9 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
         for sidx in range(group_sz):
             f = p * group_sz + sidx
             if f < F:
-                row = _feature_row(bins_ref, f, rows_cache, packed4)
+                row = _feature_row(
+                    lambda r: bins_ref[r, :].astype(jnp.int32), f,
+                    rows_cache, packed4)
                 blocks.append(
                     (row[None, :] == bin_iota).astype(oh_dt))
             else:
@@ -710,9 +713,10 @@ FUSED_MAX_WAVE_INT8_NC = 64  # 2 channels (count-proxy mode: the MXU dot
 
 
 def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
-                  hist_ref, leaf_out_ref, *maybe_cnt, F, B, W, groups,
-                  group_sz, variant, exact_dot=False, int8=False,
-                  any_cat=True, count_proxy=False, packed4=False):
+                  hist_ref, leaf_out_ref, tiles_ref, *rest, F, B, W,
+                  groups, group_sz, variant, exact_dot=False, int8=False,
+                  any_cat=True, count_proxy=False, packed4=False,
+                  compact_tile=0):
     """One grid step: partition one row chunk by the wave's W splits,
     then accumulate the wave's smaller-child histograms — ONE data pass.
 
@@ -733,24 +737,38 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
                out-of-bag included)
     hist_ref:  [groups, gb_pad, 128] accumulated histograms
     leaf_out_ref: [1, Ct] i32 leaf ids AFTER this wave
+    tiles_ref: [1] i32 (SMEM) rows put through the one-hot dot, in
+               units of COMPACT_TILE_UNIT
+    rest:      the count accumulator (count_proxy / "hilo4"), then —
+               with ``compact_tile`` — the compaction's scratch
 
     Channel layout: the exact tier (tpu_use_dp) rides one of the
     ``variant`` layouts of _wave_hist_kernel — "hilo5"
     ([g_hi | g_lo | h_hi | h_lo | count] x W, W <= 24), "hilo4" (the
-    count channel moves to a second dot into ``maybe_cnt``, W <= 32)
-    or "hilo3" (the fused hess/count plane for constant-unit-hessian
-    objectives, W <= 40) — all with exact bf16 products and f32-grade
-    hi + lo reconstruction. ``variant=None`` (precision="default"):
-    [g_hi | g_lo | h | count] x W (W <= 32), hessian single bf16
-    (2^-9 relative rounding). Counts exact in every layout.
+    count channel moves to a second dot into the count accumulator,
+    W <= 32) or "hilo3" (the fused hess/count plane for
+    constant-unit-hessian objectives, W <= 40) — all with exact bf16
+    products and f32-grade hi + lo reconstruction. ``variant=None``
+    (precision="default"): [g_hi | g_lo | h | count] x W (W <= 32),
+    hessian single bf16 (2^-9 relative rounding). Counts exact in
+    every layout.
+
+    ``compact_tile`` = T > 0: only the rows that can contribute reach
+    the dot, T at a time (see "stable row compaction" below); 0: every
+    row of the chunk does, with zero weights where it contributes
+    nothing. autotune.hist_compact_tile chooses, from the dot's cost
+    a row.
     """
     step = pl.program_id(0)
-    cnt_ref = (maybe_cnt[0] if count_proxy or variant == "hilo4"
-               else None)
+    T = compact_tile
+    has_cnt = count_proxy or variant == "hilo4"
+    cnt_ref = rest[0] if has_cnt else None
+    scratch = rest[1:] if has_cnt else rest
 
     @pl.when(step == 0)
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
+        tiles_ref[0] = 0
         if cnt_ref is not None:
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
@@ -869,55 +887,178 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
     gvec = ghm_ref[0:1, :]
     hvec = ghm_ref[1:2, :]
     mvec = ghm_ref[2:3, :]
-    # (small -1 slots likewise only match zero-weight padded tail rows)
-    m = (leaf_new == small_c).astype(jnp.float32)           # [W, Ct]
     if count_proxy:
         # exact per-slot right-child counts from the partition mask:
         # the count CHANNEL is gone from the MXU dot, but the exact
         # in-bag row count of every new (right) child falls out of
         # `moved` for the cost of one [W, Ct] reduce — wave_grower
         # derives the left side as parent - right and synthesizes the
-        # per-bin count estimates from the hessian channel
+        # per-bin count estimates from the hessian channel. Taken from
+        # the partition, BEFORE any compaction: every row counts.
         mvd = moved.astype(jnp.float32) * mvec              # [W, Ct]
         s = jnp.sum(mvd, axis=1, keepdims=True)             # [W, 1]
         wp_c = cnt_ref.shape[0]
         if wp_c != W:
             s = jnp.pad(s, ((0, wp_c - W), (0, 0)))
         cnt_ref[...] += jnp.broadcast_to(s, cnt_ref.shape)
+    chan = _channel_rows(gvec, hvec, mvec, variant=variant, int8=int8,
+                         count_proxy=count_proxy)
+    acc_kw = dict(F=F, B=B, groups=groups, group_sz=group_sz,
+                  variant=variant, exact_dot=exact_dot, int8=int8,
+                  packed4=packed4)
+    hist_cnt_ref = cnt_ref if variant == "hilo4" else None
+    if not T:
+        # ---- masked full-chunk dot: every row of the chunk goes
+        # through the one-hot dot, rows outside the wave's smaller
+        # children with zero weight rows (small -1 slots only match
+        # zero-weight padded tail rows) ----
+        m = (leaf_new == small_c).astype(jnp.float32)       # [W, Ct]
+        _accumulate_hist(
+            lambda r: binsf_ref[r, :].astype(i32), chan, m, hist_ref,
+            hist_cnt_ref, **acc_kw)
+        tiles_ref[0] += ct // COMPACT_TILE_UNIT
+        return
+
+    # ---- stable row compaction ahead of the dot ----
+    # The partition above visits every row; the one-hot dot is what a
+    # row costs (bins x lanes MACs), and only rows that sit in one of
+    # the wave's smaller children AND carry weight can change a sum.
+    # Those rows are packed, in row order, into a [C, 2T] staging
+    # buffer that lives across grid steps; whenever T of them are
+    # there, ONE T-wide tile goes through the weight-row build and the
+    # one-hot dot. The packing is itself an MXU gather, like `cols`
+    # above: per T-row sub-tile a one-hot P[2T, T] (row t -> staging
+    # position count + its rank among the sub-tile's selected rows)
+    # contracts against the sub-tile's bin rows and channel
+    # multiplicands. Every value that passes through is exact in the
+    # dot's input type (bins <= 255; bf16-rounded channel multiplicands,
+    # the same rounding the weight rows got before; the row's 1-based
+    # slot <= 64) and meets a single 1, so the gather is exact and the
+    # histogram differs from the masked dot's only in the order of its
+    # f32 additions.
+    x_ref, sel_ref, staged_ref, cnt_smem = scratch
+    xdt = jnp.float32 if exact_dot else jnp.bfloat16
+    dot_prec = (jax.lax.Precision.HIGHEST if exact_dot
+                else jax.lax.Precision.DEFAULT)
+    S = 2 * T
+    k1_c = jax.lax.broadcasted_iota(i32, (W, 1), 0) + 1
+    slot1 = jnp.sum(jnp.where(leaf_new == small_c, k1_c, 0), axis=0,
+                    keepdims=True)                          # [1, Ct]
+    sel = (slot1 > 0) & ((mvec > 0.0) | (gvec != 0.0) | (hvec != 0.0))
+    sel_f = sel.astype(jnp.float32)
+    sel_ref[...] = sel_f
+    # payload rows [_PAY_ROWS, Ct]: the channel multiplicands, then the
+    # row's slot (0 = not selected), laid out by sublane selects (no
+    # sublane concat of 1-row pieces)
+    r_iota = jax.lax.broadcasted_iota(i32, (_PAY_ROWS, 1), 0)
+    pay = jnp.zeros((_PAY_ROWS, ct), jnp.float32)
+    for j, row in enumerate(chan + [slot1.astype(jnp.float32) * sel_f]):
+        pay = jnp.where(r_iota == j, row, pay)
+    x_ref[0:_PAY_ROWS, :] = pay.astype(xdt)
+    f_rows = binsf_ref.shape[0]
+    x_ref[_PAY_ROWS:_PAY_ROWS + f_rows, :] = \
+        binsf_ref[...].astype(i32).astype(xdt)
+    slot_row = len(chan)
+
+    @pl.when(step == 0)
+    def _():
+        staged_ref[...] = jnp.zeros_like(staged_ref)
+        cnt_smem[0] = 0
+
+    # U[i, j] = 1 where i < j: sel . U = each row's rank among the
+    # selected rows before it (0/1 products, f32 sums <= T: exact)
+    tri = (jax.lax.broadcasted_iota(i32, (T, T), 0)
+           < jax.lax.broadcasted_iota(i32, (T, T), 1)).astype(xdt)
+    s_iota = jax.lax.broadcasted_iota(i32, (S, 1), 0)
+    n_sub = ct // T
+
+    def flush():
+        m = (staged_ref[slot_row:slot_row + 1, 0:T]
+             == k1_c.astype(jnp.float32)).astype(jnp.float32)  # [W, T]
+        rows = [staged_ref[j:j + 1, 0:T] for j in range(slot_row)]
+        _accumulate_hist(
+            lambda r: staged_ref[_PAY_ROWS + r, 0:T].astype(i32), rows,
+            m, hist_ref, hist_cnt_ref, **acc_kw)
+        staged_ref[:, 0:T] = staged_ref[:, T:S]
+        staged_ref[:, T:S] = jnp.zeros((staged_ref.shape[0], T),
+                                       jnp.float32)
+        tiles_ref[0] += T // COMPACT_TILE_UNIT
+
+    def sub_tile(i, c):
+        # one more turn than there are sub-tiles in the LAST grid step:
+        # it packs nothing and flushes what is left (zero weights past
+        # `count`: the staging buffer is zero there)
+        live = i < n_sub
+        start = pl.multiple_of(jnp.minimum(i, n_sub - 1) * T, T)
+        sel_t = sel_ref[:, pl.ds(start, T)] * live.astype(jnp.float32)
+        rank = jax.lax.dot_general(
+            jnp.broadcast_to(sel_t, (_PAY_ROWS, T)).astype(xdt), tri,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=dot_prec,
+            preferred_element_type=jnp.float32)[0:1, :]     # [1, T]
+        tgt = jnp.where(sel_t > 0.0, rank.astype(i32) + c, -1)
+        perm = (s_iota == tgt).astype(xdt)                  # [S, T]
+        staged_ref[...] += jax.lax.dot_general(
+            x_ref[:, pl.ds(start, T)], perm,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=dot_prec,
+            preferred_element_type=jnp.float32)             # [C, S]
+        c = c + jnp.sum(sel_t).astype(i32)
+        full = (c >= T) | (jnp.logical_not(live) & (c > 0))
+        pl.when(full)(flush)
+        return jnp.where(full, jnp.maximum(c - T, 0), c)
+
+    last = step == pl.num_programs(0) - 1
+    cnt_smem[0] = jax.lax.fori_loop(
+        0, n_sub + last.astype(i32), sub_tile, cnt_smem[0])
+
+
+# rows of the compaction payload block ahead of the bin rows in the
+# staging layout: <= 5 channel multiplicands + the slot, padded to one
+# packed bf16 sublane tile so the bin rows start tile-aligned
+_PAY_ROWS = autotune.HIST_COMPACT_PAY_ROWS
+# the unit `tiles` counts in (rows): a pass that compacts reports
+# T / unit per dotted tile, one that does not reports chunk / unit per
+# grid step, so rows_dotted = tiles * unit either way and stays int32-
+# exact (the rows themselves would not, past 2^31 / waves rows)
+COMPACT_TILE_UNIT = 128
+
+
+def _channel_rows(gvec, hvec, mvec, *, variant, int8, count_proxy):
+    """The [1, n] per-row multiplicands of a wave slot's MXU weight
+    rows, in channel order (see _fused_kernel's channel layouts). The
+    weight row of slot k, channel c is ``m[k] * rows[c]``. For "hilo4"
+    the LAST row (the mask) feeds the second, count dot instead."""
     if int8 and count_proxy:
         # 2 channels x W <= 128 lanes -> waves up to 64 leaves wide,
         # cutting full-data passes per tree (the count channel's lane
         # budget bought more wave width than the counts were worth)
-        w_rows = jnp.concatenate([m * gvec, m * hvec], axis=0)  # [2W, Ct]
-    elif int8:
+        return [gvec, hvec]
+    if int8:
         # quantized mode (tpu_quantized_hist): gvec/hvec hold integers
         # in [-127, 127]; int8 MXU products, exact int32 sums, 2x rate
-        w_rows = jnp.concatenate(
-            [m * gvec, m * hvec, m * mvec], axis=0)          # [3W, Ct]
-    elif variant == "hilo5":
-        g_hi, g_lo = _bf16_split(gvec)
-        h_hi, h_lo = _bf16_split(hvec)
-        w_rows = jnp.concatenate(
-            [m * g_hi, m * g_lo, m * h_hi, m * h_lo, m * mvec],
-            axis=0)                                          # [5W, Ct]
-    elif variant == "hilo4":
-        # count channels move to a second dot (see _wave_hist_kernel)
-        g_hi, g_lo = _bf16_split(gvec)
-        h_hi, h_lo = _bf16_split(hvec)
-        w_rows = jnp.concatenate(
-            [m * g_hi, m * g_lo, m * h_hi, m * h_lo], axis=0)  # [4W, Ct]
-        cnt_rows = m * mvec
-    elif variant == "hilo3":
+        return [gvec, hvec, mvec]
+    g_hi, g_lo = _bf16_split(gvec)
+    if variant == "hilo3":
         # fused hess/count plane (h == mask, see _wave_hist_kernel)
-        g_hi, g_lo = _bf16_split(gvec)
-        w_rows = jnp.concatenate(
-            [m * g_hi, m * g_lo, m * mvec], axis=0)          # [3W, Ct]
-    else:
-        g_hi, g_lo = _bf16_split(gvec)
-        w_rows = jnp.concatenate(
-            [m * g_hi, m * g_lo, m * hvec, m * mvec], axis=0)  # [4W, Ct]
-    if variant != "hilo4":
-        cnt_rows = None
+        return [g_hi, g_lo, mvec]
+    if variant is None:
+        return [g_hi, g_lo, hvec, mvec]
+    h_hi, h_lo = _bf16_split(hvec)
+    # hilo5: count is the 5th channel; hilo4: it moves to a second dot
+    return [g_hi, g_lo, h_hi, h_lo, mvec]
+
+
+def _accumulate_hist(get_row, chan, m, hist_ref, cnt_ref, *, F, B, groups,
+                     group_sz, variant, exact_dot, int8, packed4):
+    """hist_ref[p] += one_hot(bins of group p) . weight rows, over the
+    ``n`` rows on the lane axis of ``m`` [W, n] (slot membership, f32
+    0/1), ``chan`` (_channel_rows) and ``get_row(r)`` (stored bin row r
+    as i32 lanes)."""
+    n = m.shape[1]
+    n_w = len(chan) - (variant == "hilo4")
+    w_rows = jnp.concatenate([m * r for r in chan[:n_w]], axis=0)
+    cnt_rows = m * chan[-1] if variant == "hilo4" else None
     nrow = w_rows.shape[0]
     if nrow != 128:
         w_rows = jnp.pad(w_rows, ((0, 128 - nrow), (0, 0)))
@@ -928,7 +1069,7 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
     # ---- one-hot tiles + lane-contracting MXU accumulate ----
     Bp = _round_up(B, 8)       # aligned per-feature stride (see
     gb = group_sz * Bp         # _wave_hist_kernel)
-    bin_iota = jax.lax.broadcasted_iota(i32, (Bp, 1), 0)
+    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (Bp, 1), 0)
     # bf16 operands halve the one-hot tile's VMEM/register footprint;
     # numerically identical to the DEFAULT bf16 MXU pass (interpret
     # mode keeps f32 for the HIGHEST-precision CPU oracle)
@@ -946,11 +1087,11 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
         for sidx in range(group_sz):
             f = p * group_sz + sidx
             if f < F:
-                row = _feature_row(binsf_ref, f, rows_cache, packed4)
+                row = _feature_row(get_row, f, rows_cache, packed4)
                 blocks.append(
                     (row[None, :] == bin_iota).astype(oh_dt))
             else:
-                blocks.append(jnp.zeros((Bp, ct), oh_dt))
+                blocks.append(jnp.zeros((Bp, n), oh_dt))
         oh_t = (blocks[0] if group_sz == 1
                 else jnp.concatenate(blocks, axis=0))
         acc = jax.lax.dot_general(
@@ -982,7 +1123,8 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
                                              "interpret", "precision",
                                              "any_cat", "count_proxy",
                                              "packed4", "num_features",
-                                             "dequant", "variant"))
+                                             "dequant", "variant",
+                                             "compact"))
 def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      leaf_ids, tbl, *, num_bins,
                                      chunk=2048, interpret=False,
@@ -990,10 +1132,20 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      gh_scale=None, any_cat=True,
                                      count_proxy=False, packed4=False,
                                      num_features=None, dequant=True,
-                                     variant="hilo5"):
+                                     variant="hilo5", compact=None):
     """Partition one wave + build its smaller-child histograms in ONE
-    data pass. Returns (new_leaf_ids [N], hist [W, F, B, 3]) — or, with
-    ``count_proxy``, (new_leaf_ids, hist [W, F, B, 2], cnt_right [W]).
+    data pass. Returns (new_leaf_ids [N], hist [W, F, B, 3], work) —
+    or, with ``count_proxy``, (new_leaf_ids, hist [W, F, B, 2],
+    cnt_right [W], work). ``work`` is a [2] int32: rows scanned and
+    rows put through the one-hot dot, in units of COMPACT_TILE_UNIT.
+
+    Only rows that sit in one of the wave's smaller children and carry
+    weight can change a sum, and the one-hot dot is what a row costs:
+    where it is dear enough (autotune.hist_compact_tile, from the
+    dot's MACs a row) the kernel packs those rows ahead of the dot and
+    dots whole tiles of them (_fused_kernel). ``compact`` = True /
+    False overrides that choice — for tests and for the measurement
+    that sets its threshold, never from a parameter.
 
     tbl: [18, W] int32 packed split table (TBL_* rows: 10 scalar
     fields + 8 categorical bitset words). g/h must be pre-masked by
@@ -1075,24 +1227,39 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                    ((0, 128 - W), (0, TBL_ROWS - tbl.shape[0])),
                    constant_values=-1)                     # [128, 16]
 
+    T = autotune.hist_compact_tile(
+        geom=geom, chunk=chunk, bins_bytes=bins_t.dtype.itemsize,
+        int8=int8, force=compact)
+    exact_dot = interpret and not int8
     kernel = functools.partial(
         _fused_kernel, F=F, B=B, W=W, groups=groups, group_sz=group_sz,
-        variant=variant, exact_dot=interpret and not int8, int8=int8,
-        any_cat=any_cat, count_proxy=count_proxy, packed4=packed4)
+        variant=variant, exact_dot=exact_dot, int8=int8,
+        any_cat=any_cat, count_proxy=count_proxy, packed4=packed4,
+        compact_tile=T)
 
     blk = autotune.fused_hist_block_shapes(chunk=chunk, geom=geom,
-                                           tbl_rows=TBL_ROWS)
+                                           tbl_rows=TBL_ROWS,
+                                           compact_tile=T)
     out_specs = [
         pl.BlockSpec(blk["hist"], lambda i: (0, 0, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec(blk["leaf_out"], lambda i: (0, i),
                      memory_space=pltpu.VMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     out_shape = [
         jax.ShapeDtypeStruct(blk["hist"],
                              jnp.int32 if int8 else jnp.float32),
         jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32),
     ]
+    scratch = []
+    if T:
+        xdt = jnp.float32 if exact_dot else jnp.bfloat16
+        scratch = [pltpu.VMEM(blk["x"], xdt),
+                   pltpu.VMEM(blk["sel"], jnp.float32),
+                   pltpu.VMEM(blk["staged"], jnp.float32),
+                   pltpu.SMEM((1,), jnp.int32)]
     if count_proxy:
         out_specs.append(pl.BlockSpec(blk["cnt"], lambda i: (0, 0),
                                       memory_space=pltpu.VMEM))
@@ -1117,11 +1284,17 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         ],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
+        scratch_shapes=scratch,
         compiler_params=autotune.tpu_compiler_params(),
         name="fused_partition_histogram_pallas",
         interpret=interpret,
     )(tblT, bins_t, ghm, leaf2d)
     hist, leaf_out = outs[0], outs[1]
+    work = jnp.stack([jnp.int32(n_pad // COMPACT_TILE_UNIT), outs[2][0]])
+    outs = outs[:2] + outs[3:]
+
+    def ret(*vals):
+        return vals + (work,)
 
     # [groups, gb_pad, 128] -> [F, B, nchan*W] -> [W, F, B, nchan'].
     # channel rows were [c*W + k]: reshape (nchan, W) then combine
@@ -1135,13 +1308,13 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
             hist = hist.astype(jnp.float32) \
                 * jnp.stack([jnp.float32(gh_scale[0]),
                              jnp.float32(gh_scale[1])])
-        return (leaf_out[0, :n], hist.transpose(2, 0, 1, 3),
-                outs[2][:W, 0])
+        return ret(leaf_out[0, :n], hist.transpose(2, 0, 1, 3),
+                   outs[2][:W, 0])
     if int8:
         hist = hist.transpose(0, 1, 3, 2)                  # [F,B,W,3]
         if dequant:
             hist = hist.astype(jnp.float32) * _qscale_vec(gh_scale)
-        return leaf_out[0, :n], hist.transpose(2, 0, 1, 3)
+        return ret(leaf_out[0, :n], hist.transpose(2, 0, 1, 3))
     if variant == "hilo5":
         hist = jnp.stack([hist[:, :, 0] + hist[:, :, 1],   # g = hi+lo
                           hist[:, :, 2] + hist[:, :, 3],   # h = hi+lo
@@ -1160,7 +1333,7 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         hist = jnp.stack([hist[:, :, 0] + hist[:, :, 1],   # g = hi+lo
                           hist[:, :, 2],                   # h (bf16)
                           hist[:, :, 3]], axis=2)          # count
-    return leaf_out[0, :n], hist.transpose(3, 0, 1, 2)
+    return ret(leaf_out[0, :n], hist.transpose(3, 0, 1, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -719,6 +719,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 internal_count=jnp.zeros(L - 1, f32),
                 split_is_cat=jnp.zeros(L - 1, bool),
                 split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
+                wave_work=jnp.zeros(2, jnp.int32),
             ),
         )
 
@@ -762,6 +763,9 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 left_smaller = lcnt <= rcnt
                 small_ids = jnp.where(left_smaller, wl, new_ids)
                 small_ids = jnp.where(active, small_ids, -1)
+                # rows this pass scanned / put through the one-hot dot:
+                # the fused TPU kernel counts them, no other route does
+                wave_work = jnp.zeros(2, jnp.int32)
                 if use_fused:
                     safe_feat = jnp.maximum(feat, 0)
                     tbl = jnp.concatenate([jnp.stack([
@@ -785,6 +789,8 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     hist_small = dq(hist_reduce_fn(hist_small))
                     if proxy:
                         cnt_r = reduce_fn(fused_out[2])
+                    if not gpu_hist:
+                        wave_work = reduce_fn(fused_out[-1])   # all shards'
                     # out-of-bag rows partition too; their g/h are pre-masked
                     # and the count channel rides on sample_mask
                 elif use_fused_xla:
@@ -850,6 +856,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
                 rec = state.rec
                 rec = rec._replace(
+                    wave_work=rec.wave_work + wave_work,
                     num_leaves=rec.num_leaves + n_act,
                     split_leaf=rec.split_leaf.at[pos].set(wl, mode="drop"),
                     split_feature=rec.split_feature.at[pos].set(

@@ -420,7 +420,7 @@ class TestTunedParity:
         tbl_d = jnp.asarray(tbl)
         outs = []
         for chunk in (256, 768):
-            leaf_o, hist = fused_partition_histogram_pallas(
+            leaf_o, hist, _ = fused_partition_histogram_pallas(
                 bins, g, h, mask, leaf, tbl_d, num_bins=64,
                 chunk=chunk, interpret=True)
             outs.append((np.asarray(leaf_o), np.asarray(hist)))

@@ -166,23 +166,60 @@ class TestFusedKernelVariants:
         return (bins_t, g, h, mask, leaf, wl, new_ids, feat, tbin,
                 dleft, meta, tbl, B, W)
 
+    @pytest.mark.parametrize("compact", [False, True],
+                             ids=["masked", "compacted"])
     @pytest.mark.parametrize("variant,unit_h", [("hilo4", False),
                                                 ("hilo3", True)])
-    def test_fused_variant_bitwise_vs_hilo5(self, variant, unit_h):
+    def test_fused_variant_bitwise_vs_hilo5(self, variant, unit_h,
+                                            compact):
+        """Whether every row of a chunk goes through the dot under
+        zero weights or only the compacted contributing rows do: the
+        layouts stay bit-equal to one another."""
         (bins_t, g, h, mask, leaf, wl, new_ids, feat, tbin, dleft,
          meta, tbl, B, W) = self._fused_case()
         if unit_h:
             h = mask.copy()         # constant-unit-hessian contract
         gm, hm = g * mask, h * mask
         base = _jx(bins_t, gm, hm, mask, leaf, tbl)
-        l5, h5 = fused_partition_histogram_pallas(
+        l5, h5, _ = fused_partition_histogram_pallas(
             *base, num_bins=B, chunk=256, interpret=True,
-            variant="hilo5")
-        lv, hv = fused_partition_histogram_pallas(
+            variant="hilo5", compact=compact)
+        lv, hv, _ = fused_partition_histogram_pallas(
             *base, num_bins=B, chunk=256, interpret=True,
-            variant=variant)
+            variant=variant, compact=compact)
         np.testing.assert_array_equal(np.asarray(lv), np.asarray(l5))
         np.testing.assert_array_equal(np.asarray(hv), np.asarray(h5))
+
+    @pytest.mark.parametrize("variant", ["hilo5", "hilo4", "hilo3"])
+    def test_compacted_packed4_fused_kernel_bitwise(self, variant):
+        """Nibble-packed bins through the compaction: the packed byte
+        rows are what the gather carries (<= 255, exact), unpacked
+        after it; same bits as unpacked bins through the same kernel."""
+        r = np.random.default_rng(6)
+        N, F, B, W = 900, 6, 16, 8
+        bins = r.integers(0, B, (F, N)).astype(np.uint8)
+        packed = (bins[0::2] | (bins[1::2] << 4)).astype(np.uint8)
+        mask = (r.uniform(size=N) > 0.3).astype(np.float32)
+        g = r.normal(size=N).astype(np.float32) * mask
+        h = mask if variant == "hilo3" else \
+            r.uniform(0.2, 1.0, N).astype(np.float32) * mask
+        leaf = r.integers(0, 4, N).astype(np.int32)
+        tbl = np.zeros((18, W), np.int32)
+        tbl[0] = [0, 1, 2, 3, -1, -1, -1, -1]
+        tbl[1] = [4, 5, 6, 7, -1, -1, -1, -1]
+        tbl[2] = r.integers(0, F, W)
+        tbl[3] = r.integers(2, B - 2, W)
+        tbl[7] = B
+        tbl[8] = tbl[1]
+        kw = dict(num_bins=B, chunk=256, interpret=True, variant=variant,
+                  compact=True)
+        lu, hu, _ = fused_partition_histogram_pallas(
+            *_jx(bins, g, h, mask, leaf, tbl), **kw)
+        lp, hp, _ = fused_partition_histogram_pallas(
+            *_jx(packed, g, h, mask, leaf, tbl), packed4=True,
+            num_features=F, **kw)
+        np.testing.assert_array_equal(np.asarray(lp), np.asarray(lu))
+        np.testing.assert_array_equal(np.asarray(hp), np.asarray(hu))
 
     def test_fused_xla_bitwise_vs_legacy_pipeline(self):
         """The XLA fused route == [apply_wave_splits ->

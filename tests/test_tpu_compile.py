@@ -88,6 +88,14 @@ _HIST_CASES = {
     # LRB window model: 53 features (+3 pad), 255 bins, 31 leaves
     "lrb-hilo4-W30": (56, 256, 30, "highest", "hilo4", False, False,
                       False, 1 << 14, 1024),
+    # the benchmark's cell (criteo_share.train): 67 features, 255 bins,
+    # the pinned 16384-row chunk, both exact layouts the tuner times.
+    # A dotted row costs enough here (autotune.hist_compact_tile) that
+    # the kernel compacts rows ahead of its dot
+    "criteo-hilo5-W24": (67, 255, 24, "highest", "hilo5", False, False,
+                         False, 1 << 16, 16384),
+    "criteo-hilo4-W32": (67, 255, 32, "highest", "hilo4", False, False,
+                         False, 1 << 16, 16384),
     # edge shapes (the retired on-chip shape sweep)
     "edge-F1": (1, 64, 14, "int8", None, True, False, False, 8192, 4096),
     "edge-4bin-packed4-oddF": (27, 16, 64, "int8", None, True, True,
@@ -157,6 +165,30 @@ def test_largest_offered_chunk_is_priced_inside_what_compiles(spec):
     top = max(c["chunk"] for c in cands)
     assert top == 32768
     case = (8, 64, 64, "int8", None, True, False, False, 1 << 16, top)
+    args, kw = _hist_args(spec, case, fused=True)
+    compiled = jax.jit(functools.partial(
+        fused_partition_histogram_pallas, **kw)).lower(*args).compile()
+    assert _mosaic(compiled) == 1
+
+
+def test_largest_offered_chunk_of_a_compacting_geometry_compiles(spec):
+    """The same question where the kernel compacts rows ahead of its dot
+    (the benchmark cell's geometry): the pricing then carries the
+    staging buffer, the payload and bin rows in bf16, the [2T, T]
+    one-hot and the gathered result, and T-wide one-hot tiles in place
+    of chunk-wide ones."""
+    from lightgbm_tpu.ops import autotune
+    from lightgbm_tpu.ops.hist_wave import \
+        fused_partition_histogram_pallas
+    F, B, W = 67, 255, 32
+    geom = autotune.hist_geometry(F=F, B=B, W=W)
+    cands = autotune.hist_chunk_candidates(
+        F=F, B=B, W=W, fused=True, variant="hilo4", n_rows=1 << 24)
+    top = max(c["chunk"] for c in cands)
+    assert top == 32768
+    assert autotune.hist_compact_tile(geom=geom, chunk=top) == \
+        autotune.HIST_COMPACT_TILE
+    case = (F, B, W, "highest", "hilo4", False, False, False, 1 << 16, top)
     args, kw = _hist_args(spec, case, fused=True)
     compiled = jax.jit(functools.partial(
         fused_partition_histogram_pallas, **kw)).lower(*args).compile()

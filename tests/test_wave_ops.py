@@ -103,7 +103,7 @@ class TestFusedKernel:
             wl, new_ids, feat, tbin, dleft.astype(np.int32),
             meta_np.missing_type[feat], meta_np.default_bin[feat],
             meta_np.num_bin[feat], small]])
-        leaf_f, hist_f = fused_partition_histogram_pallas(
+        leaf_f, hist_f, _ = fused_partition_histogram_pallas(
             jnp.asarray(bins_t), jnp.asarray(gm),
             jnp.asarray(hm), jnp.asarray(mask), jnp.asarray(leaf), tbl,
             num_bins=B, chunk=256, interpret=True)
@@ -293,7 +293,7 @@ class TestInt8Histogram:
             np.zeros(W, np.int32)]])
         leaf0 = np.where(mask > 0, leaf, 0).astype(np.int32)
         sg, sh = 0.125, 2.0
-        leaf_f, hist_f = fused_partition_histogram_pallas(
+        leaf_f, hist_f, _ = fused_partition_histogram_pallas(
             jnp.asarray(bins_t), jnp.asarray(gm), jnp.asarray(hm),
             jnp.asarray(mask), jnp.asarray(leaf0), tbl,
             num_bins=64, chunk=256, interpret=True,
@@ -372,7 +372,7 @@ class TestWideBins:
             meta_np.missing_type[feat], meta_np.default_bin[feat],
             meta_np.num_bin[feat], new_ids,
             np.zeros(W, np.int32)]])
-        leaf_f, _ = fused_partition_histogram_pallas(
+        leaf_f, *_ = fused_partition_histogram_pallas(
             jnp.asarray(bins_t), jnp.asarray(g), jnp.asarray(h),
             jnp.asarray(mask), jnp.asarray(leaf), tbl,
             num_bins=B, chunk=256, interpret=True)
@@ -428,7 +428,7 @@ class TestCountProxy:
             np.zeros(W, np.int32)]])
         leaf0 = np.where(mask > 0, leaf, 0).astype(np.int32)
         sg, sh = 0.125, 2.0
-        leaf_f, hist_f, cnt_r = fused_partition_histogram_pallas(
+        leaf_f, hist_f, cnt_r, _ = fused_partition_histogram_pallas(
             jnp.asarray(bins_t), jnp.asarray(gm), jnp.asarray(hm),
             jnp.asarray(mask), jnp.asarray(leaf0), tbl,
             num_bins=64, chunk=256, interpret=True,
@@ -619,3 +619,191 @@ class TestPacked4:
         np.testing.assert_allclose(
             np.asarray(gp.predict_raw(X[:200])),
             np.asarray(gu.predict_raw(X[:200])), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# stable row compaction ahead of the fused kernel's one-hot dot
+# ---------------------------------------------------------------------------
+
+_CT = 256          # the cases' chunk; the compaction tile is gcd(chunk, 512)
+
+
+def _compaction_case(kind):
+    """(kernel args, oracle args, B, contributing rows) of one wave.
+    In the first four cases nobody moves (thresholds past every bin),
+    the left child keeps the parent's id and is the smaller one, so the
+    contributing rows are the in-bag rows of the four parents: placed
+    by hand. The last has real splits, out-of-bag rows, zero-weight
+    passenger rows and a chunk-padded tail."""
+    r = np.random.default_rng(31)
+    F, B, W = 5, 64, 8
+    N = 1000
+    n_in = {"none": 0, "under_one_tile": 37, "exact_tiles": 2 * _CT,
+            "every_row": N, "oob_passengers_padded_tail": None}[kind]
+    bins_t = r.integers(0, 63, (F, N)).astype(np.uint8)
+    g = r.normal(size=N).astype(np.float32)
+    h = r.uniform(0.1, 1, N).astype(np.float32)
+    wl = np.array([0, 1, 2, 3, -1, -1, -1, -1], np.int32)
+    new_ids = np.array([4, 5, 6, 7, -1, -1, -1, -1], np.int32)
+    feat = r.integers(0, F, W).astype(np.int32)
+    dleft = r.integers(0, 2, W).astype(bool)
+    miss = np.array([0, 1, 2, 0, 1], np.int32)[feat]
+    defb = np.array([0, 3, 0, 0, 5], np.int32)[feat]
+    nb = np.full(W, B, np.int32)
+    if n_in is None:
+        mask = (r.uniform(size=N) > 0.3).astype(np.float32)
+        leaf = r.integers(0, 6, N).astype(np.int32)
+        leaf[leaf >= 4] += 4                          # 8, 9: not split
+        tbin = r.integers(5, 55, W).astype(np.int32)
+        small = np.where(r.uniform(size=W) < 0.5, wl, new_ids)
+        small = np.where(wl >= 0, small, -1).astype(np.int32)
+        # passengers: rows that partition but carry no weight at all
+        # (validation rows ride the training pass this way)
+        mask[::11] = 0.0
+    else:
+        mask = np.ones(N, np.float32)
+        leaf = np.full(N, 9, np.int32)                # not in the wave
+        leaf[r.choice(N, n_in, replace=False)] = \
+            r.integers(0, 4, n_in).astype(np.int32)
+        tbin = np.full(W, B + 10, np.int32)
+        miss[:] = 0
+        small = wl.copy()
+    gm, hm = g * mask, h * mask
+    tbl = np.stack([wl, new_ids, feat, tbin, dleft.astype(np.int32), miss,
+                    defb, nb, small, np.zeros(W, np.int32)])
+    kern = tuple(jnp.asarray(x) for x in
+                 (bins_t, gm, hm, mask, leaf, tbl))
+    orac = tuple(jnp.asarray(x) for x in
+                 (bins_t, gm, hm, mask, leaf, wl, new_ids, feat, tbin,
+                  dleft, np.zeros(W, bool), np.zeros((W, 8), np.int32),
+                  small, miss, defb, nb))
+    return kern, orac, B, small, mask
+
+
+@pytest.mark.parametrize("kind", ["none", "under_one_tile", "exact_tiles",
+                                  "every_row",
+                                  "oob_passengers_padded_tail"])
+def test_compacted_fused_kernel_matches_xla(kind):
+    """Only rows in the wave's smaller children reach the dot, a tile
+    at a time: leaf ids and counts bit-equal to the XLA twin, g/h
+    f32-grade, and the kernel's count of dotted rows is the
+    contributing rows rounded up to whole tiles."""
+    from lightgbm_tpu.ops.hist_wave import (COMPACT_TILE_UNIT,
+                                            fused_partition_histogram_xla)
+    kern, orac, B, small, mask = _compaction_case(kind)
+    leaf_x, hist_x = fused_partition_histogram_xla(*orac, num_bins=B)
+    leaf_c, hist_c, work = fused_partition_histogram_pallas(
+        *kern, num_bins=B, chunk=_CT, interpret=True, compact=True)
+    np.testing.assert_array_equal(np.asarray(leaf_c), np.asarray(leaf_x))
+    hc, hx = np.asarray(hist_c), np.asarray(hist_x)
+    np.testing.assert_array_equal(hc[..., 2], hx[..., 2])
+    np.testing.assert_allclose(hc, hx, atol=5e-5)
+    contributing = int((np.isin(np.asarray(leaf_x), small[small >= 0])
+                        & (mask > 0)).sum())
+    scanned, dotted = (int(v) * COMPACT_TILE_UNIT for v in work)
+    assert scanned == -(-len(mask) // _CT) * _CT
+    assert dotted == -(-contributing // _CT) * _CT
+    # the same pass without compaction dots every row it scans
+    *_, work_m = fused_partition_histogram_pallas(
+        *kern, num_bins=B, chunk=_CT, interpret=True, compact=False)
+    assert int(work_m[1]) == int(work_m[0]) == scanned // COMPACT_TILE_UNIT
+
+
+@pytest.mark.parametrize("tier", ["default", "int8", "int8_proxy",
+                                  "packed4_proxy"])
+def test_compacted_fused_kernel_vs_masked(tier):
+    """The other tiers the kernel serves: with compaction the same leaf
+    ids and counts as the masked full-chunk dot, the proxy's
+    partition-mask counts included; the integer tiers' sums the same
+    bits, the single-bf16 tier's f32 sums the same to rounding (its
+    full-mantissa hessians add up in another order)."""
+    r = np.random.default_rng(32)
+    F, W, N = 6, 8, 1500
+    B = 16 if tier == "packed4_proxy" else 64
+    bins = r.integers(0, B, (F, N)).astype(np.uint8)
+    mask = (r.uniform(size=N) > 0.25).astype(np.float32)
+    if tier == "default":
+        g = r.normal(size=N).astype(np.float32)
+        h = r.uniform(0.1, 1, N).astype(np.float32)
+        kw = dict(precision="default")
+    else:
+        g = r.integers(-127, 128, N).astype(np.float32)
+        h = r.integers(0, 128, N).astype(np.float32)
+        kw = dict(precision="int8", gh_scale=(0.5, 0.25),
+                  count_proxy=tier != "int8")
+    if tier == "packed4_proxy":
+        kw.update(packed4=True, num_features=F)
+        bins_dev = bins[0::2] | (bins[1::2] << 4)
+    else:
+        bins_dev = bins
+    leaf = r.integers(0, 5, N).astype(np.int32)
+    wl = np.array([0, 1, 2, 3, -1, -1, -1, -1], np.int32)
+    new_ids = np.array([5, 6, 7, 8, -1, -1, -1, -1], np.int32)
+    feat = r.integers(0, F, W).astype(np.int32)
+    tbl = np.stack([wl, new_ids, feat,
+                    r.integers(2, B - 2, W).astype(np.int32),
+                    np.zeros(W, np.int32), np.zeros(W, np.int32),
+                    np.zeros(W, np.int32), np.full(W, B, np.int32),
+                    new_ids, np.zeros(W, np.int32)])
+    args = tuple(jnp.asarray(x) for x in
+                 (bins_dev, g * mask, h * mask, mask, leaf, tbl))
+    outs = [fused_partition_histogram_pallas(
+        *args, num_bins=B, chunk=512, interpret=True, compact=c, **kw)
+        for c in (False, True)]
+    assert len(outs[0]) == (4 if "proxy" in tier else 3)
+    (s_m, d_m), (s_c, d_c) = (np.asarray(o[-1]) for o in outs)
+    assert s_m == d_m == s_c and 0 < d_c < d_m     # rows scanned, dotted
+    for a, b in zip(outs[0][:-1], outs[1][:-1]):
+        a, b = np.asarray(a), np.asarray(b)
+        if tier == "default" and a.ndim == 4:
+            np.testing.assert_array_equal(a[..., 2], b[..., 2])
+            np.testing.assert_allclose(a, b, atol=5e-5)
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.fixture
+def compaction_everywhere(monkeypatch):
+    """The trace-time rule (autotune.hist_compact_tile: the dot's MACs a
+    row) would not compact a test-sized geometry; lower its threshold,
+    and keep traces made under it out of the other tests' jit caches."""
+    from lightgbm_tpu.ops import autotune
+    jax.clear_caches()
+    monkeypatch.setattr(autotune, "HIST_COMPACT_MIN_MACS", 0)
+    yield
+    jax.clear_caches()
+
+
+def test_grower_counts_rows_scanned_and_dotted(compaction_everywhere):
+    """Through the whole grower: the compacted fused kernel grows the
+    XLA route's tree, and the tree's record carries the wave passes'
+    rows scanned and rows dotted (whole tiles of the smaller children's
+    rows; the model's own counts say how many those are)."""
+    from lightgbm_tpu.ops.hist_wave import COMPACT_TILE_UNIT
+    bins, grad, hess, mask, fmask, meta, B = _grower_problem()
+    bins_t = jnp.asarray(np.ascontiguousarray(bins.T))
+    n = bins.shape[0]
+    recs = {}
+    for fused in (True, False):       # the interpreted kernel, the XLA twin
+        cfg = WaveGrowerConfig(num_leaves=15, num_bins=B, wave_size=8,
+                               chunk=512, fused=fused)
+        grow = make_wave_grower(cfg, meta)
+        recs[fused], leaf = grow(bins_t, grad, hess, mask, fmask)
+        recs[fused, "leaf"] = np.asarray(leaf)
+    rec, ref = recs[True], recs[False]
+    np.testing.assert_array_equal(recs[True, "leaf"], recs[False, "leaf"])
+    np.testing.assert_array_equal(np.asarray(rec.split_feature),
+                                  np.asarray(ref.split_feature))
+    assert np.asarray(ref.wave_work).tolist() == [0, 0]
+    scanned, dotted = (int(v) * COMPACT_TILE_UNIT
+                       for v in np.asarray(rec.wave_work))
+    n_splits = int(rec.num_leaves) - 1
+    n_pad = -(-n // 512) * 512
+    waves = scanned // n_pad          # a pass scans every (padded) row
+    assert scanned == waves * n_pad and waves >= -(-n_splits // 8)
+    # each split's smaller child, from the record's own counts
+    cnt = np.asarray(rec.leaf_count)
+    ic = np.asarray(rec.internal_count)
+    assert 0 < dotted < scanned
+    assert dotted >= (n - cnt.max())        # every split's smaller side
+    assert dotted <= int(ic[:n_splits].sum() // 2) + waves * 512
