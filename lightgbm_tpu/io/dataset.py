@@ -58,6 +58,10 @@ class Metadata:
         return len(self.query_boundaries) - 1
 
 
+# columns of the bin search's row sample gathered at a time
+_SAMPLE_COLS = 64
+
+
 def find_column_mappers(X: np.ndarray, config: Config,
                         categorical=(), total_rows: Optional[int] = None,
                         columns: Optional[Sequence[int]] = None,
@@ -81,9 +85,8 @@ def find_column_mappers(X: np.ndarray, config: Config,
     n, nf = X.shape
     cfg = config
     total = n if total_rows is None else max(int(total_rows), 1)
-    if presampled:
-        sample = X
-    else:
+    idx = None                  # sampled rows; None: every row of X
+    if not presampled:
         budget = cfg.bin_construct_sample_cnt
         if total > n > 0:
             budget = max(budget * n // total, 1)   # this shard's share
@@ -91,10 +94,7 @@ def find_column_mappers(X: np.ndarray, config: Config,
         rng = np.random.default_rng(cfg.data_random_seed)
         if sample_cnt < n:
             idx = np.sort(rng.choice(n, sample_cnt, replace=False))
-            sample = X[idx]
-        else:
-            sample = X
-    snum = sample.shape[0]
+    snum = n if idx is None else len(idx)
     filter_cnt = 0
     if cfg.min_data_in_leaf > 0 and total > 0:
         # dataset_loader.cpp: filter scaled by sample/total ratio
@@ -102,11 +102,21 @@ def find_column_mappers(X: np.ndarray, config: Config,
     cats = set(categorical)
     wanted = set(range(nf)) if columns is None else set(columns)
     mappers: List[Optional[BinMapper]] = []
+    block, j0 = None, 0
     for j in range(nf):
         if j not in wanted:
             mappers.append(None)
             continue
-        col = sample[:, j].astype(np.float64)
+        if idx is None:
+            col = X[:, j].astype(np.float64)
+        else:
+            # the sampled rows, _SAMPLE_COLS columns at a time: the
+            # whole sample at once is a second matrix (3.2 GB of fresh
+            # pages at 200,000 x 2,000) for the sake of one column
+            if block is None or not j0 <= j < j0 + _SAMPLE_COLS:
+                j0 = j - j % _SAMPLE_COLS
+                block = X[idx, j0:j0 + _SAMPLE_COLS]
+            col = block[:, j - j0].astype(np.float64)
         # reference samples only non-zero values; zeros are implied
         nonzero = col[(np.abs(col) > 1e-35) | np.isnan(col)]
         m = BinMapper()

@@ -105,14 +105,83 @@ def hist_geometry(*, F: int, B: int, W: int, F_rows: Optional[int] = None
     gb = group_sz * Bp
     groups = -(-F // group_sz)
     return dict(Bp=Bp, group_sz=group_sz, gb=gb, groups=groups,
-                gb_pad=_round_up(gb, 128), wp=_round_up(W, 8),
+                gb_pad=_round_up(gb, 128), wp=_round_up(W, 8), F=F,
                 F_rows=F if F_rows is None else F_rows)
+
+
+# Feature tiles (ops/hist_wave.py: both histogram kernels walk a grid
+# axis of them once one resident block no longer fits). A tile's stored
+# bin rows come in whole packed uint8 sublane tiles, and it holds at
+# most this many feature groups: the fused kernel's flush unrolls one
+# dot a group (8 s of Mosaic compile at 64; the wave kernel rolls its
+# loop under tiles), and its accumulators are 128 KiB a group
+HIST_TILE_ROW_ALIGN = 32
+HIST_TILE_MAX_GROUPS = 64
+
+
+def hist_feature_tile(*, F: int, B: int, W: int, chunk: int, fused: bool,
+                      F_rows: Optional[int] = None, bins_bytes: int = 1,
+                      int8: bool = False, count_proxy: bool = False,
+                      variant: Optional[str] = None,
+                      force: Optional[int] = None) -> int:
+    """Stored bin rows of one feature tile of a histogram kernel at this
+    row chunk: ALL of them (one tile, the kernel as it was before the
+    tile axis) whenever the whole working set is inside the VMEM
+    budget; else the widest aligned tile whose accumulators
+    (gb_pad x 128 x 4 B a group), double-buffered bin block and
+    compaction payload are, under HIST_TILE_MAX_GROUPS; 0 where not
+    even the narrowest is (the chunk is then no candidate). A
+    trace-time choice from the shapes, no knob; ``force`` (rows, for
+    tests and measurements) overrides the pricing only."""
+    F_rows = F if F_rows is None else F_rows
+    if force is not None:
+        return min(int(force), F_rows)
+    kw = dict(chunk=chunk, W=W, fused=fused, bins_bytes=bins_bytes,
+              int8=int8, count_proxy=count_proxy, variant=variant)
+    whole = hist_geometry(F=F, B=B, W=W, F_rows=F_rows)
+    if fits_vmem(hist_vmem_bytes(geom=whole, **kw)):
+        return F_rows
+    per_row = -(-F // F_rows)               # 2 where bins are 4-bit packed
+    align = math.lcm(HIST_TILE_ROW_ALIGN, whole["group_sz"])
+    rows = (HIST_TILE_MAX_GROUPS * whole["group_sz"] // per_row
+            // align * align)
+    while rows >= align:
+        if rows < F_rows and fits_vmem(hist_vmem_bytes(
+                geom=hist_geometry(F=rows * per_row, B=B, W=W,
+                                   F_rows=rows), tiled=True, **kw)):
+            return rows
+        rows -= align
+    return 0
+
+
+def hist_feature_tiling(*, F: int, B: int, W: int, chunk: int,
+                        F_rows: Optional[int] = None, **kw):
+    """(geom, n_tiles) of a histogram kernel call: ``geom`` is the
+    hist_geometry of ONE feature tile (hist_feature_tile), of the whole
+    matrix where one tile holds it; the kernels build their BlockSpecs
+    from it and walk ``n_tiles`` of them. A shape no tile of which fits
+    the VMEM budget at this chunk is an error here, before Mosaic is
+    asked."""
+    F_rows = F if F_rows is None else F_rows
+    rows = hist_feature_tile(F=F, B=B, W=W, chunk=chunk, F_rows=F_rows,
+                             **kw)
+    if rows <= 0:
+        raise ValueError(
+            f"no feature tile of a histogram kernel at {F} features x "
+            f"{B} bins, wave {W}, fits the VMEM budget "
+            f"({PALLAS_VMEM_BUDGET_BYTES >> 20} MiB) at a row chunk of "
+            f"{chunk}: use a smaller chunk (tpu_hist_chunk)")
+    if rows >= F_rows:
+        return hist_geometry(F=F, B=B, W=W, F_rows=F_rows), 1
+    return (hist_geometry(F=rows * -(-F // F_rows), B=B, W=W, F_rows=rows),
+            -(-F_rows // rows))
 
 
 def wave_hist_block_shapes(*, chunk: int, geom: Dict[str, int]
                            ) -> Dict[str, tuple]:
     """VMEM block shapes of wave_histogram_pallas — the kernel's
-    BlockSpecs are built from THESE tuples."""
+    BlockSpecs are built from THESE tuples (under feature tiles an i32
+    scratch of the ``bins`` block's shape is allocated beside them)."""
     return {
         "wl": (geom["wp"], 1),                            # f32 const
         "bins": (geom["F_rows"], chunk),                  # grid-indexed
@@ -162,10 +231,12 @@ def hist_compact_tile(*, geom: Dict[str, int], chunk: int,
 
 
 def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
-                            tbl_rows: int, compact_tile: int = 0
-                            ) -> Dict[str, tuple]:
+                            tbl_rows: int, compact_tile: int = 0,
+                            tiled: bool = False) -> Dict[str, tuple]:
     """VMEM block shapes of fused_partition_histogram_pallas; with
-    ``compact_tile`` also its compaction scratch."""
+    ``compact_tile`` also its compaction scratch, and ``tiled`` (``geom``
+    is then one feature tile's) the block of the wave's split columns,
+    which lie in other tiles."""
     s = {
         "tbl": (128, tbl_rows),                           # i32 const
         "bins": (geom["F_rows"], chunk),                  # grid-indexed
@@ -182,6 +253,8 @@ def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
             "sel": (1, chunk),               # f32 0/1 contributes
             "staged": (c, 2 * compact_tile),  # f32, across grid steps
         })
+    if tiled:
+        s["cols"] = (_round_up(geom["wp"], HIST_TILE_ROW_ALIGN), chunk)
     return s
 
 
@@ -189,7 +262,8 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
                     fused: bool, bins_bytes: int = 1, int8: bool = False,
                     count_proxy: bool = False,
                     tbl_rows: Optional[int] = None,
-                    variant: Optional[str] = None) -> int:
+                    variant: Optional[str] = None,
+                    tiled: bool = False) -> int:
     """Working-set bytes of one grid step of a wave-histogram kernel,
     priced from the SAME block shapes the BlockSpecs use: grid-indexed
     blocks double-buffered, plus the in-kernel temporaries (the
@@ -201,7 +275,9 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
     (hist_compact_tile) the one-hot tile and weight rows are T wide,
     not chunk wide, and the compaction's scratch and temporaries (the
     payload rows, the [2T, T] one-hot with its i32 compare, the rank
-    matrix, the gathered [C, 2T] result) are added.
+    matrix, the gathered [C, 2T] result) are added. ``tiled``: ``geom``
+    is one feature tile's (hist_feature_tile), and the fused kernel
+    reads the wave's split columns as one more double-buffered block.
     """
     oh_bytes = 1 if int8 else 2                  # int8 / bf16 one-hot
     acc_bytes = 4                                # i32 / f32 accumulator
@@ -215,8 +291,10 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
         T = hist_compact_tile(geom=geom, chunk=chunk,
                               bins_bytes=bins_bytes, int8=int8)
         s = fused_hist_block_shapes(chunk=chunk, geom=geom,
-                                    tbl_rows=tbl_rows, compact_tile=T)
+                                    tbl_rows=tbl_rows, compact_tile=T,
+                                    tiled=tiled)
         b = (2 * _nelem(s["bins"]) * bins_bytes
+             + (2 * _nelem(s["cols"]) * bins_bytes if tiled else 0)
              + 2 * _nelem(s["ghm"]) * 4
              + 2 * _nelem(s["leaf"]) * 4
              + 2 * _nelem(s["leaf_out"]) * 4
@@ -241,7 +319,8 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
         b = (2 * _nelem(s["bins"]) * bins_bytes
              + 2 * _nelem(s["ghl"]) * 4
              + _nelem(s["wl"]) * 4
-             + _nelem(s["hist"]) * acc_bytes)
+             + _nelem(s["hist"]) * acc_bytes
+             + (_nelem(s["bins"]) * 4 if tiled else 0))  # i32 scratch
     b += (geom["gb"] * n_dot * oh_bytes          # one-hot tile
           + 128 * n_dot * 4                      # weight rows
           + geom["gb_pad"] * 128 * acc_bytes)    # per-group matmul acc
@@ -702,9 +781,11 @@ def hist_chunk_candidates(*, F: int, B: int, W: int, fused: bool,
     kernels, largest-first. Chunks beyond the dataset's rows are
     pointless (the kernel would pad the whole matrix up); the int8 tier
     additionally keeps the padded row count under the int32 histogram
-    overflow guard."""
-    geom = hist_geometry(F=F, B=B, W=W,
-                         F_rows=(F + 1) // 2 if packed4 else F)
+    overflow guard. A chunk at which the kernel walks feature tiles
+    (hist_feature_tile) carries the tile's stored bin rows beside it:
+    the pair is what is timed, and the kernel derives the same tile
+    from the same shapes at trace time."""
+    F_rows = (F + 1) // 2 if packed4 else F
     base = ((1024, 2048, 4096, 8192, 16384, 32768, MAX_HIST_CHUNK)
             if exhaustive else (4096, 8192, 16384, 32768))
     out = []
@@ -713,11 +794,14 @@ def hist_chunk_candidates(*, F: int, B: int, W: int, fused: bool,
             continue
         if int8 and n_rows and 127 * (n_rows + (-n_rows) % c) >= 2 ** 31:
             continue
-        if fits_vmem(hist_vmem_bytes(
-                chunk=c, geom=geom, W=W, fused=fused,
-                bins_bytes=bins_bytes, int8=int8,
-                count_proxy=count_proxy, variant=variant)):
+        tile = hist_feature_tile(
+            F=F, B=B, W=W, chunk=c, fused=fused, F_rows=F_rows,
+            bins_bytes=bins_bytes, int8=int8, count_proxy=count_proxy,
+            variant=variant)
+        if tile >= F_rows:
             out.append({"chunk": c})
+        elif tile:
+            out.append({"chunk": c, "tile": tile})
     return out[::-1]
 
 
@@ -796,7 +880,12 @@ def tune_hist_chunk(*, fused: bool, F: int, B: int, W: int,
         count_proxy=count_proxy, packed4=packed4, n_rows=n_rows,
         exhaustive=t.mode == "exhaustive", variant=variant)
     if not cands:
-        return default
+        # every chunk was priced and none fits, not even walking the
+        # narrowest feature tile: nothing to hand Mosaic
+        raise ValueError(
+            f"no row chunk of the {'fused' if fused else 'wave'} "
+            f"histogram kernel fits the VMEM budget at {F} features x "
+            f"{B} bins, wave {W} ({precision})")
     if len(cands) == 1:
         return int(cands[0]["chunk"])
     tier = precision + ("+proxy" if count_proxy else "") \
@@ -810,7 +899,7 @@ def tune_hist_chunk(*, fused: bool, F: int, B: int, W: int,
            # different-sized datasets from overwriting each other's
            # entries on every alternation
            "chunks": [c["chunk"] for c in cands]}
-    measure = _hist_measure_fn(
+    measure = _measure or _hist_measure_fn(
         fused=fused, F=F, B=B, W=W, precision=precision,
         count_proxy=count_proxy, packed4=packed4, any_cat=any_cat,
         bins_bytes=bins_bytes,

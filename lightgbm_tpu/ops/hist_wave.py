@@ -78,6 +78,18 @@ def _bf16_split(x):
     return hi, x - hi
 
 
+def _tile_grid(n_tiles: int, n_chunks: int):
+    """(grid, at) of a histogram kernel: ``at(fn)`` turns an index map
+    over (feature tile, row chunk) into the grid's. One tile keeps the
+    1-D grid over row chunks the kernels had before the tile axis, so
+    whatever fits one resident block lowers as it always did; more
+    walk (tiles, chunks), tiles outermost: a tile's accumulator stays
+    in VMEM while every row chunk streams past it."""
+    if n_tiles == 1:
+        return (n_chunks,), lambda fn: (lambda i: fn(0, i))
+    return (n_tiles, n_chunks), lambda fn: fn
+
+
 # ---------------------------------------------------------------------------
 # XLA reference implementation
 # ---------------------------------------------------------------------------
@@ -320,8 +332,10 @@ def wave_histogram_sparse(sp, g, h, leaf_ids, wave_leaves, *, num_bins,
 def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
                       F, B, W, groups, group_sz, variant,
                       exact_dot=False, int8=False, count_proxy=False,
-                      packed4=False):
+                      packed4=False, tiled=False):
     """One grid step = one row chunk; accumulates into out_ref (VMEM).
+    ``tiled``: the grid is (feature tiles, row chunks); ``F``/``groups``
+    and every block are then one tile's (autotune.hist_feature_tiling).
 
     Every tensor keeps ROWS ON THE LANE AXIS — no relayouts anywhere:
     the weight matrix is built transposed ([channels, Ct] on sublanes)
@@ -332,7 +346,8 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
     ghl_ref:  [4, Ct] f32 packed rows (grad, hess, leaf_id, 0)
     out_ref:  [groups, gb_pad, 128] accumulated histograms
     maybe_cnt: with variant="hilo4", a second [groups, gb_pad, 128]
-              accumulator carrying the exact count channels
+              accumulator carrying the exact count channels; then,
+              ``tiled``, the [Fp, Ct] i32 scratch of the rolled loop
 
     ``variant`` selects the exact-tier (precision="highest") channel
     layout — bf16 hi/lo decompositions make every MXU product exact,
@@ -356,8 +371,9 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
     ``variant=None`` (precision="default") keeps the single-bf16 rows
     [g | h | count] x W (3W <= 128), grad/hess rounding to bf16.
     """
-    step = pl.program_id(0)
+    step = pl.program_id(1 if tiled else 0)
     cnt_ref = maybe_cnt[0] if variant == "hilo4" else None
+    rows_ref = maybe_cnt[-1] if tiled else None     # VMEM scratch
 
     @pl.when(step == 0)
     def _():
@@ -420,23 +436,21 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
         w_mm = w_rows if exact_dot else w_rows.astype(jnp.bfloat16)
         acc_dt = jnp.float32
 
-    rows_cache = {}
-    for p in range(groups):
+    gb_pad = out_ref.shape[1]
+
+    def group(p, row_at):
+        """out_ref[p] += one_hot(bins of group p) . weight rows;
+        ``row_at(sidx)`` is the [1, Ct] i32 bin row of the group's
+        ``sidx``-th feature, or None past the last feature."""
         # per-feature one-hot blocks concatenated on ALIGNED sublane
         # boundaries: one compare per feature (the previous
         # which_feat/select merge was VPU-bound — 2 selects + compare
         # per element vs 1 compare here)
         blocks = []
         for sidx in range(group_sz):
-            f = p * group_sz + sidx
-            if f < F:
-                row = _feature_row(
-                    lambda r: bins_ref[r, :].astype(jnp.int32), f,
-                    rows_cache, packed4)
-                blocks.append(
-                    (row[None, :] == bin_iota).astype(oh_dt))
-            else:
-                blocks.append(jnp.zeros((Bp, ct), oh_dt))
+            row = row_at(sidx)
+            blocks.append(jnp.zeros((Bp, ct), oh_dt) if row is None
+                          else (row == bin_iota).astype(oh_dt))
         oh_t = (blocks[0] if group_sz == 1
                 else jnp.concatenate(blocks, axis=0))   # [gb, Ct]
         # contract the LANE axis of both operands: [gb, Ct] x [128, Ct]
@@ -451,7 +465,6 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
                        else jax.lax.Precision.HIGHEST if exact_dot
                        else jax.lax.Precision.DEFAULT),
             preferred_element_type=acc_dt)              # [gb, 128]
-        gb_pad = out_ref.shape[1]
         if gb_pad != gb:
             acc = jnp.pad(acc, ((0, gb_pad - gb), (0, 0)))
         out_ref[p, :, :] += acc
@@ -470,6 +483,39 @@ def _wave_hist_kernel(wl_ref, bins_ref, ghl_ref, out_ref, *maybe_cnt,
                 acc_c = jnp.pad(acc_c, ((0, gb_pad - gb), (0, 0)))
             cnt_ref[p, :, :] += acc_c
 
+    if not tiled:
+        rows_cache = {}
+        for p in range(groups):
+            group(p, lambda sidx: (
+                _feature_row(lambda r: bins_ref[r, :].astype(jnp.int32),
+                             p * group_sz + sidx, rows_cache,
+                             packed4)[None, :]
+                if p * group_sz + sidx < F else None))
+        return
+    # a tile's groups are all alike (whole groups of stored rows, no
+    # ragged last one), so ONE group body is compiled and a loop walks
+    # it: the same dots in the same order as the unrolled loop, at a
+    # small part of its compile time (64 unrolled groups of 16,384-lane
+    # one-hot tiles took Mosaic 178 s). Its rows are read by a dynamic
+    # sublane index, which packed uint8 rows do not allow: the tile's
+    # bin block is widened to i32 once a grid step
+    rows_ref[...] = bins_ref[...].astype(jnp.int32)
+    per_row = 2 if packed4 else 1
+
+    def row_at(p, sidx):
+        r = rows_ref[pl.ds(p * (group_sz // per_row) + sidx // per_row,
+                           1), :]                       # [1, Ct]
+        if not packed4:
+            return r
+        return (jax.lax.shift_right_logical(r, 4) if sidx % 2
+                else jnp.bitwise_and(r, 15))
+
+    def body(p, carry):
+        group(p, functools.partial(row_at, p))
+        return carry
+
+    jax.lax.fori_loop(0, groups, body, 0)
+
 
 def _exact_nchan(variant) -> int:
     """MXU weight-row channels per wave slot of an exact-tier
@@ -482,12 +528,14 @@ def _exact_nchan(variant) -> int:
                    static_argnames=("num_bins", "chunk", "interpret",
                                     "precision", "count_proxy",
                                     "packed4", "num_features",
-                                    "dequant", "variant"))
+                                    "dequant", "variant",
+                                    "feature_tile"))
 def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
                           chunk=2048, interpret=False, precision="highest",
                           gh_scale=None, count_proxy=False,
                           packed4=False, num_features=None,
-                          dequant=True, variant="hilo5"):
+                          dequant=True, variant="hilo5",
+                          feature_tile=None):
     """Pallas wave histogram — same contract as wave_histogram_xla.
 
     Grid over row chunks; per chunk the kernel builds the leaf-membership
@@ -507,6 +555,11 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
     returns the RAW int32 sums instead (the quantized-psum wire format:
     the data-parallel learner reduces the integer representation across
     the mesh and dequantizes after the collective, ops/wave_grower.py).
+
+    Where the accumulators of all F features no longer fit VMEM beside
+    the bin block, the grid gains an outer axis over tiles of features
+    (autotune.hist_feature_tiling: a trace-time choice from the shapes);
+    ``feature_tile`` (stored bin rows a tile) forces one, for tests.
     """
     F, n = bins_t.shape
     if packed4:
@@ -534,8 +587,13 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
             "int8 histogram sums could overflow int32 beyond ~16.9M "
             "rows; disable tpu_quantized_hist")
     # tile geometry + block shapes from the shared source of truth the
-    # autotuner's VMEM predicate prices (ops/autotune.py)
-    geom = autotune.hist_geometry(F=F, B=B, W=W, F_rows=bins_t.shape[0])
+    # autotuner's VMEM predicate prices (ops/autotune.py); ``geom`` is
+    # ONE feature tile's, the whole matrix's where one tile holds it
+    geom, n_tiles = autotune.hist_feature_tiling(
+        F=F, B=B, W=W, chunk=chunk, fused=False, F_rows=bins_t.shape[0],
+        bins_bytes=bins_t.dtype.itemsize, int8=int8,
+        count_proxy=count_proxy, variant=variant, force=feature_tile)
+    tiled = n_tiles > 1
     group_sz, gb = geom["group_sz"], geom["gb"]
     groups, gb_pad = geom["groups"], geom["gb_pad"]
 
@@ -557,36 +615,44 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
         wl = jnp.pad(wl, ((0, wp - W), (0, 0)), constant_values=-1.0)
 
     kernel = functools.partial(
-        _wave_hist_kernel, F=F, B=B, W=W, groups=groups,
+        _wave_hist_kernel, F=geom["F"], B=B, W=W, groups=groups,
         group_sz=group_sz, variant=variant,
         exact_dot=interpret and not int8,
-        int8=int8, count_proxy=count_proxy, packed4=packed4)
+        int8=int8, count_proxy=count_proxy, packed4=packed4,
+        tiled=tiled)
 
     blk = autotune.wave_hist_block_shapes(chunk=chunk, geom=geom)
-    out_specs = [pl.BlockSpec(blk["hist"], lambda i: (0, 0, 0),
+    grid, at = _tile_grid(n_tiles, n_pad // chunk)
+    # every tile's accumulator is one block of the output's group axis
+    hist_all = (n_tiles * groups,) + blk["hist"][1:]
+    out_specs = [pl.BlockSpec(blk["hist"], at(lambda t, i: (t, 0, 0)),
                               memory_space=pltpu.VMEM)]
     out_shape = [jax.ShapeDtypeStruct(
-        blk["hist"], jnp.int32 if int8 else jnp.float32)]
+        hist_all, jnp.int32 if int8 else jnp.float32)]
     if variant == "hilo4":
         # second accumulator: the count-dot channels (f32, W lanes)
-        out_specs.append(pl.BlockSpec(blk["hist"], lambda i: (0, 0, 0),
+        out_specs.append(pl.BlockSpec(blk["hist"],
+                                      at(lambda t, i: (t, 0, 0)),
                                       memory_space=pltpu.VMEM))
-        out_shape.append(jax.ShapeDtypeStruct(blk["hist"], jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct(hist_all, jnp.float32))
+    groups = n_tiles * groups           # of the whole output from here
     outs = pl.pallas_call(
         kernel,
-        grid=(n_pad // chunk,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec(blk["wl"], lambda i: (0, 0),
+            pl.BlockSpec(blk["wl"], at(lambda t, i: (0, 0)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(blk["bins"], lambda i: (0, i),
+            pl.BlockSpec(blk["bins"], at(lambda t, i: (t, i)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(blk["ghl"], lambda i: (0, i),
+            pl.BlockSpec(blk["ghl"], at(lambda t, i: (0, i)),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(out_specs[0] if len(out_specs) == 1
                    else tuple(out_specs)),
         out_shape=(out_shape[0] if len(out_shape) == 1
                    else tuple(out_shape)),
+        scratch_shapes=([pltpu.VMEM(blk["bins"], jnp.int32)] if tiled
+                        else []),
         # the unrolled group loop's temporaries exceed the 16 MB default
         # scoped-vmem cap; v5e has 128 MB physical VMEM
         compiler_params=autotune.tpu_compiler_params(),
@@ -675,9 +741,13 @@ def wave_histogram(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
             precision=precision, gh_scale=gh_scale,
             count_proxy=count_proxy, dequant=dequant, variant=variant)
     if route == "pallas-tpu":
+        from ..utils.device import on_tpu
         return wave_histogram_pallas(
             bins_t, g, h, leaf_ids, wave_leaves, num_bins=num_bins,
             chunk=chunk or autotune.DEFAULT_HIST_CHUNK,
+            # off the chip a pinned route runs the same kernel
+            # interpreted, as the fused pass does (ops/wave_grower.py)
+            interpret=not on_tpu(),
             precision=precision, gh_scale=gh_scale,
             count_proxy=count_proxy, dequant=dequant, variant=variant)
     out = wave_histogram_xla(
@@ -712,11 +782,10 @@ FUSED_MAX_WAVE_INT8_NC = 64  # 2 channels (count-proxy mode: the MXU dot
                              # from the partition mask — see wave_grower)
 
 
-def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
-                  hist_ref, leaf_out_ref, tiles_ref, *rest, F, B, W,
+def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
                   groups, group_sz, variant, exact_dot=False, int8=False,
                   any_cat=True, count_proxy=False, packed4=False,
-                  compact_tile=0):
+                  compact_tile=0, tiled=False):
     """One grid step: partition one row chunk by the wave's W splits,
     then accumulate the wave's smaller-child histograms — ONE data pass.
 
@@ -735,6 +804,8 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
                pre-masked, the mask rides separately for the counts
     leaf_ref:  [1, Ct]  i32 leaf ids BEFORE this wave (all rows,
                out-of-bag included)
+    cols_ref:  (``tiled`` only) [Wp, Ct] the bin rows of the wave's W
+               split features, one row a slot
     hist_ref:  [groups, gb_pad, 128] accumulated histograms
     leaf_out_ref: [1, Ct] i32 leaf ids AFTER this wave
     tiles_ref: [1] i32 (SMEM) rows put through the one-hot dot, in
@@ -758,19 +829,44 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
     row of the chunk does, with zero weights where it contributes
     nothing. autotune.hist_compact_tile chooses, from the dot's cost
     a row.
+
+    ``tiled``: the grid is (feature tiles, row chunks) and ``binsf_ref``,
+    ``hist_ref``, ``F`` and ``groups`` are ONE tile's. A wave's split
+    features lie in any tile, so their bin rows come in ``cols_ref``
+    (gathered once a pass outside the kernel) and every tile routes
+    its rows alike, from the leaf ids of BEFORE the pass (``leaf_ref``
+    is never the buffer ``leaf_out_ref`` writes); each writes the same
+    new ids. Tile 0 alone counts what is counted once a pass: the
+    dotted tiles and the count-proxy's moved rows.
     """
-    step = pl.program_id(0)
+    if tiled:
+        cols_ref, *rest = rest
+    hist_ref, leaf_out_ref, tiles_ref, *rest = rest
+    step = pl.program_id(1 if tiled else 0)
+    first_tile = pl.program_id(0) == 0 if tiled else None
     T = compact_tile
     has_cnt = count_proxy or variant == "hilo4"
     cnt_ref = rest[0] if has_cnt else None
     scratch = rest[1:] if has_cnt else rest
 
+    def once_a_pass(fn):
+        """Run ``fn`` in the first feature tile only."""
+        if tiled:
+            pl.when(first_tile)(fn)
+        else:
+            fn()
+
     @pl.when(step == 0)
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
-        tiles_ref[0] = 0
-        if cnt_ref is not None:
+        if variant == "hilo4":
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+        @once_a_pass
+        def _():
+            tiles_ref[0] = 0
+            if count_proxy:
+                cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     i32 = jnp.int32
     leaf = leaf_ref[...]                                # [1, Ct]
@@ -795,7 +891,9 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
     # and it replaces the previous F-deep select sweep over [W, Ct]
     # (F x W VPU ops per row) with an F-contraction matmul.
     feat_c = tbl_ref[:W, TBL_FEAT:TBL_FEAT + 1]
-    if packed4:
+    if tiled:
+        cols = cols_ref[...].astype(i32)[:W]                # [W, Ct]
+    elif packed4:
         # 4-bit tier (dense_nbits_bin.hpp analog): two features per
         # HBM byte. Gather the PACKED byte rows (values <= 255: exact
         # bf16), then select each slot's nibble by feat & 1.
@@ -895,12 +993,14 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
         # derives the left side as parent - right and synthesizes the
         # per-bin count estimates from the hessian channel. Taken from
         # the partition, BEFORE any compaction: every row counts.
-        mvd = moved.astype(jnp.float32) * mvec              # [W, Ct]
-        s = jnp.sum(mvd, axis=1, keepdims=True)             # [W, 1]
-        wp_c = cnt_ref.shape[0]
-        if wp_c != W:
-            s = jnp.pad(s, ((0, wp_c - W), (0, 0)))
-        cnt_ref[...] += jnp.broadcast_to(s, cnt_ref.shape)
+        @once_a_pass
+        def _():
+            mvd = moved.astype(jnp.float32) * mvec          # [W, Ct]
+            s = jnp.sum(mvd, axis=1, keepdims=True)         # [W, 1]
+            wp_c = cnt_ref.shape[0]
+            if wp_c != W:
+                s = jnp.pad(s, ((0, wp_c - W), (0, 0)))
+            cnt_ref[...] += jnp.broadcast_to(s, cnt_ref.shape)
     chan = _channel_rows(gvec, hvec, mvec, variant=variant, int8=int8,
                          count_proxy=count_proxy)
     acc_kw = dict(F=F, B=B, groups=groups, group_sz=group_sz,
@@ -916,7 +1016,10 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
         _accumulate_hist(
             lambda r: binsf_ref[r, :].astype(i32), chan, m, hist_ref,
             hist_cnt_ref, **acc_kw)
-        tiles_ref[0] += ct // COMPACT_TILE_UNIT
+
+        @once_a_pass
+        def _():
+            tiles_ref[0] += ct // COMPACT_TILE_UNIT
         return
 
     # ---- stable row compaction ahead of the dot ----
@@ -982,7 +1085,10 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
         staged_ref[:, 0:T] = staged_ref[:, T:S]
         staged_ref[:, T:S] = jnp.zeros((staged_ref.shape[0], T),
                                        jnp.float32)
-        tiles_ref[0] += T // COMPACT_TILE_UNIT
+
+        @once_a_pass
+        def _():
+            tiles_ref[0] += T // COMPACT_TILE_UNIT
 
     def sub_tile(i, c):
         # one more turn than there are sub-tiles in the LAST grid step:
@@ -1008,7 +1114,7 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref,
         pl.when(full)(flush)
         return jnp.where(full, jnp.maximum(c - T, 0), c)
 
-    last = step == pl.num_programs(0) - 1
+    last = step == pl.num_programs(1 if tiled else 0) - 1
     cnt_smem[0] = jax.lax.fori_loop(
         0, n_sub + last.astype(i32), sub_tile, cnt_smem[0])
 
@@ -1124,7 +1230,7 @@ def _accumulate_hist(get_row, chan, m, hist_ref, cnt_ref, *, F, B, groups,
                                              "any_cat", "count_proxy",
                                              "packed4", "num_features",
                                              "dequant", "variant",
-                                             "compact"))
+                                             "compact", "feature_tile"))
 def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      leaf_ids, tbl, *, num_bins,
                                      chunk=2048, interpret=False,
@@ -1132,7 +1238,8 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      gh_scale=None, any_cat=True,
                                      count_proxy=False, packed4=False,
                                      num_features=None, dequant=True,
-                                     variant="hilo5", compact=None):
+                                     variant="hilo5", compact=None,
+                                     feature_tile=None):
     """Partition one wave + build its smaller-child histograms in ONE
     data pass. Returns (new_leaf_ids [N], hist [W, F, B, 3], work) —
     or, with ``count_proxy``, (new_leaf_ids, hist [W, F, B, 2],
@@ -1146,6 +1253,14 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     dots whole tiles of them (_fused_kernel). ``compact`` = True /
     False overrides that choice — for tests and for the measurement
     that sets its threshold, never from a parameter.
+
+    Where one resident block cannot hold every feature's accumulator,
+    bin rows and compaction payload, the pass walks tiles of features
+    (autotune.hist_feature_tiling, a trace-time choice from the shapes;
+    ``feature_tile`` = stored bin rows a tile forces one, for tests):
+    the wave's W split columns are gathered once and handed to every
+    tile, which routes and compacts its rows alike and dots its own
+    features (_fused_kernel).
 
     tbl: [18, W] int32 packed split table (TBL_* rows: 10 scalar
     fields + 8 categorical bitset words). g/h must be pre-masked by
@@ -1203,8 +1318,13 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     nchan = ((2 if count_proxy else 3) if int8
              else _exact_nchan(variant) if hilo else 4)
     # tile geometry + block shapes from the shared source of truth the
-    # autotuner's VMEM predicate prices (ops/autotune.py)
-    geom = autotune.hist_geometry(F=F, B=B, W=W, F_rows=bins_t.shape[0])
+    # autotuner's VMEM predicate prices (ops/autotune.py); ``geom`` is
+    # ONE feature tile's, the whole matrix's where one tile holds it
+    geom, n_tiles = autotune.hist_feature_tiling(
+        F=F, B=B, W=W, chunk=chunk, fused=True, F_rows=bins_t.shape[0],
+        bins_bytes=bins_t.dtype.itemsize, int8=int8,
+        count_proxy=count_proxy, variant=variant, force=feature_tile)
+    tiled = n_tiles > 1
     Bp, group_sz, gb = geom["Bp"], geom["group_sz"], geom["gb"]
     groups, gb_pad = geom["groups"], geom["gb_pad"]
 
@@ -1232,23 +1352,55 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         int8=int8, force=compact)
     exact_dot = interpret and not int8
     kernel = functools.partial(
-        _fused_kernel, F=F, B=B, W=W, groups=groups, group_sz=group_sz,
-        variant=variant, exact_dot=exact_dot, int8=int8,
-        any_cat=any_cat, count_proxy=count_proxy, packed4=packed4,
-        compact_tile=T)
+        _fused_kernel, F=geom["F"], B=B, W=W, groups=groups,
+        group_sz=group_sz, variant=variant, exact_dot=exact_dot,
+        int8=int8, any_cat=any_cat, count_proxy=count_proxy,
+        packed4=packed4, compact_tile=T, tiled=tiled)
 
     blk = autotune.fused_hist_block_shapes(chunk=chunk, geom=geom,
                                            tbl_rows=TBL_ROWS,
-                                           compact_tile=T)
-    out_specs = [
-        pl.BlockSpec(blk["hist"], lambda i: (0, 0, 0),
+                                           compact_tile=T, tiled=tiled)
+    grid, at = _tile_grid(n_tiles, n_pad // chunk)
+    # every tile's accumulator is one block of the output's group axis
+    hist_all = (n_tiles * groups,) + blk["hist"][1:]
+    operands = [tblT, bins_t, ghm, leaf2d]
+    in_specs = [
+        pl.BlockSpec(blk["tbl"], at(lambda t, i: (0, 0)),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec(blk["leaf_out"], lambda i: (0, i),
+        pl.BlockSpec(blk["bins"], at(lambda t, i: (t, i)),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec(blk["ghm"], at(lambda t, i: (0, i)),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec(blk["leaf"], at(lambda t, i: (0, i)),
+                     memory_space=pltpu.VMEM),
+    ]
+    if tiled:
+        # the wave's split columns, one row a slot: a tile holds only
+        # its own features' rows, so the W rows the partition reads are
+        # gathered here, once a pass (W x N bytes beside the F x N the
+        # pass streams anyway)
+        feat = jnp.maximum(tbl[TBL_FEAT].astype(jnp.int32), 0)
+        if packed4:
+            byte = bins_t[feat // 2]
+            cols = jnp.where((feat % 2 == 1)[:, None],
+                             jnp.right_shift(byte, 4),
+                             jnp.bitwise_and(byte, 15))
+        else:
+            cols = bins_t[feat]                            # [W, N]
+        operands.append(jnp.pad(
+            cols, ((0, blk["cols"][0] - W), (0, 0))))
+        in_specs.append(pl.BlockSpec(blk["cols"],
+                                     at(lambda t, i: (0, i)),
+                                     memory_space=pltpu.VMEM))
+    out_specs = [
+        pl.BlockSpec(blk["hist"], at(lambda t, i: (t, 0, 0)),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec(blk["leaf_out"], at(lambda t, i: (0, i)),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct(blk["hist"],
+        jax.ShapeDtypeStruct(hist_all,
                              jnp.int32 if int8 else jnp.float32),
         jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         jax.ShapeDtypeStruct((1,), jnp.int32),
@@ -1261,34 +1413,28 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                    pltpu.VMEM(blk["staged"], jnp.float32),
                    pltpu.SMEM((1,), jnp.int32)]
     if count_proxy:
-        out_specs.append(pl.BlockSpec(blk["cnt"], lambda i: (0, 0),
+        out_specs.append(pl.BlockSpec(blk["cnt"],
+                                      at(lambda t, i: (0, 0)),
                                       memory_space=pltpu.VMEM))
         out_shape.append(jax.ShapeDtypeStruct(blk["cnt"], jnp.float32))
     elif variant == "hilo4":
         # second histogram-shaped accumulator: the count-dot channels
-        out_specs.append(pl.BlockSpec(blk["hist"], lambda i: (0, 0, 0),
+        out_specs.append(pl.BlockSpec(blk["hist"],
+                                      at(lambda t, i: (t, 0, 0)),
                                       memory_space=pltpu.VMEM))
-        out_shape.append(jax.ShapeDtypeStruct(blk["hist"], jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct(hist_all, jnp.float32))
+    groups = n_tiles * groups           # of the whole output from here
     outs = pl.pallas_call(
         kernel,
-        grid=(n_pad // chunk,),
-        in_specs=[
-            pl.BlockSpec(blk["tbl"], lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(blk["bins"], lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(blk["ghm"], lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(blk["leaf"], lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
+        grid=grid,
+        in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
         compiler_params=autotune.tpu_compiler_params(),
         name="fused_partition_histogram_pallas",
         interpret=interpret,
-    )(tblT, bins_t, ghm, leaf2d)
+    )(*operands)
     hist, leaf_out = outs[0], outs[1]
     work = jnp.stack([jnp.int32(n_pad // COMPACT_TILE_UNIT), outs[2][0]])
     outs = outs[:2] + outs[3:]

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import registry as obs
 from .autotune import DEFAULT_HIST_CHUNK
 from .grower import TreeRecord
 from .hist_wave import (fused_partition_histogram_pallas, wave_histogram)
@@ -401,6 +402,27 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                      and not pallas_hist)
     if use_fused_xla:
         from .hist_wave import fused_partition_histogram_xla
+
+    # what this grower's shapes come to, for whoever reads the registry
+    # (benchmark/readers/): the pool of every leaf's [F, B, 3] f32
+    # histogram, kept for the subtraction, and the feature tiles a
+    # histogram pass walks (1: one resident block holds every feature),
+    # priced as the kernels price them at trace time. Set here and not
+    # in grow(): a step served by the registry is not traced again
+    F_meta = int(meta_const.num_bin.shape[0])
+    obs.gauge("mem/hist_pool_bytes").set(float(L * F_meta * B * 3 * 4))
+    if route == "pallas-tpu" and default_seams and not cfg.sparse_hist:
+        _geom, n_tiles = autotune.hist_feature_tiling(
+            F=F_meta, B=B, W=W, fused=bool(use_fused),
+            chunk=(fused_chunk if use_fused
+                   else cfg.chunk or DEFAULT_HIST_CHUNK),
+            F_rows=(-(-F_meta // 2) if cfg.packed4 and use_fused
+                    else F_meta),
+            bins_bytes=1 if B <= 256 else 4, int8=quant,
+            count_proxy=proxy,
+            variant=(cfg.exact_variant
+                     if cfg.precision == "highest" else None))
+        obs.gauge("hist/feature_tiles").set(float(n_tiles))
 
     if hist_fn is None and cfg.sparse_hist:
         # sparse tier: the histogram source is the (dense bins, sparse

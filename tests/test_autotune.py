@@ -186,20 +186,28 @@ class TestVmemPredicate:
         small = autotune.hist_chunk_candidates(
             F=28, B=64, W=64, fused=True, int8=True, count_proxy=True)
         assert {"chunk": 16384} in small
-        # ...a wide/deep-bin problem must shed the big tiles
+        # ...a wide/deep-bin problem no longer sheds the big chunks:
+        # where one resident block is over the budget the kernel walks
+        # feature tiles (tests/test_wide_features.py), and the candidate
+        # carries the tile it was priced with
         wide = autotune.hist_chunk_candidates(
             F=256, B=256, W=24, fused=True)
-        assert wide and all(c["chunk"] < 32768 for c in wide)
+        assert [c["chunk"] for c in wide] == [32768, 16384, 8192, 4096]
         geom = autotune.hist_geometry(F=256, B=256, W=24)
         for c in wide:
-            assert autotune.fits_vmem(autotune.hist_vmem_bytes(
+            one_block = autotune.fits_vmem(autotune.hist_vmem_bytes(
                 chunk=c["chunk"], geom=geom, W=24, fused=True))
-        assert not autotune.fits_vmem(autotune.hist_vmem_bytes(
-            chunk=32768, geom=geom, W=24, fused=True))
-        # a shape whose VMEM accumulator alone exceeds the budget has
-        # no feasible tile at all (the kernel cannot run there)
-        assert autotune.hist_chunk_candidates(
-            F=4096, B=256, W=24, fused=True) == []
+            assert one_block == ("tile" not in c)
+            if not one_block:
+                assert autotune.fits_vmem(autotune.hist_vmem_bytes(
+                    chunk=c["chunk"], W=24, fused=True, tiled=True,
+                    geom=autotune.hist_geometry(F=c["tile"], B=256,
+                                                W=24)))
+        assert "tile" in wide[0] and "tile" not in wide[-1]
+        # a shape whose VMEM accumulator alone exceeds the budget used
+        # to have no feasible chunk at all; under tiles every chunk is
+        assert all("tile" in c for c in autotune.hist_chunk_candidates(
+            F=4096, B=256, W=24, fused=True))
 
     def test_int8_overflow_guard_filters_chunks(self):
         # n just under the int32 histogram guard: padding a 16M-row

@@ -422,6 +422,57 @@ def test_traced_kernel_name_holds_the_benchmarks_substring(kernel):
     assert name == kernel and any(p in name for p in patterns)
 
 
+@pytest.mark.parametrize("kernel", ["wave_histogram_pallas",
+                                    "fused_partition_histogram_pallas"])
+def test_tiled_kernels_keep_their_names(kernel):
+    """Walking feature tiles (a forced tile of 32 of 72 bin rows) is the
+    same ``pallas_call`` under the same name: the benchmark's
+    ``kernel.*`` metrics read the tiled kernels by the patterns they
+    read the untiled ones by."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import hist_wave
+    S = jax.ShapeDtypeStruct
+    N, F, B, W = 8192, 72, 64, 14
+    fn = functools.partial(getattr(hist_wave, kernel), num_bins=B,
+                           chunk=4096, variant="hilo5", feature_tile=32)
+    rows = (S((F, N), jnp.uint8), S((N,), jnp.float32), S((N,), jnp.float32))
+    args = rows + ((S((N,), jnp.int32), S((W,), jnp.int32))
+                   if kernel == "wave_histogram_pallas" else
+                   (S((N,), jnp.float32), S((N,), jnp.int32),
+                    S((18, W), jnp.int32)))
+    (name,) = _traced_kernel_names(fn, *args)
+    assert name == kernel
+
+
+def test_grower_sets_the_tile_and_pool_gauges():
+    """``hist/feature_tiles`` (tiles a histogram pass walks; 1 where one
+    resident block holds every feature) and ``mem/hist_pool_bytes`` (the
+    pool of every leaf's histogram) are set where the grower is built
+    (a step the registry serves is not traced again); ``benchmark/readers/
+    kernel.feature_tiles.py`` and ``step.hist_pool_gib.py`` read them."""
+    from lightgbm_tpu.ops import autotune
+    reg = obs.default_registry()
+    for name in ("hist/feature_tiles", "mem/hist_pool_bytes"):
+        reg.gauge(name).set(-1.0)
+    g = _booster()
+    g.train_one_iter()
+    gauges = reg.snapshot()["gauges"]
+    cfg = g._grower_cfg
+    f_hist = g.train_data.num_features + g._pad_features
+    assert gauges["mem/hist_pool_bytes"] == \
+        cfg.num_leaves * f_hist * cfg.num_bins * 3 * 4
+    # the CPU's route runs no Mosaic kernel: no tiles to count
+    assert cfg.route == "fused-xla" and gauges["hist/feature_tiles"] == -1.0
+    # the same booster's shapes on the kernels' route: one tile (a
+    # grower under tiles sets 3: tests/test_wide_features.py)
+    _geom, tiles = autotune.hist_feature_tiling(
+        F=f_hist, B=cfg.num_bins, W=cfg.wave_size, fused=True,
+        chunk=cfg.chunk or autotune.DEFAULT_HIST_CHUNK,
+        variant=cfg.exact_variant)
+    assert tiles == 1
+
+
 # -- what a span costs ------------------------------------------------------------
 
 def test_span_off_path_stays_in_microseconds():

@@ -96,6 +96,12 @@ _HIST_CASES = {
                          False, 1 << 16, 16384),
     "criteo-hilo4-W32": (67, 255, 32, "highest", "hilo4", False, False,
                          False, 1 << 16, 16384),
+    # the benchmark's wide cell (epsilon_wide.train): 2,000 features at
+    # 255 bins, no resident block of which fits VMEM, so both kernels
+    # walk feature tiles (tests/test_wide_features.py); the chunk is
+    # the one the cell's runs resolved
+    "epsilon-hilo5-W24": (2000, 256, 24, "highest", "hilo5", False, False,
+                          False, 1 << 16, 16384),
     # edge shapes (the retired on-chip shape sweep)
     "edge-F1": (1, 64, 14, "int8", None, True, False, False, 8192, 4096),
     "edge-4bin-packed4-oddF": (27, 16, 64, "int8", None, True, True,
@@ -138,7 +144,8 @@ def test_fused_partition_histogram_kernel_compiles(spec, name):
 
 @pytest.mark.parametrize("name", ["higgs-int8-proxy-W64",
                                   "higgs-hilo4-W32", "lrb-hilo4-W30",
-                                  "edge-4bin-packed4-oddF"])
+                                  "edge-4bin-packed4-oddF",
+                                  "epsilon-hilo5-W24"])
 def test_wave_histogram_kernel_compiles(spec, name):
     """The partition-free wave kernel: every tree's root pass."""
     from lightgbm_tpu.ops.hist_wave import wave_histogram_pallas
@@ -193,6 +200,54 @@ def test_largest_offered_chunk_of_a_compacting_geometry_compiles(spec):
     compiled = jax.jit(functools.partial(
         fused_partition_histogram_pallas, **kw)).lower(*args).compile()
     assert _mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "wave"])
+def test_epsilon_tiles_are_priced_inside_the_budget(fused):
+    """What the two epsilon cases above compile is what the pricing
+    says: tiles of 64 bin rows, each inside the VMEM budget, where the
+    whole matrix in one block is over twice the chip's VMEM."""
+    from lightgbm_tpu.ops import autotune
+    F, B, W, _p, variant, *_rest, chunk = _HIST_CASES["epsilon-hilo5-W24"]
+    geom, tiles = autotune.hist_feature_tiling(
+        F=F, B=B, W=W, chunk=chunk, fused=fused, variant=variant)
+    assert (tiles, geom["F_rows"]) == (32, 64)
+    assert autotune.hist_vmem_bytes(
+        chunk=chunk, geom=geom, W=W, fused=fused, variant=variant,
+        tiled=True) <= autotune.PALLAS_VMEM_BUDGET_BYTES
+    assert autotune.hist_vmem_bytes(
+        chunk=chunk, geom=autotune.hist_geometry(F=F, B=B, W=W), W=W,
+        fused=fused, variant=variant) > 2 * autotune.TPU_VMEM_CAPACITY_BYTES[
+            "TPU v5 lite"]
+
+
+def _pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_eqns(inner)
+
+
+def test_criteo_width_still_lowers_with_one_tile(spec):
+    """67 features at 255 bins fit one resident block: the fused kernel
+    keeps its 1-D grid over row chunks and the in-kernel gather of the
+    split columns (four operands, no ``cols``), as before the tile
+    axis; the epsilon width walks a 2-D grid with the fifth."""
+    from lightgbm_tpu.ops.hist_wave import \
+        fused_partition_histogram_pallas
+    seen = {}
+    for name in ("criteo-hilo5-W24", "epsilon-hilo5-W24"):
+        args, kw = _hist_args(spec, _HIST_CASES[name], fused=True)
+        fn = functools.partial(fused_partition_histogram_pallas, **kw)
+        (call,) = _pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        seen[name] = (len(call.params["grid_mapping"].grid),
+                      len(call.invars))
+    assert seen == {"criteo-hilo5-W24": (1, 4),
+                    "epsilon-hilo5-W24": (2, 5)}
 
 
 @pytest.mark.parametrize("num_leaves", [255, 31])
