@@ -194,11 +194,11 @@ def wave_hist_block_shapes(*, chunk: int, geom: Dict[str, int]
 # kernel's row compaction (ops/hist_wave.py _fused_kernel)
 HIST_COMPACT_TILE = 512
 # The compaction pays where a row through the one-hot dot is dear
-# against a row through the scan (a [2T, T] one-hot and two small dots a
-# sub-tile, ~2 ns a row): the dot's MACs a row, groups x gb x 128 (the
-# int8 tiers' at half, the MXU's int8 rate being twice its bf16 rate),
-# at or above this. Measured on a v5e (PERF.md section 6, PR 27, calls
-# A and B; masked ns a row against scan + dot x share of rows that
+# against a row through the scan (then a [2T, T] one-hot and two small
+# dots a sub-tile, ~2 ns a row): the dot's MACs a row, groups x gb x 128
+# (the int8 tiers' at half, the MXU's int8 rate being twice its bf16
+# rate), at or above this. Measured on a v5e (PERF.md section 6, PR 27,
+# calls A and B; masked ns a row against scan + dot x share of rows that
 # contribute): 2,195,456 MACs (67 features x 255 bins, hilo5) 24.2
 # against 2.7 + 22.7 x share; 917,504 (28 x 255) 10.9 against 2.2 +
 # 9.6 x share; 229,376 (28 x 63, hilo5) 2.87 against 1.87 + 2.59 x
@@ -206,6 +206,13 @@ HIST_COMPACT_TILE = 512
 # pass; 114,688 (28 x 63, int8) 2.16 against 2.02 + 1.69 x share: behind
 # from 0.08 on. The threshold asks for a break-even share of a half or
 # more (a wave's smaller children never hold more than half the rows).
+# Since PR 31 (a [T, T] one-hot a turn, a second on the turn that fills
+# a tile, a 128 x 128 rank triangle) the scan is cheaper: at 67 x 255
+# 1.42 + 23.1 x share against the 1.99 + 22.6 x share the [2T, T] route
+# read in the same call (no categorical sweep; PERF.md section 6, PR 31,
+# call A); the narrow shapes were not timed again, so the threshold
+# stands where the old scan put it: re-derive it from a masked-against-
+# compacted timing at 28 x 63 before moving it.
 HIST_COMPACT_MIN_MACS = 1 << 19
 # rows of the compaction's payload block ahead of the bin rows (one
 # packed bf16 sublane tile; ops/hist_wave.py _PAY_ROWS)
@@ -251,7 +258,7 @@ def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
         s.update({
             "x": (c, chunk),                 # bf16 payload + bin rows
             "sel": (1, chunk),               # f32 0/1 contributes
-            "staged": (c, 2 * compact_tile),  # f32, across grid steps
+            "staged": (c, compact_tile),     # f32, across grid steps
         })
     if tiled:
         s["cols"] = (_round_up(geom["wp"], HIST_TILE_ROW_ALIGN), chunk)
@@ -274,8 +281,9 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
     count dot writes. Where the fused kernel compacts
     (hist_compact_tile) the one-hot tile and weight rows are T wide,
     not chunk wide, and the compaction's scratch and temporaries (the
-    payload rows, the [2T, T] one-hot with its i32 compare, the rank
-    matrix, the gathered [C, 2T] result) are added. ``tiled``: ``geom``
+    payload rows, the two [T, T] one-hots of a turn that fills a tile
+    with their i32 compares, the 128 x 128 rank triangle, the gathered
+    [C, T] result) are added. ``tiled``: ``geom``
     is one feature tile's (hist_feature_tile), and the fused kernel
     reads the wave's split columns as one more double-buffered block.
     """
@@ -311,8 +319,8 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
                   # payload rows (f32, then bf16), the bins' i32 + bf16
                   + HIST_COMPACT_PAY_ROWS * chunk * 6
                   + geom["F_rows"] * chunk * 6
-                  + T * T * 6                          # rank matrix
-                  + 2 * T * T * 6                      # [2T, T] one-hot
+                  + 128 * 128 * 6                      # rank triangle
+                  + 2 * T * T * 6                      # [T, T] one-hots
                   + _nelem(s["staged"]) * 4)           # gathered result
     else:
         s = wave_hist_block_shapes(chunk=chunk, geom=geom)
@@ -524,8 +532,10 @@ def tune_hist_route(*, backend: Optional[str] = None,
 # 3: the fused kernel compacts rows ahead of its dot, and the tuner times
 # it over 2^20 rows at a stated share of contributing rows (2 was that
 # kernel timed over 65,536 rows: files of it exist on machines PR 27
-# measured on, and hold the wrong exact-tier layout)
-TUNING_CACHE_VERSION = 3
+# measured on, and hold the wrong exact-tier layout).
+# 4: the compaction's scan costs about half (a [T, T] gather a turn, a
+# block-wise rank): timings of the fused kernel cached before are stale
+TUNING_CACHE_VERSION = 4
 
 
 def default_cache_dir() -> str:

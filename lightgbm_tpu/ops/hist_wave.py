@@ -825,10 +825,13 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     every layout.
 
     ``compact_tile`` = T > 0: only the rows that can contribute reach
-    the dot, T at a time (see "stable row compaction" below); 0: every
-    row of the chunk does, with zero weights where it contributes
-    nothing. autotune.hist_compact_tile chooses, from the dot's cost
-    a row.
+    the dot, T at a time (see "stable row compaction" below): each
+    T-row sub-tile of the chunk is gathered through ONE [T, T] one-hot
+    into a [C, T] staging buffer, and the turn on which the buffer
+    fills dots it and gathers the rows past its end a second time, as
+    the next tile's head. 0: every row of the chunk reaches the dot,
+    with zero weights where it contributes nothing.
+    autotune.hist_compact_tile chooses, from the dot's cost a row.
 
     ``tiled``: the grid is (feature tiles, row chunks) and ``binsf_ref``,
     ``hist_ref``, ``F`` and ``groups`` are ONE tile's. A wave's split
@@ -1026,24 +1029,26 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     # The partition above visits every row; the one-hot dot is what a
     # row costs (bins x lanes MACs), and only rows that sit in one of
     # the wave's smaller children AND carry weight can change a sum.
-    # Those rows are packed, in row order, into a [C, 2T] staging
-    # buffer that lives across grid steps; whenever T of them are
-    # there, ONE T-wide tile goes through the weight-row build and the
-    # one-hot dot. The packing is itself an MXU gather, like `cols`
-    # above: per T-row sub-tile a one-hot P[2T, T] (row t -> staging
-    # position count + its rank among the sub-tile's selected rows)
-    # contracts against the sub-tile's bin rows and channel
-    # multiplicands. Every value that passes through is exact in the
-    # dot's input type (bins <= 255; bf16-rounded channel multiplicands,
-    # the same rounding the weight rows got before; the row's 1-based
-    # slot <= 64) and meets a single 1, so the gather is exact and the
+    # Those rows are packed, in row order, into a [C, T] staging buffer
+    # that lives across grid steps; whenever T of them are there, ONE
+    # T-wide tile goes through the weight-row build and the one-hot
+    # dot. The packing is itself an MXU gather, like `cols` above: per
+    # T-row sub-tile a one-hot P[T, T] (row t -> staging position count
+    # + its rank among the sub-tile's selected rows) contracts against
+    # the sub-tile's bin rows and channel multiplicands. Positions past
+    # the tile's end meet no 1; the turn on which the tile fills dots
+    # it and gathers those rows again, through the same one-hot moved
+    # down by T, as the head of the next tile: a second gather on that
+    # turn only. Every value that passes through is exact in the dot's
+    # input type (bins <= 255; bf16-rounded channel multiplicands, the
+    # same rounding the weight rows got before; the row's 1-based slot
+    # <= 64) and meets a single 1, so the gather is exact and the
     # histogram differs from the masked dot's only in the order of its
     # f32 additions.
     x_ref, sel_ref, staged_ref, cnt_smem = scratch
     xdt = jnp.float32 if exact_dot else jnp.bfloat16
     dot_prec = (jax.lax.Precision.HIGHEST if exact_dot
                 else jax.lax.Precision.DEFAULT)
-    S = 2 * T
     k1_c = jax.lax.broadcasted_iota(i32, (W, 1), 0) + 1
     slot1 = jnp.sum(jnp.where(leaf_new == small_c, k1_c, 0), axis=0,
                     keepdims=True)                          # [1, Ct]
@@ -1068,23 +1073,43 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
         staged_ref[...] = jnp.zeros_like(staged_ref)
         cnt_smem[0] = 0
 
-    # U[i, j] = 1 where i < j: sel . U = each row's rank among the
-    # selected rows before it (0/1 products, f32 sums <= T: exact)
-    tri = (jax.lax.broadcasted_iota(i32, (T, T), 0)
-           < jax.lax.broadcasted_iota(i32, (T, T), 1)).astype(xdt)
-    s_iota = jax.lax.broadcasted_iota(i32, (S, 1), 0)
+    # U[i, j] = 1 where i < j, one 128-lane block's worth: blk . U =
+    # each row's rank among the block's selected rows before it (0/1
+    # products, f32 sums <= T: exact)
+    LB = 128                                 # lanes of a block
+    tri = (jax.lax.broadcasted_iota(i32, (LB, LB), 0)
+           < jax.lax.broadcasted_iota(i32, (LB, LB), 1)).astype(xdt)
+    t_iota = jax.lax.broadcasted_iota(i32, (T, 1), 0)
     n_sub = ct // T
 
+    def rank_of(start, live_f):
+        """[1, T] i32: selected rows of the sub-tile at ``start`` before
+        each row. The T // 128 lane blocks ride the sublanes of ONE dot
+        against the block-sized triangle; a block's ranks then start at
+        the sum of the blocks before it."""
+        blks = [sel_ref[:, pl.ds(pl.multiple_of(start + b * LB, LB), LB)]
+                * live_f for b in range(T // LB)]
+        lhs = jnp.zeros((_PAY_ROWS, LB), jnp.float32)
+        for b, blk in enumerate(blks):
+            lhs = jnp.where(r_iota == b, blk, lhs)
+        within = jax.lax.dot_general(
+            lhs.astype(xdt), tri,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=dot_prec,
+            preferred_element_type=jnp.float32)             # [16, LB]
+        ranks, before = [], jnp.zeros((1, 1), jnp.float32)
+        for b, blk in enumerate(blks):
+            ranks.append(within[b:b + 1, :] + before)
+            before = before + jnp.sum(blk, axis=1, keepdims=True)
+        return jnp.concatenate(ranks, axis=1).astype(i32)
+
     def flush():
-        m = (staged_ref[slot_row:slot_row + 1, 0:T]
+        m = (staged_ref[slot_row:slot_row + 1, :]
              == k1_c.astype(jnp.float32)).astype(jnp.float32)  # [W, T]
-        rows = [staged_ref[j:j + 1, 0:T] for j in range(slot_row)]
+        rows = [staged_ref[j:j + 1, :] for j in range(slot_row)]
         _accumulate_hist(
-            lambda r: staged_ref[_PAY_ROWS + r, 0:T].astype(i32), rows,
+            lambda r: staged_ref[_PAY_ROWS + r, :].astype(i32), rows,
             m, hist_ref, hist_cnt_ref, **acc_kw)
-        staged_ref[:, 0:T] = staged_ref[:, T:S]
-        staged_ref[:, T:S] = jnp.zeros((staged_ref.shape[0], T),
-                                       jnp.float32)
 
         @once_a_pass
         def _():
@@ -1096,22 +1121,31 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
         # `count`: the staging buffer is zero there)
         live = i < n_sub
         start = pl.multiple_of(jnp.minimum(i, n_sub - 1) * T, T)
-        sel_t = sel_ref[:, pl.ds(start, T)] * live.astype(jnp.float32)
-        rank = jax.lax.dot_general(
-            jnp.broadcast_to(sel_t, (_PAY_ROWS, T)).astype(xdt), tri,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=dot_prec,
-            preferred_element_type=jnp.float32)[0:1, :]     # [1, T]
-        tgt = jnp.where(sel_t > 0.0, rank.astype(i32) + c, -1)
-        perm = (s_iota == tgt).astype(xdt)                  # [S, T]
-        staged_ref[...] += jax.lax.dot_general(
-            x_ref[:, pl.ds(start, T)], perm,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=dot_prec,
-            preferred_element_type=jnp.float32)             # [C, S]
+        live_f = live.astype(jnp.float32)
+        sel_t = sel_ref[:, pl.ds(start, T)] * live_f
+        # staging position of each selected row, -1 where not selected
+        tgt = jnp.where(sel_t > 0.0, rank_of(start, live_f) + c,
+                        -1)                                 # [1, T]
+
+        def gather(tgt):
+            perm = (t_iota == tgt).astype(xdt)              # [T, T]
+            return jax.lax.dot_general(
+                x_ref[:, pl.ds(start, T)], perm,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=dot_prec,
+                preferred_element_type=jnp.float32)         # [C, T]
+
+        staged_ref[...] += gather(tgt)
         c = c + jnp.sum(sel_t).astype(i32)
         full = (c >= T) | (jnp.logical_not(live) & (c > 0))
-        pl.when(full)(flush)
+
+        @pl.when(full)
+        def _():
+            flush()
+            # the rows past the tile's end open the next tile (an
+            # unselected row's -1 - T meets no position either)
+            staged_ref[...] = gather(tgt - T)
+
         return jnp.where(full, jnp.maximum(c - T, 0), c)
 
     last = step == pl.num_programs(1 if tiled else 0) - 1

@@ -96,6 +96,10 @@ _HIST_CASES = {
                          False, 1 << 16, 16384),
     "criteo-hilo4-W32": (67, 255, 32, "highest", "hilo4", False, False,
                          False, 1 << 16, 16384),
+    # the same width on the int8 count-proxy tier: it compacts too (the
+    # scan's rank and gather dots are bf16 in every tier)
+    "criteo-int8-proxy-W64": (67, 255, 64, "int8", None, True, False,
+                              False, 1 << 16, 16384),
     # the benchmark's wide cell (epsilon_wide.train): 2,000 features at
     # 255 bins, no resident block of which fits VMEM, so both kernels
     # walk feature tiles (tests/test_wide_features.py); the chunk is
@@ -181,8 +185,8 @@ def test_largest_offered_chunk_is_priced_inside_what_compiles(spec):
 def test_largest_offered_chunk_of_a_compacting_geometry_compiles(spec):
     """The same question where the kernel compacts rows ahead of its dot
     (the benchmark cell's geometry): the pricing then carries the
-    staging buffer, the payload and bin rows in bf16, the [2T, T]
-    one-hot and the gathered result, and T-wide one-hot tiles in place
+    [C, T] staging buffer, the payload and bin rows in bf16, the [T, T]
+    one-hots and the gathered result, and T-wide one-hot tiles in place
     of chunk-wide ones."""
     from lightgbm_tpu.ops import autotune
     from lightgbm_tpu.ops.hist_wave import \
@@ -219,6 +223,30 @@ def test_epsilon_tiles_are_priced_inside_the_budget(fused):
         chunk=chunk, geom=autotune.hist_geometry(F=F, B=B, W=W), W=W,
         fused=fused, variant=variant) > 2 * autotune.TPU_VMEM_CAPACITY_BYTES[
             "TPU v5 lite"]
+
+
+@pytest.mark.parametrize("F, rows", [(67, 67), (2000, 64)],
+                         ids=["criteo", "epsilon"])
+def test_feature_tile_of_the_benchmark_cells_is_unmoved(F, rows):
+    """The compaction's working set only shrank (a [C, T] staging
+    buffer, [T, T] one-hots): both cells keep the tile they ran with,
+    one resident block at 67 features, 64 stored rows (the 64-group cap)
+    at 2,000: the fused kernel at every chunk the tuner offers, the
+    root kernel (whose pricing did not change) at the cells' own."""
+    from lightgbm_tpu.ops import autotune
+    for variant, W in (("hilo5", 24), ("hilo4", 32)):
+        for chunk, fused in [(c, True) for c in (4096, 8192, 16384, 32768)
+                             ] + [(16384, False)]:
+            assert autotune.hist_feature_tile(
+                F=F, B=255, W=W, chunk=chunk, fused=fused,
+                variant=variant) == rows, (variant, chunk, fused)
+    geom = autotune.hist_geometry(F=min(F, rows), B=255, W=24, F_rows=rows)
+    blk = autotune.fused_hist_block_shapes(
+        chunk=16384, geom=geom, tbl_rows=24,
+        compact_tile=autotune.HIST_COMPACT_TILE, tiled=F > rows)
+    assert blk["staged"] == (
+        autotune.HIST_COMPACT_PAY_ROWS + -(-rows // 16) * 16,
+        autotune.HIST_COMPACT_TILE)
 
 
 def _pallas_eqns(jaxpr):

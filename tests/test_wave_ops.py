@@ -6,6 +6,8 @@ kernel (hist_wave.py) now wired into the grower. The Pallas kernels run
 in interpret mode on the CPU test backend — same code path as TPU, with
 HIGHEST-precision dots standing in for the MXU's exact bf16 products.
 """
+import math
+
 import numpy as np
 import pytest
 
@@ -625,21 +627,44 @@ class TestPacked4:
 # stable row compaction ahead of the fused kernel's one-hot dot
 # ---------------------------------------------------------------------------
 
-_CT = 256          # the cases' chunk; the compaction tile is gcd(chunk, 512)
+_CT = 256          # the first cases' chunk; the tile is gcd(chunk, 512)
+
+# the boundaries of the T-wide staging route: (chunk, rows, selected
+# rows of each T-row sub-tile in turn). `c` = rows staged before a turn
+_FILLS = {
+    # c + n_sel == T exactly: the tile fills, nothing is carried over
+    "fills_exactly": (_CT, 1000, [100, 156, 10, 0]),
+    # a fully selected sub-tile arrives at c = T - 1; then one more row
+    # fills the carried T - 1 exactly
+    "full_sub_tile_at_t_minus_1": (_CT, 1000, [255, 256, 1, 0]),
+    # two sub-tiles a grid step: the step's LAST turn overflows and the
+    # remainder rides into the next chunk; a fully selected sub-tile
+    # later; the last step's extra turn flushes a part tile
+    "overflow_carried_into_next_chunk": (1024, 3000,
+                                         [300, 400, 0, 100, 512, 0]),
+    # the last live turn of the last step overflows: its remainder is
+    # the part tile the extra turn flushes
+    "extra_turn_after_last_overflow": (1024, 3000, [0, 0, 0, 0, 400, 300]),
+    # a flush every turn, two turns a step, over three chunks
+    "every_row_several_chunks": (1024, 3000, [512] * 5 + [440]),
+}
+_KINDS = ["none", "under_one_tile", "exact_tiles", "every_row",
+          "oob_passengers_padded_tail"] + sorted(_FILLS)
 
 
 def _compaction_case(kind):
-    """(kernel args, oracle args, B, contributing rows) of one wave.
-    In the first four cases nobody moves (thresholds past every bin),
-    the left child keeps the parent's id and is the smaller one, so the
-    contributing rows are the in-bag rows of the four parents: placed
-    by hand. The last has real splits, out-of-bag rows, zero-weight
-    passenger rows and a chunk-padded tail."""
+    """(kernel args, oracle args, B, smaller children, bag mask, chunk)
+    of one wave. In every case but one nobody moves (thresholds past
+    every bin), the left child keeps the parent's id and is the smaller
+    one, so the contributing rows are the in-bag rows of the four
+    parents: placed by hand, at random or (_FILLS) so many a sub-tile.
+    "oob_passengers_padded_tail" has real splits, out-of-bag rows,
+    zero-weight passenger rows and a chunk-padded tail."""
     r = np.random.default_rng(31)
     F, B, W = 5, 64, 8
-    N = 1000
+    chunk, N, fills = _FILLS.get(kind, (_CT, 1000, None))
     n_in = {"none": 0, "under_one_tile": 37, "exact_tiles": 2 * _CT,
-            "every_row": N, "oob_passengers_padded_tail": None}[kind]
+            "every_row": N}.get(kind)
     bins_t = r.integers(0, 63, (F, N)).astype(np.uint8)
     g = r.normal(size=N).astype(np.float32)
     h = r.uniform(0.1, 1, N).astype(np.float32)
@@ -650,7 +675,7 @@ def _compaction_case(kind):
     miss = np.array([0, 1, 2, 0, 1], np.int32)[feat]
     defb = np.array([0, 3, 0, 0, 5], np.int32)[feat]
     nb = np.full(W, B, np.int32)
-    if n_in is None:
+    if kind == "oob_passengers_padded_tail":
         mask = (r.uniform(size=N) > 0.3).astype(np.float32)
         leaf = r.integers(0, 6, N).astype(np.int32)
         leaf[leaf >= 4] += 4                          # 8, 9: not split
@@ -663,8 +688,14 @@ def _compaction_case(kind):
     else:
         mask = np.ones(N, np.float32)
         leaf = np.full(N, 9, np.int32)                # not in the wave
-        leaf[r.choice(N, n_in, replace=False)] = \
-            r.integers(0, 4, n_in).astype(np.int32)
+        if fills is None:
+            rows_in = r.choice(N, n_in, replace=False)
+        else:
+            T = math.gcd(chunk, 512)
+            rows_in = np.concatenate([
+                t * T + r.choice(min(T, N - t * T), k, replace=False)
+                for t, k in enumerate(fills)])
+        leaf[rows_in] = r.integers(0, 4, len(rows_in)).astype(np.int32)
         tbin = np.full(W, B + 10, np.int32)
         miss[:] = 0
         small = wl.copy()
@@ -677,12 +708,10 @@ def _compaction_case(kind):
                  (bins_t, gm, hm, mask, leaf, wl, new_ids, feat, tbin,
                   dleft, np.zeros(W, bool), np.zeros((W, 8), np.int32),
                   small, miss, defb, nb))
-    return kern, orac, B, small, mask
+    return kern, orac, B, small, mask, chunk
 
 
-@pytest.mark.parametrize("kind", ["none", "under_one_tile", "exact_tiles",
-                                  "every_row",
-                                  "oob_passengers_padded_tail"])
+@pytest.mark.parametrize("kind", _KINDS)
 def test_compacted_fused_kernel_matches_xla(kind):
     """Only rows in the wave's smaller children reach the dot, a tile
     at a time: leaf ids and counts bit-equal to the XLA twin, g/h
@@ -690,10 +719,11 @@ def test_compacted_fused_kernel_matches_xla(kind):
     contributing rows rounded up to whole tiles."""
     from lightgbm_tpu.ops.hist_wave import (COMPACT_TILE_UNIT,
                                             fused_partition_histogram_xla)
-    kern, orac, B, small, mask = _compaction_case(kind)
+    kern, orac, B, small, mask, chunk = _compaction_case(kind)
+    T = math.gcd(chunk, 512)
     leaf_x, hist_x = fused_partition_histogram_xla(*orac, num_bins=B)
     leaf_c, hist_c, work = fused_partition_histogram_pallas(
-        *kern, num_bins=B, chunk=_CT, interpret=True, compact=True)
+        *kern, num_bins=B, chunk=chunk, interpret=True, compact=True)
     np.testing.assert_array_equal(np.asarray(leaf_c), np.asarray(leaf_x))
     hc, hx = np.asarray(hist_c), np.asarray(hist_x)
     np.testing.assert_array_equal(hc[..., 2], hx[..., 2])
@@ -701,12 +731,64 @@ def test_compacted_fused_kernel_matches_xla(kind):
     contributing = int((np.isin(np.asarray(leaf_x), small[small >= 0])
                         & (mask > 0)).sum())
     scanned, dotted = (int(v) * COMPACT_TILE_UNIT for v in work)
-    assert scanned == -(-len(mask) // _CT) * _CT
-    assert dotted == -(-contributing // _CT) * _CT
+    assert scanned == -(-len(mask) // chunk) * chunk
+    assert dotted == -(-contributing // T) * T
     # the same pass without compaction dots every row it scans
     *_, work_m = fused_partition_histogram_pallas(
-        *kern, num_bins=B, chunk=_CT, interpret=True, compact=False)
+        *kern, num_bins=B, chunk=chunk, interpret=True, compact=False)
     assert int(work_m[1]) == int(work_m[0]) == scanned // COMPACT_TILE_UNIT
+
+
+@pytest.mark.parametrize("tier", ["hilo5", "int8"])
+def test_compacted_histogram_is_the_tiles_of_a_stable_compaction(tier):
+    """BIT-equal to "stable compaction, T-row tiles dotted in order",
+    emulated in NumPy: the contributing rows (in one of the wave's
+    smaller children after the pass, carrying weight) are taken in row
+    order and cut into T-row tiles, the last padded with weightless
+    rows. The int8 tier's integer sums are then plain NumPy adds; the
+    exact tier's f32 sums of a tile are the masked kernel's over that
+    tile alone (chunk T, one tile a grid step, added in turn), so the
+    order of every f32 addition is pinned."""
+    from lightgbm_tpu.ops.hist_wave import (COMPACT_TILE_UNIT,
+                                            fused_partition_histogram_xla)
+    kern, orac, B, small, mask, chunk = _compaction_case(
+        "oob_passengers_padded_tail")
+    bins_t, g, h, _, leaf, tbl = (np.asarray(x) for x in kern)
+    T = math.gcd(chunk, 512)
+    kw = dict(num_bins=B, interpret=True)
+    if tier == "int8":
+        g = np.clip(np.round(g * 40), -127, 127).astype(np.float32)
+        h = np.round(h * 100).astype(np.float32) * mask
+        kw.update(precision="int8", gh_scale=(1.0, 1.0), dequant=False)
+    leaf_new = np.asarray(fused_partition_histogram_xla(
+        *orac, num_bins=B)[0])
+    keep = np.flatnonzero(np.isin(leaf_new, small[small >= 0])
+                          & ((mask > 0) | (g != 0) | (h != 0)))
+    assert T < len(keep) and len(keep) % T          # a part tile at the end
+    pad = (-len(keep)) % T
+    rows = np.concatenate([keep, np.zeros(pad, keep.dtype)])
+    live = np.arange(len(rows)) < len(keep)
+    packed = (bins_t[:, rows], g[rows] * live, h[rows] * live,
+              mask[rows] * live, np.where(live, leaf[rows], 9))
+    _, hist_c, work = fused_partition_histogram_pallas(
+        bins_t, g, h, mask, leaf, tbl, chunk=chunk, compact=True, **kw)
+    assert int(work[1]) * COMPACT_TILE_UNIT == len(rows)
+    hist_c = np.asarray(hist_c)
+    if tier == "int8":
+        assert hist_c.dtype == np.int32
+        want = np.zeros_like(hist_c)
+        slot = {int(v): k for k, v in enumerate(small) if v >= 0}
+        k_of = np.array([slot[int(v)] for v in leaf_new[keep]])
+        for f in range(bins_t.shape[0]):
+            for c, w in enumerate((g, h, mask)):
+                np.add.at(want[:, f, :, c], (k_of, bins_t[f, keep]),
+                          w[keep].astype(np.int32))
+    else:
+        _, want, work_e = fused_partition_histogram_pallas(
+            *packed, tbl, chunk=T, compact=False, **kw)
+        assert int(work_e[1]) * COMPACT_TILE_UNIT == len(rows)
+        want = np.asarray(want)
+    assert hist_c.tobytes() == want.tobytes()
 
 
 @pytest.mark.parametrize("tier", ["default", "int8", "int8_proxy",
