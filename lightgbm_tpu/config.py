@@ -129,7 +129,7 @@ def _compile_cache_from_cpu_knob(v: Any) -> int:
     """Value remap of the pre-rename tpu_compile_cache_cpu: its 1 (CPU
     opt-in) is tpu_compile_cache=1; its 0 meant "CPU off, TPU still
     on" — which is the new knob's -1 auto, NOT its 0 (that would turn
-    the cache off on TPU/GPU too)."""
+    the cache off on the TPU too)."""
     try:
         return 1 if int(float(v)) == 1 else -1
     except (TypeError, ValueError):
@@ -505,8 +505,8 @@ class Config:
     # multiple of N.
     tpu_serve_bucket: int = -1
     # persistent XLA compile cache, backend-aware (ops/autotune.py
-    # ensure_compile_cache): -1 = auto — wired on TPU and GPU (where
-    # the expensive Mosaic/Triton compiles live), off on CPU; 1 = on
+    # ensure_compile_cache): -1 = auto — wired on the TPU (where the
+    # expensive Mosaic compiles live), off elsewhere; 1 = on
     # everywhere; 0 = off on every backend. A cache directory the
     # operator placed (JAX_COMPILATION_CACHE_DIR) always wins and is
     # used as is; otherwise the cache lives in the one fixed

@@ -591,11 +591,11 @@ class GBDT:
                     # would reshard every shard boundary
                     self._pad_rows = ing
         elif mode == "serial":
-            from ..utils.device import backend_kind
-            if backend_kind() in ("tpu", "gpu"):
-                # both Pallas kernel families pad rows to a chunk
-                # multiple internally — aligning up front avoids the
-                # per-step re-pad
+            from ..utils.device import on_tpu
+            if on_tpu():
+                # the Pallas kernels pad rows to a chunk multiple
+                # internally — aligning up front avoids the per-step
+                # re-pad
                 self._pad_rows = (-self._n) % kchunk
         # alignment unit the row padding above respects — the bucketed
         # score width must stay a multiple of it (even shards for the
@@ -604,8 +604,8 @@ class GBDT:
         if mode in ("data", "voting"):
             unit = step_cache.shard_align_unit(self._n, D, kchunk)
         elif mode == "serial":
-            from ..utils.device import backend_kind
-            unit = kchunk if backend_kind() in ("tpu", "gpu") else 1
+            from ..utils.device import on_tpu
+            unit = kchunk if on_tpu() else 1
         else:
             unit = 1
         self._row_align_unit = unit
@@ -747,8 +747,8 @@ class GBDT:
             sparse_hist=sparse_tier,
             # resolved per device kind so the step-cache geometry key
             # (which hashes this config) separates programs compiled
-            # for different kernel families — a GPU-route step never
-            # serves a CPU restore of the same geometry
+            # for different kernel families — a Mosaic-route step
+            # never serves a CPU restore of the same geometry
             route=tune_hist_route(
                 fused_eligible=not self._use_bundles
                 and not sparse_tier))

@@ -407,122 +407,27 @@ def tpu_compiler_params(*, vmem_limit_bytes: int = PALLAS_VMEM_LIMIT_BYTES):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
 
 
-# ---------------------------------------------------------------------------
-# GPU (Pallas-Triton) tier geometry: shared-memory pricing, per-CTA tiles
-# ---------------------------------------------------------------------------
-
-# per-CTA shared-memory budget the GPU tile guards admit against — the
-# smem role VMEM plays on TPU, with the same headroom discipline: 48 KB
-# is the portable static-smem floor every supported CUDA arch provides
-# without opt-in dynamic carve-outs, and the Triton compiler's own
-# reduction scratch must fit beside our blocks
-GPU_SMEM_LIMIT_BYTES = 48 * 1024
-GPU_SMEM_BUDGET_BYTES = 40 * 1024
-
-# default per-CTA row tile of the GPU histogram kernels (the role
-# DEFAULT_HIST_CHUNK plays on TPU; the histogram itself accumulates in
-# global memory via atomics, so the row tile prices only the streamed
-# bins/gradient blocks — much smaller tiles than the TPU's 8k/16k
-# VMEM-resident chunks)
-DEFAULT_GPU_HIST_CHUNK = 1024
-DEFAULT_GPU_ROW_TILE = 1024
-
-
-def gpu_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
-                          fused: bool, tbl_rows: Optional[int] = None
-                          ) -> Dict[str, tuple]:
-    """Per-CTA block shapes of the GPU wave/fused histogram kernels —
-    their BlockSpecs are built from THESE tuples (same can't-drift
-    contract as wave_hist_block_shapes on TPU). The histogram output
-    lives in global memory (atomic accumulation), so only the streamed
-    row blocks and the small split tables are priced."""
-    s = {
-        "wl": (geom["wp"],),                              # i32 const
-        "bins": (geom["F_rows"], chunk),                  # grid-indexed
-        "gh": (2, chunk),                                 # grid-indexed
-    }
-    if fused:
-        if tbl_rows is None:
-            from .hist_wave import TBL_ROWS
-            tbl_rows = TBL_ROWS
-        s["tbl"] = (tbl_rows, geom["wp"])                 # i32 const
-        s["mask"] = (chunk,)                              # grid-indexed
-        s["leaf"] = (chunk,)                              # grid-indexed
-        s["leaf_out"] = (chunk,)                          # grid-indexed
-    return s
-
-
-def gpu_hist_smem_bytes(*, chunk: int, geom: Dict[str, int], fused: bool,
-                        bins_bytes: int = 1,
-                        tbl_rows: Optional[int] = None) -> int:
-    """Working-set bytes of one GPU histogram CTA, priced from the SAME
-    block shapes the BlockSpecs use plus the per-row temporaries (the
-    [F] flat-index/value vectors of the atomic scatter)."""
-    s = gpu_hist_block_shapes(chunk=chunk, geom=geom, fused=fused,
-                              tbl_rows=tbl_rows)
-    b = (_nelem(s["bins"]) * bins_bytes
-         + _nelem(s["gh"]) * 4
-         + _nelem(s["wl"]) * 4)
-    if fused:
-        b += (_nelem(s["tbl"]) * 4
-              + _nelem(s["mask"]) * 4
-              + 2 * _nelem(s["leaf"]) * 4)
-    # per-row scatter temporaries: [F] i32 flat indices + [F] f32 vals
-    # per channel (3 channels), plus the [W] slot-compare vector
-    b += geom["F_rows"] * 4 * 4 + geom["wp"] * 4
-    return b
-
-
-def fits_smem(nbytes: int) -> bool:
-    return nbytes <= GPU_SMEM_BUDGET_BYTES
-
-
-def gpu_compiler_params(*, num_warps: int = 4, num_stages: int = 2):
-    """Pallas-Triton CompilerParams of the GPU histogram/forest kernels
-    (interpret-mode callers pass None instead)."""
-    from jax.experimental.pallas import triton as plgpu
-    return plgpu.CompilerParams(num_warps=num_warps, num_stages=num_stages)
-
-
-@functools.lru_cache(maxsize=1)
-def gpu_pallas_supported() -> bool:
-    """Is the Pallas-Triton lowering importable in this jax? Gates the
-    pallas-gpu route (tune_hist_route) and the gpu_tier test module's
-    clean skip — capability, not device presence (interpret-mode parity
-    runs on any backend)."""
-    try:
-        from jax.experimental.pallas import triton  # noqa: F401
-        return True
-    except Exception:       # noqa: BLE001 — absent lowering = no route
-        return False
-
-
 # the capability ladder of the histogram hot loop, best-first; the
 # chosen rung rides WaveGrowerConfig.route into the step-cache geometry
 # key (different backends = different compiled programs)
-HIST_ROUTES = ("pallas-tpu", "pallas-gpu", "fused-xla", "two-pass")
+HIST_ROUTES = ("pallas-tpu", "fused-xla", "two-pass")
 
 
-def tune_hist_route(*, backend: Optional[str] = None,
-                    use_pallas: Optional[bool] = None,
+def tune_hist_route(*, use_pallas: Optional[bool] = None,
                     fused_eligible: bool = True) -> str:
-    """The histogram hot-loop route for this backend, by capability:
-    the device's own Pallas tier when it can lower ("pallas-tpu" /
-    "pallas-gpu" — the Triton rung additionally needs the Pallas-Triton
-    lowering importable), else the fused single-pass XLA kernel, else
-    the legacy two-pass partition+histogram. ``use_pallas`` is the
-    config override (None = auto); ``fused_eligible`` is the caller's
-    structural gate (default kernel seams, no EFB bundles, no sparse
-    tier — ops/wave_grower.py owns it)."""
-    from ..utils.device import backend_kind
-    b = backend or backend_kind()
-    pallas = use_pallas if use_pallas is not None else (
-        b == "tpu" or (b == "gpu" and gpu_pallas_supported()))
-    if pallas:
-        return "pallas-gpu" if b == "gpu" else "pallas-tpu"
-    if fused_eligible:
-        return "fused-xla"
-    return "two-pass"
+    """The histogram hot-loop route, by capability: the Mosaic kernels
+    ("pallas-tpu") on a TPU, else the fused single-pass XLA kernel,
+    else the legacy two-pass partition+histogram. ``use_pallas`` is
+    the config override (None = auto: the device decides);
+    ``fused_eligible`` is the caller's structural gate (default kernel
+    seams, no EFB bundles, no sparse tier — ops/wave_grower.py owns
+    it)."""
+    if use_pallas is None:
+        from ..utils.device import on_tpu
+        use_pallas = on_tpu()
+    if use_pallas:
+        return "pallas-tpu"
+    return "fused-xla" if fused_eligible else "two-pass"
 
 
 # ---------------------------------------------------------------------------
@@ -651,8 +556,8 @@ class Autotuner:
         rejection is a defect to report, and a key whose EVERY
         candidate failed is fatal (training on a default nobody could
         compile would only fail later, or worse, run as something
-        else). Off-TPU (injected timers, the never-run GPU arm) the
-        default is still served."""
+        else). Off-TPU (injected timers) the default is still
+        served."""
         if not candidates:
             return default
         if self.mode == "off":
@@ -662,8 +567,8 @@ class Autotuner:
         if hit is not None and hit.get("choice") in candidates:
             obs.counter("autotune/cache_hits").add(1)
             return hit["choice"]
-        from ..utils.device import backend_kind
-        tpu = backend_kind() == "tpu"
+        from ..utils.device import on_tpu
+        tpu = on_tpu()
         timings_ms: Dict[str, float] = {}
         best_c, best_t = None, float("inf")
         with timing.phase(f"autotune/{kernel}"):
@@ -747,20 +652,19 @@ def ensure_compile_cache(mode: int = -1) -> None:
     must never move between runs).
 
     ``mode`` is config.tpu_compile_cache's tri-state. The policy
-    matrix (Design.md §5i):
+    matrix:
 
     ========  ==========  =======  ========
     backend   -1 (auto)   0 (off)  1 (on)
     ========  ==========  =======  ========
     tpu       on          off      on
-    gpu       on          off      on
-    cpu       off         off      on
+    other     off         off      on
     ========  ==========  =======  ========
 
-    TPU and GPU auto-enable: that is where the expensive Mosaic /
-    Triton compiles live. The CPU backend stays opt-in — the CPU test
-    suite compiles hundreds of small programs whose cache writes cost
-    more than they save."""
+    The TPU auto-enables: that is where the expensive Mosaic compiles
+    live. Every other backend stays opt-in — the CPU test suite
+    compiles hundreds of small programs whose cache writes cost more
+    than they save."""
     global _compile_cache_done
     if _compile_cache_done:
         return
@@ -768,8 +672,8 @@ def ensure_compile_cache(mode: int = -1) -> None:
     if jax.config.jax_compilation_cache_dir:
         _compile_cache_done = True       # operator already placed it
         return
-    from ..utils.device import backend_kind
-    if mode == 0 or (backend_kind() == "cpu" and mode != 1):
+    from ..utils.device import on_tpu
+    if mode == 0 or (not on_tpu() and mode != 1):
         # NOT a terminal decision: a later booster may opt in
         # (tpu_compile_cache=1), so leave the flag unset
         return
@@ -815,30 +719,6 @@ def hist_chunk_candidates(*, F: int, B: int, W: int, fused: bool,
     return out[::-1]
 
 
-def gpu_hist_chunk_candidates(*, F: int, B: int, W: int, fused: bool,
-                              bins_bytes: int = 1, packed4: bool = False,
-                              n_rows: int = 0, exhaustive: bool = False
-                              ) -> List[dict]:
-    """Shared-memory-feasible per-CTA row tiles for the GPU histogram
-    kernels, largest-first — the same candidate-guard contract as
-    hist_chunk_candidates, priced by gpu_hist_smem_bytes instead of
-    hist_vmem_bytes. The int8 overflow guard does not apply: the GPU
-    quantized tier accumulates int32 in GLOBAL memory (per-cell atomic
-    adds), not a per-chunk VMEM-resident plane."""
-    geom = hist_geometry(F=F, B=B, W=W,
-                         F_rows=(F + 1) // 2 if packed4 else F)
-    base = ((128, 256, 512, 1024, 2048, 4096) if exhaustive
-            else (256, 512, 1024, 2048))
-    out = []
-    for c in base:
-        if n_rows and c > max(n_rows, base[0]):
-            continue
-        if fits_smem(gpu_hist_smem_bytes(chunk=c, geom=geom, fused=fused,
-                                         bins_bytes=bins_bytes)):
-            out.append({"chunk": c})
-    return out[::-1]
-
-
 def tune_hist_chunk(*, fused: bool, F: int, B: int, W: int,
                     precision: str = "highest", count_proxy: bool = False,
                     packed4: bool = False, any_cat: bool = False,
@@ -846,45 +726,16 @@ def tune_hist_chunk(*, fused: bool, F: int, B: int, W: int,
                     variant: Optional[str] = None, _measure=None) -> int:
     """The row chunk the histogram hot path should run with — tuned on
     first encounter of this (kernel, F, B, tier, device) key, cached
-    thereafter. On CPU (and with tpu_autotune=off) this returns the
-    measured per-tier default untouched. The GPU arm tunes per-CTA row
-    tiles against the shared-memory budget (gpu_hist_chunk_candidates)
-    under its own kernel names, so cached TPU decisions are untouched;
-    timing needs a real GPU — ``_measure`` injects a fake timer so the
-    decision logic unit-tests off-GPU (it routes the GPU arm on any
-    non-TPU backend)."""
+    thereafter. Off the TPU (and with tpu_autotune=off) this returns
+    the measured per-tier default untouched; ``_measure`` injects a
+    fake timer so the decision logic unit-tests without a chip."""
     int8 = precision == "int8"
     default = DEFAULT_HIST_CHUNK_INT8 if int8 else DEFAULT_HIST_CHUNK
     t = tuner()
-    from ..utils.device import backend_kind
-    backend = backend_kind()
-    if t.mode == "off" or (backend == "cpu" and _measure is None):
+    from ..utils.device import on_tpu
+    if t.mode == "off" or (not on_tpu() and _measure is None):
         return default
     variant = variant if precision == "highest" else None
-    if backend == "gpu" or (backend != "tpu" and _measure is not None):
-        cands = gpu_hist_chunk_candidates(
-            F=F, B=B, W=W, fused=fused, bins_bytes=bins_bytes,
-            packed4=packed4, n_rows=n_rows,
-            exhaustive=t.mode == "exhaustive")
-        if not cands:
-            return DEFAULT_GPU_HIST_CHUNK
-        if len(cands) == 1:
-            return int(cands[0]["chunk"])
-        tier = precision + ("+proxy" if count_proxy else "") \
-            + ("+packed4" if packed4 else "")
-        key = {"F": F, "B": B, "W": W, "tier": tier, "fused": fused,
-               "cat": bool(any_cat), "bins_bytes": bins_bytes,
-               "device": device_kind(),
-               "chunks": [c["chunk"] for c in cands]}
-        measure = _measure or _hist_measure_fn_gpu(
-            fused=fused, F=F, B=B, W=W, precision=precision,
-            count_proxy=count_proxy, packed4=packed4, any_cat=any_cat,
-            bins_bytes=bins_bytes,
-            n_meas=_hist_measure_rows(cands, F, bins_bytes))
-        choice = t.best("fused_hist_gpu" if fused else "wave_hist_gpu",
-                        key, cands, measure,
-                        default={"chunk": DEFAULT_GPU_HIST_CHUNK})
-        return int(choice["chunk"])
     cands = hist_chunk_candidates(
         F=F, B=B, W=W, fused=fused, bins_bytes=bins_bytes, int8=int8,
         count_proxy=count_proxy, packed4=packed4, n_rows=n_rows,
@@ -955,12 +806,11 @@ def tune_exact_tier(*, F: int, B: int, n_rows: int = 0,
     feasible layouts are timed once (fused kernel at each layout's own
     wave cap, wall NORMALIZED PER SPLIT — t/W — because the layouts
     trade MXU dots per pass against passes per tree) and the winner is
-    cached; off-TPU the choice is ANALYTIC — the CPU XLA oracle is
-    layout-free, and the GPU scatter kernels accumulate one full-f32
-    channel per plane (no 128-lane budget to split), so on both the
-    variant only sets the wave-width cap and the widest feasible wave
-    wins (fewer full-data scatter passes per tree — the measured
-    off-TPU win). tpu_autotune=off pins the pre-variant "hilo5".
+    cached; off-TPU the choice is ANALYTIC — the XLA oracle is
+    layout-free, so the variant only sets the wave-width cap and the
+    widest feasible wave wins (fewer full-data scatter passes per tree
+    — the measured off-TPU win). tpu_autotune=off pins the pre-variant
+    "hilo5".
     ``_measure`` injects a fake timer (unit tests; it forces the timed
     arm on any backend — the key's device field keeps entries
     apart)."""
@@ -978,7 +828,7 @@ def tune_exact_tier(*, F: int, B: int, n_rows: int = 0,
         return "hilo5"
     from ..utils.device import on_tpu
     if not on_tpu() and _measure is None:
-        # the analytic arm — CPU and GPU alike (see docstring)
+        # the analytic arm (see docstring)
         return cands[0]["variant"]
     key = {"F": F, "B": B, "cat": bool(any_cat),
            "bins_bytes": bins_bytes, "device": device_kind(),
@@ -1025,16 +875,10 @@ def _exact_tier_measure_fn(*, F, B, any_cat, bins_bytes, n_rows):
 # rule (not a timed sweep) because the tier also changes EXACTNESS
 # (see tune_hist_tier), so auto only engages where it is bit-equal
 SPARSE_TIER_MAX_DENSITY = 0.125
-# the GPU arm's lower ceiling: on the gpu route, choosing the sparse
-# tier forfeits the pallas-gpu fused kernel (the sparse tier runs the
-# XLA scatter path), so the sparse side must win by more than it does
-# on backends where both tiers are XLA
-SPARSE_TIER_MAX_DENSITY_GPU = 1.0 / 16.0
 
 
 def tune_hist_tier(*, requested: int, density: float, nnz: int,
-                   F: int, B: int, W: int, quant: bool,
-                   backend: Optional[str] = None) -> bool:
+                   F: int, B: int, W: int, quant: bool) -> bool:
     """True = the sparse histogram tier (ops/hist_wave.py
     wave_histogram_sparse, scatter over nnz) serves this booster;
     False = the dense one-hot tier. Selected per (density, geometry)
@@ -1046,9 +890,7 @@ def tune_hist_tier(*, requested: int, density: float, nnz: int,
     The auto rule is exactness-first: integer (quantized) accumulation
     is order-free, so the sparse completion subtraction is BIT-equal
     to the dense tier — auto therefore requires ``quant`` AND density
-    under the backend's ceiling (SPARSE_TIER_MAX_DENSITY, or the lower
-    SPARSE_TIER_MAX_DENSITY_GPU on the gpu route — ``backend`` pins it
-    for decision unit tests, None reads the live backend_kind()).
+    under SPARSE_TIER_MAX_DENSITY.
     tpu_sparse=1 forces the tier for f32 histograms too (final-ulp
     reassociation drift vs the dense tier is possible; logged)."""
     if requested == 0:
@@ -1062,12 +904,7 @@ def tune_hist_tier(*, requested: int, density: float, nnz: int,
         return True
     if not quant:
         return False
-    if backend is None:
-        from ..utils.device import backend_kind
-        backend = backend_kind()
-    ceiling = (SPARSE_TIER_MAX_DENSITY_GPU if backend == "gpu"
-               else SPARSE_TIER_MAX_DENSITY)
-    return float(density) <= ceiling
+    return float(density) <= SPARSE_TIER_MAX_DENSITY
 
 
 # ---------------------------------------------------------------------------
@@ -1350,73 +1187,6 @@ def _hist_measure_fn(*, fused: bool, F: int, B: int, W: int,
                 precision=precision, gh_scale=gh_scale,
                 count_proxy=count_proxy, packed4=packed4,
                 num_features=F if packed4 else None, variant=variant)
-
-    return lambda cand: timing.measure(
-        functools.partial(run, int(cand["chunk"])))
-
-
-def _hist_measure_fn_gpu(*, fused: bool, F: int, B: int, W: int,
-                         precision: str, count_proxy: bool, packed4: bool,
-                         any_cat: bool, bins_bytes: int, n_meas: int):
-    """measure(candidate) for the GPU histogram kernels — the same
-    synthetic-data harness as _hist_measure_fn, pointed at the
-    Pallas-Triton kernels (non-interpret: this path only runs when a
-    real GPU is the backend; unit tests inject ``_measure`` instead).
-    No ``variant`` knob: the GPU scatter is layout-free, every hilo
-    variant lowers to the same kernel."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    from .hist_wave import (fused_partition_histogram_pallas_gpu,
-                            wave_histogram_pallas_gpu)
-
-    rng = np.random.default_rng(0)
-    int8 = precision == "int8"
-    F_rows = (F + 1) // 2 if packed4 else F
-    bdt = np.uint8 if bins_bytes == 1 else np.int32
-    bmax = 255 if packed4 else max(B - 1, 1)
-    bins = jnp.asarray(rng.integers(0, bmax + 1, (F_rows, n_meas),
-                                    dtype=np.int64).astype(bdt))
-    if int8:
-        g = jnp.asarray(rng.integers(-127, 128, n_meas).astype(np.float32))
-        h = jnp.asarray(rng.integers(0, 128, n_meas).astype(np.float32))
-        gh_scale = (1.0, 1.0)
-    else:
-        g = jnp.asarray(rng.normal(size=n_meas).astype(np.float32))
-        h = jnp.asarray(np.abs(rng.normal(size=n_meas)).astype(np.float32))
-        gh_scale = None
-    leaf_ids = jnp.zeros(n_meas, jnp.int32)
-    if fused:
-        mask = jnp.ones(n_meas, jnp.float32)
-        col = np.full(W, -1, np.int32)
-        tbl = np.zeros((18, W), np.int32)
-        tbl[0] = col                     # TBL_PARENT
-        tbl[1] = col                     # TBL_NEW
-        tbl[0, 0], tbl[1, 0] = 0, 1
-        tbl[3, 0] = B // 2               # TBL_BIN
-        tbl[7] = B                       # TBL_NUMBIN
-        tbl[8] = col                     # TBL_SMALL
-        tbl[8, 0] = 1
-        tbl_d = jnp.asarray(tbl)
-
-        def run(chunk):
-            return fused_partition_histogram_pallas_gpu(
-                bins, g, h, mask, leaf_ids, tbl_d, num_bins=B,
-                chunk=chunk, precision=precision, gh_scale=gh_scale,
-                any_cat=any_cat, count_proxy=count_proxy,
-                packed4=packed4, num_features=F if packed4 else None)
-    else:
-        wl = jnp.asarray(np.concatenate(
-            [np.zeros(1, np.int32), np.full(W - 1, -1, np.int32)])
-            if W > 1 else np.zeros(1, np.int32))
-
-        def run(chunk):
-            return wave_histogram_pallas_gpu(
-                bins, g, h, leaf_ids, wl, num_bins=B, chunk=chunk,
-                precision=precision, gh_scale=gh_scale,
-                count_proxy=count_proxy, packed4=packed4,
-                num_features=F if packed4 else None)
 
     return lambda cand: timing.measure(
         functools.partial(run, int(cand["chunk"])))

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.pallas import triton as plgpu
 
 from . import autotune
 
@@ -712,7 +711,7 @@ def wave_histogram(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
                    chunk=0, use_pallas=None, precision="highest",
                    gh_scale=None, count_proxy=False, dequant=True,
                    variant="hilo5", route=""):
-    """Dispatch: Pallas on TPU/GPU, XLA elsewhere (force via use_pallas
+    """Dispatch: Pallas on the TPU, XLA elsewhere (force via use_pallas
     or pin an explicit ``route`` — see autotune.tune_hist_route).
 
     precision="int8": g/h are integer-valued (quantized) and gh_scale
@@ -732,14 +731,6 @@ def wave_histogram(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
             route = "two-pass"
         else:
             route = autotune.tune_hist_route(use_pallas=use_pallas)
-    if route == "pallas-gpu":
-        from ..utils.device import backend_kind
-        return wave_histogram_pallas_gpu(
-            bins_t, g, h, leaf_ids, wave_leaves, num_bins=num_bins,
-            chunk=chunk or autotune.DEFAULT_GPU_HIST_CHUNK,
-            interpret=backend_kind() != "gpu",
-            precision=precision, gh_scale=gh_scale,
-            count_proxy=count_proxy, dequant=dequant, variant=variant)
     if route == "pallas-tpu":
         from ..utils.device import on_tpu
         return wave_histogram_pallas(
@@ -1514,406 +1505,3 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                           hist[:, :, 2],                   # h (bf16)
                           hist[:, :, 3]], axis=2)          # count
     return ret(leaf_out[0, :n], hist.transpose(3, 0, 1, 2))
-
-
-# ---------------------------------------------------------------------------
-# Pallas GPU (Triton) kernels
-# ---------------------------------------------------------------------------
-#
-# The GPU port keeps the SAME public contracts as the TPU kernels but a
-# completely different accumulation strategy: there is no MXU to feed,
-# so the one-hot matmul design would waste the device — instead the
-# histogram lives in GLOBAL memory and every (row, feature) contributes
-# via atomic adds (the canonical Triton histogram idiom, and the same
-# per-workgroup scatter shape as the reference's
-# ocl/histogram256.cl device kernels). Consequences:
-#
-# - No 128-lane budget: every hilo channel layout accumulates the full
-#   f32 (or int32) value per channel, so "hilo5"/"hilo4"/"hilo3" all
-#   lower to the SAME kernel (the variant only matters upstream, where
-#   it sets the wave-width cap). No bf16 hi/lo split, no wave caps.
-# - Bit-equality with the XLA oracle holds by ORDER: each histogram
-#   cell receives its adds in increasing global row order (grid blocks
-#   ascend, the in-block row loop ascends, and a cell is touched by
-#   exactly one feature), which is exactly the order XLA's scatter-add
-#   applies duplicate updates in. In interpret mode (grid steps
-#   sequential) this makes every output BIT-equal to the oracle — the
-#   tier-1 parity proof. On a real GPU, CTAs race: f32 sums can
-#   reassociate run-to-run, while the int8 tier's int32 adds are
-#   order-free and stay exact (the reason the quantized tier is the
-#   recommended GPU configuration).
-# - Zero-init rides input_output_aliases with a pre-zeroed operand
-#   (NOT a step-0 in-kernel zero, which would race the other CTAs'
-#   atomics on a real device).
-#
-# Out-of-wave rows land in a DUMP slot (index W) that is allocated and
-# sliced off — the GPU analog of the oracle's mode="drop" sentinel.
-
-
-def _gpu_unpack_row(bins_ref, r, F, packed4):
-    """Row ``r``'s logical per-feature bin vector [F] i32 — nibble
-    unpack for the 4-bit tier (feature 2p in the LOW nibble of byte
-    row p, matching _feature_row)."""
-    i32 = jnp.int32
-    if not packed4:
-        return bins_ref[:, r].astype(i32)
-    packed = bins_ref[:, r].astype(i32)                   # [ceil(F/2)]
-    f_iota = jax.lax.broadcasted_iota(i32, (F,), 0)
-    byte = packed[f_iota // 2]
-    return jnp.where(f_iota % 2 == 1,
-                     jax.lax.shift_right_logical(byte, 4),
-                     jnp.bitwise_and(byte, 15))
-
-
-def _gpu_wave_kernel(wl_ref, bins_ref, g_ref, h_ref, leaf_ref,
-                     hist0_ref, hist_ref, *, F, B, W, chunk,
-                     int8, count_proxy, packed4):
-    """One grid block = one row chunk; per row, one atomic-add per
-    channel over the F distinct flat targets
-    ``slot*F*B + f*B + bin_f`` (out-of-wave rows -> the dump slot W).
-
-    wl_ref:   [W] i32 wave leaf ids (-1 = inactive)
-    bins_ref: [F_rows, chunk] feature-major bins
-    g/h_ref:  [chunk] f32 (int8 tier: integer-valued)
-    leaf_ref: [chunk] i32 leaf ids (-1 = out of bag / padding)
-    hist_ref: [(W+1)*F*B, C] flat accumulator (aliased to the
-              pre-zeroed hist0_ref input; C = 2 with count_proxy)
-    """
-    del hist0_ref                      # aliased: its values ARE hist_ref
-    i32 = jnp.int32
-    wl = wl_ref[...]                                      # [W]
-    offs = jax.lax.broadcasted_iota(i32, (F,), 0) * B     # [F]
-
-    def body(r, carry):
-        lid = leaf_ref[r]
-        eq = (wl == lid) & (wl >= 0)
-        fnd = jnp.any(eq)
-        slot = jnp.where(fnd, jnp.argmax(eq).astype(i32), W)
-        flat = slot * (F * B) + offs + _gpu_unpack_row(
-            bins_ref, r, F, packed4)                      # [F] distinct
-        if int8:
-            gq = jnp.full((F,), g_ref[r].astype(i32))
-            hq = jnp.full((F,), h_ref[r].astype(i32))
-            plgpu.atomic_add(hist_ref, (flat, 0), gq)
-            plgpu.atomic_add(hist_ref, (flat, 1), hq)
-            if not count_proxy:
-                plgpu.atomic_add(hist_ref, (flat, 2),
-                              jnp.full((F,), jnp.int32(1)))
-        else:
-            plgpu.atomic_add(hist_ref, (flat, 0),
-                          jnp.full((F,), g_ref[r]))
-            plgpu.atomic_add(hist_ref, (flat, 1),
-                          jnp.full((F,), h_ref[r]))
-            plgpu.atomic_add(hist_ref, (flat, 2),
-                          jnp.full((F,), jnp.float32(1.0)))
-        return carry
-
-    jax.lax.fori_loop(0, chunk, body, 0)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "chunk", "interpret",
-                                    "precision", "count_proxy",
-                                    "packed4", "num_features",
-                                    "dequant", "variant"))
-def wave_histogram_pallas_gpu(bins_t, g, h, leaf_ids, wave_leaves, *,
-                              num_bins, chunk=0, interpret=False,
-                              precision="highest", gh_scale=None,
-                              count_proxy=False, packed4=False,
-                              num_features=None, dequant=True,
-                              variant="hilo5"):
-    """Pallas-Triton wave histogram — same contract (and, in interpret
-    mode, same BITS) as wave_histogram_xla / wave_histogram_pallas.
-
-    precision="highest"/"default" both accumulate full f32 per channel
-    (no lane budget to ration — see the section comment; ``variant``
-    is accepted for interface parity and ignored). precision="int8"
-    accumulates the pre-quantized integer g/h in int32 — atomically
-    ORDER-FREE, so exact on a real GPU too — and ``gh_scale``
-    dequantizes (``dequant=False`` returns the raw int32 sums, the
-    quantized-psum wire format). count_proxy (int8 only) drops the
-    count channel like the TPU kernel: [W, F, B, 2] out.
-    """
-    del variant                        # layout-free on GPU
-    F, n = bins_t.shape
-    if packed4:
-        if num_bins > 16:
-            raise NotImplementedError("packed4 needs max_bin <= 16")
-        F = int(num_features)
-    W = int(wave_leaves.shape[0])
-    B = num_bins
-    int8 = precision == "int8"
-    if count_proxy and not int8:
-        raise NotImplementedError("count_proxy requires precision='int8'")
-    chunk = chunk or autotune.DEFAULT_GPU_HIST_CHUNK
-    if int8 and 127 * (n + (-n) % chunk) >= 2 ** 31:
-        raise NotImplementedError(
-            "int8 histogram sums could overflow int32 beyond ~16.9M "
-            "rows; disable tpu_quantized_hist")
-
-    pad = (-n) % chunk
-    if pad:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad)))
-        g = jnp.pad(g, (0, pad))
-        h = jnp.pad(h, (0, pad))
-        leaf_ids = jnp.pad(leaf_ids, (0, pad), constant_values=-1)
-    n_pad = n + pad
-
-    C = 2 if count_proxy else 3
-    acc_dt = jnp.int32 if int8 else jnp.float32
-    size = (W + 1) * F * B                       # + the dump slot
-    hist0 = jnp.zeros((size, C), acc_dt)
-    F_rows = bins_t.shape[0]
-
-    kernel = functools.partial(
-        _gpu_wave_kernel, F=F, B=B, W=W, chunk=chunk, int8=int8,
-        count_proxy=count_proxy, packed4=packed4)
-
-    hist = pl.pallas_call(
-        kernel,
-        grid=(n_pad // chunk,),
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),
-            pl.BlockSpec((F_rows, chunk), lambda i: (0, i)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((size, C), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((size, C), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((size, C), acc_dt),
-        input_output_aliases={5: 0},
-        compiler_params=(None if interpret
-                         else autotune.gpu_compiler_params()),
-        name="wave_histogram_pallas_gpu",
-        interpret=interpret,
-    )(wave_leaves.astype(jnp.int32), bins_t,
-      g.astype(jnp.float32), h.astype(jnp.float32),
-      leaf_ids.astype(jnp.int32), hist0)
-
-    out = hist[:W * F * B].reshape(W, F, B, C)
-    if int8:
-        if not dequant:
-            return out
-        if count_proxy:
-            return out.astype(jnp.float32) * jnp.stack(
-                [jnp.float32(gh_scale[0]), jnp.float32(gh_scale[1])])
-        return out.astype(jnp.float32) * _qscale_vec(gh_scale)
-    return out
-
-
-def _gpu_fused_kernel(tbl_ref, bins_ref, g_ref, h_ref, mask_ref,
-                      leaf_ref, hist0_ref, cnt0_ref, hist_ref,
-                      leaf_out_ref, cnt_ref, *, F, B, W, chunk,
-                      int8, any_cat, count_proxy, packed4):
-    """One grid block: partition one row chunk by the wave's W splits
-    (vectorized compare math, bit-identical to ops/partition.py
-    row_goes_right — the same logical forms as the TPU _fused_kernel),
-    then scatter the wave's smaller-child histograms with per-row
-    atomic adds.
-
-    tbl_ref: [18, W] i32 packed split table (TBL_* ROWS — the GPU
-    kernel reads the table in its natural orientation; no 128-lane
-    transpose). cnt_ref: [W] f32 per-slot moved-row counts (aliased
-    pre-zeroed; count_proxy only — a 1-element stub otherwise).
-    """
-    del hist0_ref, cnt0_ref            # aliased pre-zeroed operands
-    i32 = jnp.int32
-    leaf = leaf_ref[...]                                   # [chunk]
-    parent = tbl_ref[TBL_PARENT, :]                        # [W]
-    new_ids = tbl_ref[TBL_NEW, :]
-    feat = tbl_ref[TBL_FEAT, :]
-    tbin = tbl_ref[TBL_BIN, :]
-    dleft = tbl_ref[TBL_DLEFT, :]
-    miss = tbl_ref[TBL_MISS, :]
-    defb = tbl_ref[TBL_DEFBIN, :]
-    nb = tbl_ref[TBL_NUMBIN, :]
-    small = tbl_ref[TBL_SMALL, :]
-    iscat = tbl_ref[TBL_ISCAT, :]
-
-    # ---- vectorized partition, [W, chunk] orientation ----
-    safe_feat = jnp.maximum(feat, 0)
-    if packed4:
-        packed = bins_ref[safe_feat // 2, :].astype(i32)   # [W, chunk]
-        cols = jnp.where((safe_feat % 2 == 1)[:, None],
-                         jax.lax.shift_right_logical(packed, 4),
-                         jnp.bitwise_and(packed, 15))
-    else:
-        cols = bins_ref[safe_feat, :].astype(i32)          # [W, chunk]
-    na_sent = jnp.where(miss == 2, nb - 1, -9)[:, None]
-    def_sent = jnp.where(miss == 1, defb, -9)[:, None]
-    is_missing = (cols == na_sent) | (cols == def_sent)
-    gt = cols > tbin[:, None]
-    ndl = (dleft == 0)[:, None]
-    right = gt ^ (is_missing & (gt ^ ndl))
-    if any_cat:
-        widx = jnp.right_shift(cols, 5)
-        word = jnp.zeros_like(cols)
-        for wq in range(8):
-            word = jnp.where(widx == wq,
-                             tbl_ref[TBL_CATW + wq, :][:, None], word)
-        cat_left = jnp.bitwise_and(
-            jnp.right_shift(word, jnp.bitwise_and(cols, 31)), 1) != 0
-        iscat_b = (iscat > 0)[:, None]
-        right = (iscat_b & ~cat_left) | (~iscat_b & right)
-    eq = (leaf[None, :] == parent[:, None]) \
-        & (parent >= 0)[:, None]                           # [W, chunk]
-    moved = eq & right
-    dest1 = jnp.sum(jnp.where(moved, (new_ids + 1)[:, None], 0), axis=0)
-    leaf_new = jnp.where(dest1 > 0, dest1 - 1, leaf).astype(i32)
-    leaf_out_ref[...] = leaf_new
-
-    in_bag = mask_ref[...] > 0                             # [chunk]
-    small_right = small == new_ids                         # [W]
-    if count_proxy:
-        # exact per-slot moved-row counts (f32 0/1 sums are integer-
-        # valued -> order-free exact, atomics or not)
-        s = jnp.sum((moved & in_bag[None, :]).astype(jnp.float32),
-                    axis=1)                                # [W]
-        plgpu.atomic_add(cnt_ref,
-                      (jax.lax.broadcasted_iota(i32, (W,), 0),), s)
-
-    # ---- per-row atomic histogram scatter ----
-    offs = jax.lax.broadcasted_iota(i32, (F,), 0) * B      # [F]
-
-    def body(r, carry):
-        memb = (eq[:, r] & (moved[:, r] == small_right)
-                & (small >= 0) & in_bag[r])
-        fnd = jnp.any(memb)
-        slot = jnp.where(fnd, jnp.argmax(memb).astype(i32), W)
-        flat = slot * (F * B) + offs + _gpu_unpack_row(
-            bins_ref, r, F, packed4)
-        if int8:
-            plgpu.atomic_add(hist_ref, (flat, 0),
-                          jnp.full((F,), g_ref[r].astype(i32)))
-            plgpu.atomic_add(hist_ref, (flat, 1),
-                          jnp.full((F,), h_ref[r].astype(i32)))
-            if not count_proxy:
-                plgpu.atomic_add(hist_ref, (flat, 2),
-                              jnp.full((F,), jnp.int32(1)))
-        else:
-            plgpu.atomic_add(hist_ref, (flat, 0),
-                          jnp.full((F,), g_ref[r]))
-            plgpu.atomic_add(hist_ref, (flat, 1),
-                          jnp.full((F,), h_ref[r]))
-            plgpu.atomic_add(hist_ref, (flat, 2),
-                          jnp.full((F,), jnp.float32(1.0)))
-        return carry
-
-    jax.lax.fori_loop(0, chunk, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("num_bins", "chunk",
-                                             "interpret", "precision",
-                                             "any_cat", "count_proxy",
-                                             "packed4", "num_features",
-                                             "dequant", "variant"))
-def fused_partition_histogram_pallas_gpu(bins_t, g, h, sample_mask,
-                                         leaf_ids, tbl, *, num_bins,
-                                         chunk=0, interpret=False,
-                                         precision="highest",
-                                         gh_scale=None, any_cat=True,
-                                         count_proxy=False,
-                                         packed4=False,
-                                         num_features=None,
-                                         dequant=True,
-                                         variant="hilo5"):
-    """Pallas-Triton twin of fused_partition_histogram_pallas: same
-    contract, and in interpret mode the same BITS as
-    fused_partition_histogram_xla. Returns (new_leaf_ids [N],
-    hist [W, F, B, 3]) — with ``count_proxy``, (new_leaf_ids,
-    hist [W, F, B, 2], cnt_right [W]).
-
-    No wave-width caps: the atomic scatter has no 128-lane budget, so
-    every ``variant`` lowers to the same kernel (accepted for
-    interface parity). The partition math is the exact integer/compare
-    sequence of the TPU kernel and the XLA oracle — bit-identical by
-    construction; the histogram's bit-equality argument is the
-    row-order one in the section comment.
-    """
-    del variant                        # layout-free on GPU
-    F, n = bins_t.shape
-    if packed4:
-        if num_bins > 16:
-            raise NotImplementedError("packed4 needs max_bin <= 16")
-        F = int(num_features)
-    W = int(tbl.shape[1])
-    B = num_bins
-    int8 = precision == "int8"
-    if count_proxy and not int8:
-        raise NotImplementedError("count_proxy requires precision='int8'")
-    chunk = chunk or autotune.DEFAULT_GPU_HIST_CHUNK
-    if int8 and 127 * (n + (-n) % chunk) >= 2 ** 31:
-        raise NotImplementedError(
-            "int8 histogram sums could overflow int32 beyond ~16.9M "
-            "rows; disable tpu_quantized_hist")
-
-    pad = (-n) % chunk
-    if pad:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad)))
-        g = jnp.pad(g, (0, pad))
-        h = jnp.pad(h, (0, pad))
-        sample_mask = jnp.pad(sample_mask, (0, pad))
-        leaf_ids = jnp.pad(leaf_ids, (0, pad), constant_values=-1)
-    n_pad = n + pad
-
-    C = 2 if count_proxy else 3
-    acc_dt = jnp.int32 if int8 else jnp.float32
-    size = (W + 1) * F * B                       # + the dump slot
-    hist0 = jnp.zeros((size, C), acc_dt)
-    # count accumulator (1-element stub when unused: pallas wants a
-    # static operand list, and the kernel never touches the stub)
-    cnt0 = jnp.zeros((W if count_proxy else 1,), jnp.float32)
-    F_rows = bins_t.shape[0]
-    tbl18 = tbl.astype(jnp.int32)                # [18, W], natural
-
-    kernel = functools.partial(
-        _gpu_fused_kernel, F=F, B=B, W=W, chunk=chunk, int8=int8,
-        any_cat=any_cat, count_proxy=count_proxy, packed4=packed4)
-
-    outs = pl.pallas_call(
-        kernel,
-        grid=(n_pad // chunk,),
-        in_specs=[
-            pl.BlockSpec(tbl18.shape, lambda i: (0, 0)),
-            pl.BlockSpec((F_rows, chunk), lambda i: (0, i)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((size, C), lambda i: (0, 0)),
-            pl.BlockSpec(cnt0.shape, lambda i: (0,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((size, C), lambda i: (0, 0)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec(cnt0.shape, lambda i: (0,)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((size, C), acc_dt),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct(cnt0.shape, jnp.float32),
-        ),
-        input_output_aliases={6: 0, 7: 2},
-        compiler_params=(None if interpret
-                         else autotune.gpu_compiler_params()),
-        name="fused_partition_histogram_pallas_gpu",
-        interpret=interpret,
-    )(tbl18, bins_t, g.astype(jnp.float32), h.astype(jnp.float32),
-      sample_mask.astype(jnp.float32), leaf_ids.astype(jnp.int32),
-      hist0, cnt0)
-    hist, leaf_out, cnt = outs
-
-    hist = hist[:W * F * B].reshape(W, F, B, C)
-    if count_proxy:
-        if dequant:
-            hist = hist.astype(jnp.float32) * jnp.stack(
-                [jnp.float32(gh_scale[0]), jnp.float32(gh_scale[1])])
-        return leaf_out[:n], hist, cnt[:W]
-    if int8:
-        if dequant:
-            hist = hist.astype(jnp.float32) * _qscale_vec(gh_scale)
-        return leaf_out[:n], hist
-    if gh_scale is not None and dequant:
-        hist = hist * _qscale_vec(gh_scale)
-    return leaf_out[:n], hist

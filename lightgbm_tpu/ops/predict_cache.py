@@ -14,9 +14,7 @@ program") and fragile (any odd request batch size minted a fresh
 trace).
 
 Here the dispatch becomes a pure function of an explicit, hashable
-**geometry key** — path kind (XLA scan / fused Pallas forest, with
-the Pallas-Triton forest dispatching under its own "pallas-gpu" kind
-so CPU-interpret and GPU-native programs never alias), the
+**geometry key** — path kind (XLA scan / fused Pallas forest), the
 32-bucketed per-feature table offsets (their sum is Wtot), padded
 split/leaf axes, class count, tree-chunk and step counts, the row
 bucket, the device kind — held in a bounded process-wide LRU:

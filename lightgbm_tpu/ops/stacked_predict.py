@@ -593,28 +593,20 @@ class StackedModel:
         if rows is None:
             rows = self._bin_rows(X)
         N = X.shape[0]
-        from ..utils.device import backend_kind
-        bk = backend_kind()
-        # route by device kind: TPU always takes the fused kernel, GPU
-        # takes its Triton twin when the lowering is importable, CPU
-        # runs the XLA scan (use_pallas forces the kernel either way —
-        # off-accelerator it runs in interpret mode, which is how the
-        # tier-1 parity suite drives it)
-        if use_pallas is not None:
-            forest = bool(use_pallas)
-        else:
-            forest = (bk == "tpu" or (bk == "gpu"
-                                      and autotune.gpu_pallas_supported()))
-        gpu_route = (forest and bk == "gpu"
-                     and autotune.gpu_pallas_supported())
+        from ..utils.device import on_tpu
+        tpu = on_tpu()
+        # route by device kind: the TPU takes the fused kernel, every
+        # other platform runs the XLA scan (use_pallas forces the
+        # kernel either way — off the TPU it runs in interpret mode,
+        # which is how the tier-1 parity suite drives it)
+        forest = tpu if use_pallas is None else bool(use_pallas)
         # VMEM guard from the kernel's ACTUAL block bytes (W, P, C,
         # one-hot all scale with TC x padded S/L, not just Wtot):
         # _pallas_tc halves the tree chunk until the blocks fit and
         # returns None for models that cannot fit at all — those use
         # the XLA scan path instead of crashing the fused kernel.
         tc = self._pallas_tc() if forest else None
-        row_tile = (autotune.DEFAULT_GPU_ROW_TILE if gpu_route
-                    else autotune.DEFAULT_ROW_TILE)
+        row_tile = autotune.DEFAULT_ROW_TILE
         if forest and tc is None:
             # the default row tile can miss the VMEM budget where a
             # smaller one fits (row_tile-scaled blocks dominating at
@@ -631,7 +623,7 @@ class StackedModel:
             # fused kernel must be able to see (and a smoke run to
             # assert) that it did not get it
             obs.counter("predict/forest_surrenders").add(1)
-            (log.warning if bk == "tpu" else log.info)(
+            (log.warning if tpu else log.info)(
                 "fused forest kernel does not fit VMEM at any tile "
                 "(Wtot=%d, S=%d, L=%d): predicting through the XLA "
                 "scan instead", self._Wtot, self._S, self._L)
@@ -646,10 +638,9 @@ class StackedModel:
             # compute instead of serializing after the math. f32 on
             # the wire (f64 only at this API boundary,
             # predictor.hpp-style) halves the download.
-            interp = not (bk == "tpu" or gpu_route)
+            interp = not tpu
             row_tile, tc = self._tuned_tiles(first, ntree, row_tile,
-                                             tc, interp,
-                                             gpu_route=gpu_route)
+                                             tc, interp)
             dev = self._device_arrays_pallas(first, ntree, tc)
             fchunk = 1 << 18
             # online batches pad to a pow2 serve bucket so request
@@ -664,8 +655,7 @@ class StackedModel:
             # serve-bucket answer for huge batches (obs/reqlog.py)
             reqlog.note_bucket(chunk)
             _, TCr, Sp, Lp = dev[1].shape
-            key = ("pallas-gpu" if gpu_route else "pallas", device,
-                   offs, Sp, Lp, self.num_class,
+            key = ("pallas", device, offs, Sp, Lp, self.num_class,
                    TCr, dev[0].shape[0], row_tile, dev_bin, m_max,
                    chunk, interp)
 
@@ -675,20 +665,14 @@ class StackedModel:
             # the warm program on ITS arrays
             def build():
                 if dev_bin:
-                    fx = (forest_predict_from_x_gpu if gpu_route
-                          else forest_predict_from_x)
-
                     def run(part, dv, aux):
-                        return fx(
+                        return forest_predict_from_x(
                             jnp.asarray(part), *aux, *dv,
                             offsets=offs, row_tile=row_tile,
                             interpret=interp)
                 else:
-                    fp = (forest_predict_pallas_gpu if gpu_route
-                          else forest_predict_pallas)
-
                     def run(part, dv, aux):
-                        return fp(
+                        return forest_predict_pallas(
                             jnp.asarray(part), *dv, offsets=offs,
                             row_tile=row_tile, interpret=interp)
                 return run
@@ -769,8 +753,7 @@ class StackedModel:
                                  ntree, Sp, Lp, np.int32, tc)
 
     def _tuned_tiles(self, first: int, ntree: int, rt_default: int,
-                     tc_default: int, interp: bool,
-                     gpu_route: bool = False):
+                     tc_default: int, interp: bool):
         """(row_tile, tc) for the fused forest kernel — autotuned on
         first encounter of this model-shape key (ops/autotune.py),
         cached on disk thereafter. The key is the kernel's SHAPE — the
@@ -787,15 +770,8 @@ class StackedModel:
         t = autotune.tuner()
         if interp or t.mode == "off":
             return rt_default, tc_default
-        if gpu_route:
-            # per-CTA row tiles: far smaller than the TPU grid tiles —
-            # the register-resident accumulator and the F gather rows
-            # scale with the tile, not a VMEM double buffer
-            tiles = ((256, 512, 1024, 2048)
-                     if t.mode == "exhaustive" else (512, 1024, 2048))
-        else:
-            tiles = ((512, 1024, 2048, 4096, 8192)
-                     if t.mode == "exhaustive" else (1024, 2048, 4096))
+        tiles = ((512, 1024, 2048, 4096, 8192)
+                 if t.mode == "exhaustive" else (1024, 2048, 4096))
         cands = []
         for rt in tiles:
             tc = self._pallas_tc(rt)
@@ -824,10 +800,8 @@ class StackedModel:
 
         def measure(cand):
             dev = self._device_arrays_pallas(first, ntree, cand["tc"])
-            fp = (forest_predict_pallas_gpu if gpu_route
-                  else forest_predict_pallas)
             return timing.measure(
-                lambda: fp(
+                lambda: forest_predict_pallas(
                     codes, *dev, offsets=offs,
                     row_tile=cand["row_tile"], interpret=False))
 
@@ -1105,123 +1079,3 @@ def forest_predict_pallas(codes_t, W, P, tgt, leaf, cls, *, offsets,
         interpret=interpret,
     )(codes_t, W, P, tgt, leaf, cls)
     return acc[:N]
-
-
-# --- fused forest kernel, Pallas GPU (Triton) ------------------------------
-#
-# Same math as _forest_kernel, re-shaped for a CTA grid. Differences
-# forced by the Triton lowering:
-#
-#   * grid is (row blocks,) ONLY — the steps axis moves into an
-#     in-kernel fori_loop so the score accumulator lives in registers
-#     instead of a revisited output block (Triton has no sequential
-#     multi-visit output-block contract to lean on).
-#   * the one-hot [Wtot, nt] tile + MXU dot is replaced by F row
-#     gathers of the step's W table: C[n, :] = sum_f W[code_f(n), :].
-#     Addition of {-1, 0, 1} int8 rows in feature order gives the
-#     identical integer C the one-hot contraction produces.
-#   * step-indexed stacks are pre-flattened ([steps*Wtot, TC*Sp] etc.)
-#     so every in-loop access is either a traced-scalar row or a
-#     traced-vector gather — both lower on Triton and interpret alike.
-#
-# Bit-equality vs forest_predict_pallas(interpret=True) at the same
-# row_tile: C and E are exact small integers under any association,
-# the match/leaf reduction has at most one nonzero per (row, tree),
-# and the only order-sensitive f32 sums — the HIGHEST-precision class
-# dot and the step accumulator — run over identical shapes in
-# identical step order. tests/test_gpu_tier.py pins this bitwise.
-
-def _gpu_forest_kernel(codes_ref, W_ref, P_ref, tgt_ref, leaf_ref,
-                       cls_ref, acc_ref, *, F, Wtot, TC, Sp, Lp, K,
-                       steps, nt):
-    i32 = jnp.int32
-    codes = codes_ref[...].astype(i32)                   # [F, nt]
-
-    def step_body(s, acc):
-        base = s * Wtot
-        # node decisions via F row gathers (codes carry the global
-        # feature offset already, so they index W's node axis directly)
-        C = jnp.zeros((nt, TC * Sp), i32)
-        for f in range(F):
-            C = C + W_ref[base + codes[f, :], :].astype(i32)
-        C8 = C.astype(jnp.int8)                          # values {0,1}
-        vals = []
-        for t in range(TC):
-            j = s * TC + t
-            Ct = C8[:, t * Sp:(t + 1) * Sp]
-            E = jax.lax.dot_general(
-                Ct, P_ref[j], (((1,), (0,)), ((), ())),
-                preferred_element_type=i32)              # [nt, Lp]
-            match = (E == tgt_ref[j][None, :]).astype(jnp.float32)
-            vals.append(jnp.sum(match * leaf_ref[j][None, :],
-                                axis=1, keepdims=True))  # [nt, 1]
-        val = jnp.concatenate(vals, axis=1)              # [nt, TC]
-        contrib = jax.lax.dot_general(
-            val, cls_ref[s], (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)          # [nt, K]
-        return acc + contrib
-
-    acc_ref[...] = jax.lax.fori_loop(
-        0, steps, step_body, jnp.zeros((nt, K), jnp.float32))
-
-
-@functools.partial(jax.jit, static_argnames=("offsets", "row_tile",
-                                             "interpret"))
-def forest_predict_pallas_gpu(codes_t, W, P, tgt, leaf, cls, *,
-                              offsets,
-                              row_tile=autotune.DEFAULT_GPU_ROW_TILE,
-                              interpret=False):
-    """codes_t [F, N] int32 -> scores [N, K] f32 on the GPU backend.
-
-    Accepts the SAME device stacks as forest_predict_pallas (one
-    _device_arrays_pallas build serves both kernels); the step axis is
-    flattened here so the in-kernel loop indexes with plain scalars."""
-    del offsets   # codes are globally offset; kept for call symmetry
-    F, N = codes_t.shape
-    steps, Wtot, TCSp = W.shape
-    _, TC, Sp, Lp = P.shape
-    K = cls.shape[-1]
-    pad = (-N) % row_tile
-    if pad:
-        # padded rows get code 0 -> garbage scores, sliced off below
-        codes_t = jnp.pad(codes_t, ((0, 0), (0, pad)))
-    n_pad = N + pad
-    kernel = functools.partial(
-        _gpu_forest_kernel, F=F, Wtot=Wtot, TC=TC, Sp=Sp, Lp=Lp, K=K,
-        steps=steps, nt=row_tile)
-    acc = pl.pallas_call(
-        kernel,
-        grid=(n_pad // row_tile,),
-        in_specs=[
-            pl.BlockSpec((F, row_tile), lambda r: (0, r)),
-            pl.BlockSpec((steps * Wtot, TCSp), lambda r: (0, 0)),
-            pl.BlockSpec((steps * TC, Sp, Lp), lambda r: (0, 0, 0)),
-            pl.BlockSpec((steps * TC, Lp), lambda r: (0, 0)),
-            pl.BlockSpec((steps * TC, Lp), lambda r: (0, 0)),
-            pl.BlockSpec((steps, TC, K), lambda r: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, K), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, K), jnp.float32),
-        compiler_params=(None if interpret
-                         else autotune.gpu_compiler_params()),
-        name="forest_predict_pallas_gpu",
-        interpret=interpret,
-    )(codes_t,
-      W.reshape(steps * Wtot, TCSp),
-      P.reshape(steps * TC, Sp, Lp),
-      tgt.reshape(steps * TC, Lp),
-      leaf.reshape(steps * TC, Lp),
-      cls)
-    return acc[:N]
-
-
-def forest_predict_from_x_gpu(x, E, off32, nan_slot, W, P, tgt, leaf,
-                              cls, *, offsets,
-                              row_tile=autotune.DEFAULT_GPU_ROW_TILE,
-                              interpret=False):
-    """Device binning + GPU forest kernel in ONE dispatch."""
-    codes_t = _codes_from_x(x, E, off32, nan_slot)
-    return forest_predict_pallas_gpu(codes_t, W, P, tgt, leaf, cls,
-                                     offsets=offsets, row_tile=row_tile,
-                                     interpret=interpret)

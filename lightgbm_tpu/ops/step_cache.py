@@ -36,7 +36,7 @@ What had to move out of the per-instance closures to get there:
 The registry key covers everything that shapes the trace (learner
 mode, mesh device ids, WaveGrowerConfig incl. split hyperparameters,
 forced splits and the resolved histogram ``route`` — pallas-tpu /
-pallas-gpu / fused-xla / two-pass, so the same geometry on a different
+fused-xla / two-pass, so the same geometry on a different
 backend compiles its own program and a checkpoint restored onto
 another device kind re-resolves and re-keys instead of replaying a
 foreign kernel choice — valid-set slice layout, bins dtype/shape,

@@ -40,7 +40,8 @@ import numpy as np
 from ..obs import registry as obs
 from .autotune import DEFAULT_HIST_CHUNK
 from .grower import TreeRecord
-from .hist_wave import (fused_partition_histogram_pallas, wave_histogram)
+from .hist_wave import (fused_partition_histogram_pallas, wave_histogram,
+                        wave_histogram_pallas)
 from .partition import member_column, row_goes_right
 from .split import (FeatureMeta, SplitParams, SplitResult, KMIN_SCORE,
                     calculate_leaf_output, find_best_split)
@@ -126,7 +127,7 @@ class WaveGrowerConfig(NamedTuple):
     # injected seams.
     sparse_hist: bool = False
     # resolved histogram route (ops/autotune.py tune_hist_route):
-    # "pallas-tpu" | "pallas-gpu" | "fused-xla" | "two-pass"; "" = auto
+    # "pallas-tpu" | "fused-xla" | "two-pass"; "" = auto
     # by backend. models/gbdt.py stamps the resolved value here so the
     # step-cache geometry key separates per-backend programs — a
     # checkpoint restored onto a different device kind re-resolves and
@@ -355,8 +356,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     route = cfg.route or autotune.tune_hist_route(
         use_pallas=cfg.use_pallas,
         fused_eligible=cfg.fused is not False)
-    gpu_hist = route == "pallas-gpu"
-    pallas_hist = route in ("pallas-tpu", "pallas-gpu")
+    pallas_hist = route == "pallas-tpu"
     use_fused = cfg.fused
     if use_fused is None:
         from .hist_wave import (FUSED_MAX_WAVE, FUSED_MAX_WAVE_HILO,
@@ -371,35 +371,27 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                            "hilo3": FUSED_MAX_WAVE_HILO3}[
                                cfg.exact_variant]
                      if cfg.precision == "highest" else FUSED_MAX_WAVE)
-        # the GPU fused kernel accumulates by atomics into global
-        # memory — no lane budget, so no wave-width cap applies there
-        use_fused = (default_seams and (gpu_hist or W <= fused_cap)
+        use_fused = (default_seams and W <= fused_cap
                      and not bundled and not cfg.sparse_hist
                      and pallas_hist)
     if use_fused:
-        from ..utils.device import backend_kind, on_tpu
-        # interpret mode runs the kernel off its native accelerator
-        # (the tier-1 parity suite drives both kernel families on CPU)
-        fused_interpret = (backend_kind() != "gpu" if gpu_hist
-                           else not on_tpu())
-        from .hist_wave import fused_partition_histogram_pallas_gpu
-        fused_kernel_fn = (fused_partition_histogram_pallas_gpu
-                           if gpu_hist
-                           else fused_partition_histogram_pallas)
-        fused_chunk = cfg.chunk or (autotune.DEFAULT_GPU_HIST_CHUNK
-                                    if gpu_hist else DEFAULT_HIST_CHUNK)
+        from ..utils.device import on_tpu
+        # interpret mode runs the kernel off the chip (the tier-1
+        # parity suite drives it on the CPU)
+        fused_interpret = not on_tpu()
+        fused_chunk = cfg.chunk or DEFAULT_HIST_CHUNK
     # off-TPU twin of the fused kernel (ops/hist_wave.py
     # fused_partition_histogram_xla): partition + smaller-child
     # histogram in one traced region, reusing the leaf-membership
     # compares between the two and riding ONE combined scatter —
     # bit-identical to [partition_fn -> hist_fn], so it is the default
     # off-TPU route wherever the Pallas fused kernel would be the
-    # on-TPU one. cfg.fused=False opts out (the legacy two-pass
-    # pipeline, kept as the parity oracle).
+    # on-TPU one. cfg.fused=False or a pinned route="two-pass" opts out
+    # (the legacy two-pass pipeline, kept as the parity oracle).
     use_fused_xla = (not use_fused and cfg.fused is not False
                      and default_seams and not bundled
                      and not cfg.sparse_hist
-                     and not pallas_hist)
+                     and route == "fused-xla")
     if use_fused_xla:
         from .hist_wave import fused_partition_histogram_xla
 
@@ -635,16 +627,9 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 # matching tier — no partition logic to pay for on an
                 # unsplit tree, and (packed4) the default hist_fn never
                 # sees the packed byte rows the fused path keeps in HBM
-                from .hist_wave import (wave_histogram_pallas,
-                                        wave_histogram_pallas_gpu)
-                wave_kernel = (wave_histogram_pallas_gpu if gpu_hist
-                               else wave_histogram_pallas)
-                root_chunk = cfg.chunk or (
-                    autotune.DEFAULT_GPU_HIST_CHUNK if gpu_hist
-                    else DEFAULT_HIST_CHUNK)
-                local_root = wave_kernel(
+                local_root = wave_histogram_pallas(
                     bins_t, hg, hh, bag_mask_ids(leaf0), root_wl,
-                    num_bins=B, chunk=root_chunk,
+                    num_bins=B, chunk=fused_chunk,
                     interpret=fused_interpret, precision=cfg.precision,
                     gh_scale=gh_scale, count_proxy=proxy,
                     packed4=cfg.packed4,
@@ -797,7 +782,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                         meta.default_bin[safe_feat],
                         meta.num_bin[safe_feat], small_ids,
                         iscat.astype(jnp.int32)]), catw.T])      # [18, W]
-                    fused_out = fused_kernel_fn(
+                    fused_out = fused_partition_histogram_pallas(
                         bins_t, hg, hh, sample_mask,
                         state.leaf_ids, tbl, num_bins=B,
                         chunk=fused_chunk,
@@ -811,8 +796,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     hist_small = dq(hist_reduce_fn(hist_small))
                     if proxy:
                         cnt_r = reduce_fn(fused_out[2])
-                    if not gpu_hist:
-                        wave_work = reduce_fn(fused_out[-1])   # all shards'
+                    wave_work = reduce_fn(fused_out[-1])   # all shards'
                     # out-of-bag rows partition too; their g/h are pre-masked
                     # and the count channel rides on sample_mask
                 elif use_fused_xla:
@@ -1101,8 +1085,8 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         return rec, state.leaf_ids
 
     # jit-capture: ok(B, hp, cfg, quant, use_fused, use_fused_xla,
-    # fused_chunk, fused_interpret, gpu_hist, fused_kernel_fn,
-    # fused_partition_histogram_xla, meta_const,
+    # fused_chunk, fused_interpret, fused_partition_histogram_xla,
+    # meta_const,
     # bound_counts, depth_ok, hist_fn, hist_reduce_fn, reduce_fn,
     # max_reduce_fn, row_offset_fn, split_fn, partition_fn) —
     # factory-scoped jit: every capture derives from this factory

@@ -61,20 +61,3 @@ def on_tpu() -> bool:
     gates Pallas kernel dispatch (Pallas TPU kernels can't lower for the
     CPU backend). Honors the device_type routing like get_devices()."""
     return get_devices()[0].platform == "tpu"
-
-
-def on_gpu() -> bool:
-    """True when framework computation runs on a GPU device — gates the
-    Pallas-Triton kernel dispatch (ops/hist_wave.py /
-    ops/stacked_predict.py GPU tiers); jax reports both CUDA and ROCm
-    as platform "gpu"."""
-    return get_devices()[0].platform == "gpu"
-
-
-def backend_kind() -> str:
-    """The routing backend of the selected platform: "tpu", "gpu" or
-    "cpu". ONE three-way seam for every kernel-route decision (tier
-    selection, compile-cache policy, autotuner arms) instead of
-    scattered on_tpu()/on_gpu() pairs that can disagree."""
-    p = get_devices()[0].platform
-    return p if p in ("tpu", "gpu") else "cpu"

@@ -68,6 +68,25 @@ def fit_gbdt(X, y, params, num_round=30, weight=None, group=None,
     return g
 
 
+def adopt_benchmark_tests(stem, into):
+    """Run ``benchmark/tests/<stem>.py`` in tier-1: import that file by
+    its path and put its tests and fixtures into ``into`` (the calling
+    test module's ``globals()``), so pytest collects each case once,
+    here, under this conftest. The benchmark's files are the yardstick
+    and stay where they are, unedited; a program change that breaks what
+    they read fails in this suite before any chip time is spent."""
+    import importlib.util
+    from pathlib import Path
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "tests"
+            / f"{stem}.py")
+    spec = importlib.util.spec_from_file_location(f"benchmark_{stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    into.update({k: v for k, v in vars(mod).items()
+                 if k.startswith("test_")
+                 or hasattr(v, "_fixture_function_marker")})
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _step_cache_suite_guard():
     """Regression guard for the compiled-step registry

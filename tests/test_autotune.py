@@ -129,7 +129,7 @@ class TestTuningCache:
         the routing seam the tuner itself asks — no option exists for
         it."""
         from lightgbm_tpu.utils import device, log
-        monkeypatch.setattr(device, "backend_kind", lambda: backend)
+        monkeypatch.setattr(device, "on_tpu", lambda: backend == "tpu")
         lines = []
         log.set_callback(lines.append)
         try:
@@ -160,7 +160,7 @@ class TestTuningCache:
         """The same rule through the seam the trainer calls
         (tune_hist_chunk with an injected ``_measure``)."""
         from lightgbm_tpu.utils import device, log
-        monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+        monkeypatch.setattr(device, "on_tpu", lambda: True)
         monkeypatch.setattr(autotune, "device_kind",
                             lambda: "TPU v5 lite")
         autotune.configure("on", str(tmp_path / "t.json"))
@@ -280,6 +280,55 @@ class TestDefaultsOffTpu:
             assert autotune.tune_hist_chunk(
                 fused=True, F=28, B=64, W=64, precision="int8",
                 count_proxy=True) == autotune.DEFAULT_HIST_CHUNK_INT8
+            assert not (tmp_path / "t.json").exists()
+        finally:
+            autotune.configure("on", None)
+
+    @staticmethod
+    def _on_platform(monkeypatch, name):
+        """Steer the one question every route decision asks
+        (device.on_tpu reads get_devices()[0].platform) to a platform
+        this host does not have."""
+        from types import SimpleNamespace
+
+        from lightgbm_tpu.utils import device
+        monkeypatch.setattr(
+            device, "get_devices",
+            lambda: [SimpleNamespace(platform=name, device_kind=name)])
+
+    def test_tune_hist_route_capability_ladder(self, monkeypatch):
+        """Two questions: Mosaic or not, fused-eligible or not. Any
+        platform but the TPU — a jax GPU included — takes the plain XLA
+        routes."""
+        assert autotune.HIST_ROUTES == ("pallas-tpu", "fused-xla",
+                                        "two-pass")
+        self._on_platform(monkeypatch, "tpu")
+        assert autotune.tune_hist_route() == "pallas-tpu"
+        assert autotune.tune_hist_route(
+            fused_eligible=False) == "pallas-tpu"
+        for other in ("cpu", "gpu"):
+            self._on_platform(monkeypatch, other)
+            assert autotune.tune_hist_route() == "fused-xla"
+            assert autotune.tune_hist_route(
+                fused_eligible=False) == "two-pass"
+        # the config override beats the device, both directions
+        assert autotune.tune_hist_route(use_pallas=True) == "pallas-tpu"
+        self._on_platform(monkeypatch, "tpu")
+        assert autotune.tune_hist_route(use_pallas=False) == "fused-xla"
+        assert autotune.tune_hist_route(
+            use_pallas=False, fused_eligible=False) == "two-pass"
+
+    @pytest.mark.parametrize("platform", ["cpu", "gpu"])
+    def test_cpu_backend_without_timer_keeps_default(
+            self, tmp_path, monkeypatch, platform):
+        """Off the TPU — on whatever platform jax has — the wave arm
+        times nothing and writes nothing without an injected timer."""
+        self._on_platform(monkeypatch, platform)
+        autotune.configure("on", str(tmp_path / "t.json"))
+        try:
+            assert autotune.tune_hist_chunk(
+                fused=False, F=8, B=64, W=8) == \
+                autotune.DEFAULT_HIST_CHUNK
             assert not (tmp_path / "t.json").exists()
         finally:
             autotune.configure("on", None)
@@ -532,7 +581,7 @@ def test_compile_cache_operator_placement_wins(cache_rule, monkeypatch,
     sits inside it."""
     from lightgbm_tpu.utils import device
     at, install = cache_rule
-    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     placed = str(tmp_path / "operator_cache")
     cfg = install(placed)
     at.ensure_compile_cache()
@@ -550,7 +599,7 @@ def test_compile_cache_unset_goes_to_the_fixed_checkout_path(
 
     from lightgbm_tpu.utils import device
     at, install = cache_rule
-    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     cfg = install()
     at.ensure_compile_cache()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

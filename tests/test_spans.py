@@ -355,14 +355,11 @@ def _pallas_calls(path):
 @pytest.mark.parametrize("module,kernel", [
     ("hist_wave.py", "wave_histogram_pallas"),
     ("hist_wave.py", "fused_partition_histogram_pallas"),
-    ("hist_wave.py", "wave_histogram_pallas_gpu"),
-    ("hist_wave.py", "fused_partition_histogram_pallas_gpu"),
     ("predict.py", "leaf_gather_pallas"),
     ("stacked_predict.py", "forest_predict_pallas"),
-    ("stacked_predict.py", "forest_predict_pallas_gpu"),
 ])
 def test_every_pallas_call_is_named_after_its_entry_point(module, kernel):
-    """Seven ``pallas_call``s in ops/, each with ``name=`` its jitted entry
+    """Four ``pallas_call``s in ops/, each with ``name=`` its jitted entry
     point's own name: whichever of the two the compiler shows in a trace,
     the name is the same and stays put."""
     calls = dict(_pallas_calls(OPS / module))
@@ -370,7 +367,7 @@ def test_every_pallas_call_is_named_after_its_entry_point(module, kernel):
     all_calls = [c for m in ("hist_wave.py", "predict.py",
                              "stacked_predict.py")
                  for c in _pallas_calls(OPS / m)]
-    assert len(all_calls) == 7 and all(n for _, n in all_calls)
+    assert len(all_calls) == 4 and all(n for _, n in all_calls)
 
 
 def _traced_kernel_names(fn, *args):

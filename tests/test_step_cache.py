@@ -19,7 +19,9 @@ import pytest
 
 from conftest import (TEST_PARAMS, fit_gbdt, make_binary,
                       make_multiclass, make_regression)
-from lightgbm_tpu.ops import step_cache
+from lightgbm_tpu.ops import autotune, step_cache
+from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+from lightgbm_tpu.ops.wave_grower import WaveGrowerConfig, make_wave_grower
 
 pytestmark = pytest.mark.stepcache
 
@@ -364,3 +366,48 @@ def test_geometry_bucketing_shares_across_data_shapes():
     _, d2 = stats_delta(lambda: fit_gbdt(X2, y2, params, num_round=5))
     assert d2["misses"] == 0, "same-bucket shapes must share the step"
     assert d2["hits"] >= 1
+
+
+def test_route_field_separates_config_identity():
+    """The resolved route is part of WaveGrowerConfig, and so of the
+    step's geometry key."""
+    kw = dict(num_leaves=15, num_bins=63, wave_size=8, hp=SplitParams())
+    cfgs = [WaveGrowerConfig(**kw, route=r) for r in autotune.HIST_ROUTES]
+    assert len(set(cfgs)) == len({hash(c) for c in cfgs}) == 3
+
+
+@pytest.mark.parametrize("backend", ["gpu", "rocm"])
+def test_bogus_route_rejected(backend):
+    """A Pallas route of any backend but the TPU is an unknown name."""
+    meta = FeatureMeta(
+        num_bin=np.full(4, 63, np.int32),
+        missing_type=np.zeros(4, np.int32),
+        default_bin=np.zeros(4, np.int32),
+        monotone=np.zeros(4, np.int32),
+        penalty=np.ones(4, np.float32))
+    cfg = WaveGrowerConfig(num_leaves=15, num_bins=63, wave_size=8,
+                           hp=SplitParams(), route=f"pallas-{backend}")
+    with pytest.raises(ValueError, match="route"):
+        make_wave_grower(cfg, meta)
+
+
+def test_pinned_routes_train_bit_identical_and_key_apart(monkeypatch):
+    """A pinned two-pass run trains the trees of the fused-XLA route
+    bit for bit, compiles a step of its own (the route rides the
+    geometry key), and a same-geometry retrain under the same pin is a
+    pure registry hit."""
+    X, y = make_binary(640, seed=21)
+    params = dict(TEST_PARAMS, objective="binary")
+    g_fused = fit_gbdt(X, y, params, num_round=4)   # this host's own route
+    rep = g_fused.device_report()
+    assert (rep["route"], rep["fused_xla"]) == ("fused-xla", True)
+    monkeypatch.setattr(autotune, "tune_hist_route",
+                        lambda **kw: "two-pass")
+    g_two1, d1 = stats_delta(lambda: fit_gbdt(X, y, params, num_round=4))
+    g_two2, d2 = stats_delta(lambda: fit_gbdt(X, y, params, num_round=4))
+    rep = g_two1.device_report()
+    assert (rep["route"], rep["fused_xla"]) == ("two-pass", False)
+    assert trees(g_two1) == trees(g_fused)
+    assert d1["misses"] >= 1, "the pinned route compiles its own step"
+    assert d2["misses"] == 0 and d2["hits"] >= 1
+    assert trees(g_two2) == trees(g_two1)

@@ -349,7 +349,6 @@ def _step_under_test(monkeypatch, tier, mesh=None):
     from lightgbm_tpu.parallel.learners import make_data_parallel_grower
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
-    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
 
     N, nvalid, F, B, L = 1 << 17, 1 << 14, 32, 64, 255
     if tier == "proxy":

@@ -174,7 +174,7 @@ def test_no_fitting_tile_is_an_error_not_a_default(tmp_path, monkeypatch):
     (its TPU arm) both refuse, in their own words, before Mosaic is
     asked; under the real budget the tuner times (chunk, tile) pairs."""
     from lightgbm_tpu.utils import device
-    monkeypatch.setattr(device, "backend_kind", lambda: "tpu")
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
     monkeypatch.setattr(autotune, "device_kind", lambda: "TPU v5 lite")
     autotune.configure("on", str(tmp_path / "t.json"))
     try:
