@@ -373,6 +373,9 @@ def phase_compare(tier_report: dict, sz: Sizes, seed: int,
                                      np.float32)
     _hist_close(wave, wave_x[..., :wave.shape[-1]], int8,
                 f"compare[{tier}]: wave (root) kernel histograms")
+    if not int8:
+        _root_kernel_checks(d, r, tier=tier, trained=variant, B=B,
+                            chunk=small_chunk, interp=interp)
 
     # one tree through the whole grower at the trained wave width:
     # Pallas route vs the two-pass XLA route. Depth is held to 4 on a
@@ -439,6 +442,55 @@ def phase_compare(tier_report: dict, sz: Sizes, seed: int,
     check(dv <= 1e-4, f"compare[{tier}]: leaf outputs within 1e-4 "
           f"(max |diff| {dv:.2e})")
     say(f"compare[{tier}] wall: {time.monotonic() - t0:.1f}s")
+
+
+def _root_kernel_checks(d, r, *, tier, trained, B, chunk, interp) -> None:
+    """The root pass's kernel of its own (a two-digit split of the bin
+    axis; the bf16 tiers) against the wave kernel's slot 0 with one live
+    leaf, bit for bit, on the device's own bf16 operands and
+    DEFAULT-precision dot (the CPU suite interprets both in f32): every
+    layout the grower's predicate sends there (hilo5, hilo4 whose counts
+    ride a fifth row where the wave kernel runs a second dot, hilo3,
+    plain bf16), at the smoke's 64 bins (16 high digits, eight features
+    a dot) and at 255 (32 high digits, four a dot), and at a chunk the
+    root kernel halves."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.hist_wave import (root_histogram_pallas,
+                                            wave_histogram_pallas)
+    F, N = d["bins"].shape
+    in_root = jnp.where(d["mask"] > 0, 0, -1)
+    one_slot = jnp.where(d["wl"] == 0, 0, -1)
+    wide = jnp.asarray(r.integers(0, 255, (F, N)).astype(np.uint8))
+    layouts = {"hilo5": dict(precision="highest", variant="hilo5"),
+               "hilo4": dict(precision="highest", variant="hilo4"),
+               "hilo3": dict(precision="highest", variant="hilo3"),
+               "bf16": dict(precision="default", variant=None)}
+
+    def pair(bins, nb, layout, root_chunk, wave_chunk):
+        # hilo3's gate: the hessian IS the bag mask
+        h = d["mask"] if layout == "hilo3" else d["h"]
+        kw = dict(num_bins=nb, interpret=interp, **layouts[layout])
+        root = np.asarray(root_histogram_pallas(
+            bins, d["g"], h, in_root, chunk=root_chunk, **kw))
+        slot0 = np.asarray(wave_histogram_pallas(
+            bins, d["g"], h, in_root, one_slot, chunk=wave_chunk,
+            **kw))[:1]
+        return root, slot0
+
+    for layout in layouts:
+        for bins, nb in ((d["bins"], B), (wide, 255)):
+            root, slot0 = pair(bins, nb, layout, chunk, chunk)
+            check(root.shape == (1, F, nb, 3)
+                  and np.array_equal(root, slot0),
+                  f"compare[{tier}]: root kernel [1, F={F}, B={nb}, 3] "
+                  f"({layout}{', trained' if layout == trained else ''}) "
+                  f"equals the wave kernel's slot 0 bit for bit")
+    # asked for 32768 rows a step, 5 channels x 256 bins walk 16384
+    # (autotune.root_hist_tiling): the wave kernel's sums AT 16384
+    root, slot0 = pair(wide, 255, "hilo5", 32768, 16384)
+    check(np.array_equal(root, slot0),
+          f"compare[{tier}]: root kernel asked 32768 rows a step equals "
+          f"the wave kernel's slot 0 at 16384 bit for bit")
 
 
 def _hist_close(got, ref, int8: bool, what: str) -> None:

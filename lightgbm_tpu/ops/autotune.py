@@ -190,6 +190,153 @@ def wave_hist_block_shapes(*, chunk: int, geom: Dict[str, int]
     }
 
 
+# The root pass's two-digit split of the bin axis (ops/hist_wave.py
+# root_histogram_pallas): bin b = hi * 2^ROOT_SPLIT_LO_BITS + lo, the
+# histogram of a feature the [H, nchan x L] product of its hi one-hot
+# and its lo-selected channel rows. L = 8 minimises the rows a feature
+# needs (H + nchan x L = 32 + 40 at 256 bins, against 64 + 20 at L = 4
+# and 16 + 80 at L = 16, which ran 1.84x / 1.47x slower than L = 8 at
+# 67 / 2,000 features: PERF.md section 5, PR 33, call A).
+ROOT_SPLIT_LO_BITS = 3
+# The root kernel serves the root from this many (padded) bins on: its
+# dot spends nchan x L x 128 = 5,120 MACs on a row of a feature whatever
+# the bin count, the wave kernel's Bp x 128. Measured on a v5e, the
+# kernels alone, hilo5, chunk 16384 (PERF.md section 5, PR 33, call A;
+# wave kernel with one live slot against root kernel, ms a pass): 256
+# bins 244.8 against 41.06 (67 features, 10,485,760 rows) and 279.9
+# against 46.90 (2,000 features, 393,216 rows): 6.0x; 128 bins 50.32
+# against 17.11 (67 x 4,194,304): 2.9x; 64 bins 20.33 against 15.51
+# (28 x 8,388,608): 1.31x. The lower side of the line is arithmetic,
+# not measurement: at 64 bins the MACs stand 8,192 against 5,120 and
+# the time 1.31x, so at 48 bins (6,144 against 5,120) there is nothing
+# left to buy, and nothing was timed there.
+ROOT_SPLIT_MIN_BINS = 64
+
+
+def root_split_applies(*, B: int, precision: str, count_proxy: bool = False,
+                       packed4: bool = False) -> bool:
+    """Whether a tree's root pass takes the root kernel of its own
+    (ops/hist_wave.py root_histogram_pallas) in place of the wave kernel
+    with one live slot: a trace-time choice from the shapes and the
+    tier. The bf16 tiers alone (the int8 tiers' operands are not built
+    from ops Mosaic lowers at int8: docs/Design.md section 9) and a bin
+    count at which the digit split buys MACs and its two digits hold
+    (byte bins: over 256 levels the bins are words)."""
+    return (precision in ("highest", "default") and not count_proxy
+            and not packed4
+            and ROOT_SPLIT_MIN_BINS <= _round_up(B, 8) <= 256)
+
+
+def root_pass_macs(*, B: int, nchan: int, split: bool) -> int:
+    """MACs the root pass's dot spends on one row of one feature: the
+    root kernel's ``nchan x L`` streamed rows against 128 lanes where
+    its digit split serves the root (``split``), the wave kernel's
+    ``Bp`` one-hot rows against 128 lanes where it does."""
+    return 128 * (nchan << ROOT_SPLIT_LO_BITS if split else _round_up(B, 8))
+
+
+def root_hist_geometry(*, F: int, B: int, nchan: int) -> Dict[str, int]:
+    """Geometry of the root kernel over ``F`` stored bin rows (a feature
+    tile's, or all): L = 2^ROOT_SPLIT_LO_BITS low digits, H high digits
+    (a packed bf16 sublane tile's multiple that divides 128), ``gf`` =
+    128 // H features' hi one-hots side by side as the 128-lane operand
+    of one dot, whose other operand streams their ``R`` = ``gf x nchan x
+    L`` selected channel rows."""
+    Bp = _round_up(B, 8)
+    L = 1 << ROOT_SPLIT_LO_BITS
+    if Bp > 32 * L:
+        raise NotImplementedError(
+            f"the root kernel's digit split holds {32 * L} bins, not {B}")
+    H = 16 if Bp <= 16 * L else 32
+    gf = 128 // H
+    return dict(Bp=Bp, L=L, H=H, gf=gf, nchan=nchan, R=gf * nchan * L,
+                groups=-(-F // gf), F=F)
+
+
+def root_hist_block_shapes(*, chunk: int, geom: Dict[str, int]
+                           ) -> Dict[str, tuple]:
+    """VMEM block shapes of root_histogram_pallas (its BlockSpecs and
+    its two i32 scratches, of the ``bins`` block's shape, are built
+    from THESE tuples)."""
+    return {
+        "bins": (geom["F"], chunk),                       # grid-indexed
+        "ghl": (4, chunk),                                # grid-indexed
+        "hist": (geom["groups"], geom["R"], 128),         # accumulator
+    }
+
+
+def root_hist_vmem_bytes(*, chunk: int, geom: Dict[str, int],
+                         bins_bytes: int = 1) -> int:
+    """Working-set bytes of one grid step of the root kernel, priced as
+    hist_vmem_bytes prices the wave kernels': double-buffered
+    grid-indexed blocks, the hi and lo digit scratches with the widened
+    block they are cut from, the accumulators, the channel rows
+    broadcast over the L sublanes, and one group's operands (the
+    selected rows in f32 and bf16, the 128 hi one-hot rows with their
+    compare) and matmul result."""
+    s = root_hist_block_shapes(chunk=chunk, geom=geom)
+    return (2 * _nelem(s["bins"]) * bins_bytes
+            + 2 * _nelem(s["ghl"]) * 4
+            + 3 * _nelem(s["bins"]) * 4
+            + _nelem(s["hist"]) * 4
+            + geom["nchan"] * geom["L"] * chunk * 4
+            + geom["R"] * chunk * 6
+            + 128 * chunk * 6
+            + geom["R"] * 128 * 4)
+
+
+def root_hist_tiling(*, F: int, B: int, nchan: int, chunk: int,
+                     bins_bytes: int = 1, force: Optional[int] = None):
+    """(chunk, geom, n_tiles) of a root kernel call, given the grower's
+    row chunk: the rows a grid step walks and the feature tile it holds,
+    both priced against the VMEM budget as hist_feature_tiling prices a
+    wave kernel's.
+
+    The chunk first. The grower's is chosen by what the wave and fused
+    kernels cost (hist_chunk_candidates prices those two), and the root
+    kernel's operands of one group (R + 128 rows of a whole chunk, in
+    f32 and bf16) do not shrink with the tile: at 32768, which the tuner
+    offers both cells' shapes, 5 channels x 256 bins price 78.3 MB
+    against the budget's 75.5 MB at the narrowest tile. So the kernel
+    walks the largest ``chunk / 2^k`` at which its narrowest tile fits
+    (16384 there: two steps where the waves walk one; its wrapper pads
+    the rows itself; at 4096 the kernel alone still read 45.8 ms against
+    the wave kernel's 244.8: PERF.md section 5, PR 33, call A8r).
+
+    Then the tile: one wherever the whole working set is inside the
+    budget, else the widest tile of whole packed uint8 sublane tiles
+    (HIST_TILE_ROW_ALIGN, a multiple of ``gf``) that is. The
+    accumulators are ``nchan x L x 128`` floats a feature where a wave
+    kernel's are ``Bp x 128``, so the bin block and its digit scratches
+    are what a tile is sized by. ``force``: stored rows a tile, for
+    tests."""
+    def geom(rows):
+        return root_hist_geometry(F=rows, B=B, nchan=nchan)
+
+    def fits(rows, c):
+        return fits_vmem(root_hist_vmem_bytes(
+            chunk=c, geom=geom(rows), bins_bytes=bins_bytes))
+
+    narrowest = min(F, HIST_TILE_ROW_ALIGN)
+    while not fits(narrowest, chunk) and chunk % 256 == 0:
+        chunk //= 2                     # halves stay whole lane tiles
+    if force is not None:
+        rows = min(int(force), F)
+    elif fits(F, chunk):
+        rows = F
+    else:
+        rows = F // HIST_TILE_ROW_ALIGN * HIST_TILE_ROW_ALIGN
+        while rows > 0 and not fits(rows, chunk):
+            rows -= HIST_TILE_ROW_ALIGN
+        if rows <= 0:
+            raise ValueError(
+                f"no feature tile of the root histogram kernel at {F} "
+                f"features x {B} bins fits the VMEM budget "
+                f"({PALLAS_VMEM_BUDGET_BYTES >> 20} MiB) at a row chunk "
+                f"of {chunk}")
+    return chunk, geom(rows), -(-F // rows)
+
+
 # rows of one dotted tile, and of one sub-tile of the scan, of the fused
 # kernel's row compaction (ops/hist_wave.py _fused_kernel)
 HIST_COMPACT_TILE = 512

@@ -3,7 +3,8 @@
 TPU-native replacement for the reference's per-leaf histogram kernels
 (reference: src/io/dense_bin.hpp:72-130 CPU loops,
 src/treelearner/ocl/histogram256.cl:345 OpenCL device kernels). Two key
-departures from round 1's per-leaf one-hot einsum:
+departures from round 1's per-leaf one-hot einsum, and a third for
+the root:
 
 1. **Wave batching.** The MXU matmul that accumulates histograms has
    128 output lanes but a single leaf only needs 3 channels
@@ -18,6 +19,13 @@ departures from round 1's per-leaf one-hot einsum:
    HIGGS size — the measured 5.5 ms/pass was pure HBM traffic). The
    Pallas kernel builds the one-hot tiles in VMEM and feeds the MXU
    directly.
+
+3. **The root pays for one leaf.** The one pass of a tree that serves
+   a single leaf has no other leaves to fill lanes with; its kernel
+   (``root_histogram_pallas``) splits the bin axis into two digits and
+   contracts a feature's hi one-hot against its lo-selected weight
+   rows: a sixth of the one-hot dot's MACs at 255 bins, the same sums
+   bit for bit.
 
 Data layout is **feature-major**: ``bins_t [F, N]`` so that a feature's
 bin row is a contiguous lane vector — the transposed one-hot tile
@@ -87,6 +95,22 @@ def _tile_grid(n_tiles: int, n_chunks: int):
     if n_tiles == 1:
         return (n_chunks,), lambda fn: (lambda i: fn(0, i))
     return (n_tiles, n_chunks), lambda fn: fn
+
+
+def _chunk_padded_rows(bins_t, g, h, leaf_ids, chunk):
+    """(bins_t, ghl) of a partition-free histogram kernel: the rows
+    padded to a whole number of chunks (pad rows in leaf -1, which no
+    slot counts) and the [4, N] f32 rows (grad, hess, leaf id, 0)."""
+    pad = (-bins_t.shape[1]) % chunk
+    if pad:
+        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad)))
+        g = jnp.pad(g, (0, pad))
+        h = jnp.pad(h, (0, pad))
+        leaf_ids = jnp.pad(leaf_ids, (0, pad), constant_values=-1)
+    return bins_t, jnp.stack([
+        g.astype(jnp.float32), h.astype(jnp.float32),
+        leaf_ids.astype(jnp.float32), jnp.zeros_like(g, jnp.float32)],
+        axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -596,18 +620,8 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
     group_sz, gb = geom["group_sz"], geom["gb"]
     groups, gb_pad = geom["groups"], geom["gb_pad"]
 
-    pad = (-n) % chunk
-    if pad:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, pad)))
-        g = jnp.pad(g, (0, pad))
-        h = jnp.pad(h, (0, pad))
-        leaf_ids = jnp.pad(leaf_ids, (0, pad), constant_values=-1)
-    n_pad = n + pad
-
-    ghl = jnp.stack([
-        g.astype(jnp.float32), h.astype(jnp.float32),
-        leaf_ids.astype(jnp.float32), jnp.zeros_like(g, jnp.float32)],
-        axis=0)                                          # [4, N]
+    bins_t, ghl = _chunk_padded_rows(bins_t, g, h, leaf_ids, chunk)
+    n_pad = bins_t.shape[1]
     wp = geom["wp"]
     wl = wave_leaves.astype(jnp.float32)[:, None]        # [W, 1]
     if wp != W:
@@ -698,6 +712,201 @@ def wave_histogram_pallas(bins_t, g, h, leaf_ids, wave_leaves, *, num_bins,
             return out
         out = out.astype(jnp.float32) * _qscale_vec(gh_scale)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Root histogram Pallas kernel: a two-digit split of the bin axis
+# ---------------------------------------------------------------------------
+
+def _root_weight_rows(gvec, hvec, mvec, variant):
+    """The [1, n] MXU weight rows of the one leaf a root pass serves,
+    in channel order: what row 0 of _wave_hist_kernel's ``w_rows``
+    blocks holds, value for value. "hilo4" rides the five rows of
+    "hilo5": with one leaf there are lanes to spare for its counts."""
+    if variant is None:
+        rows = [gvec, hvec]
+    else:
+        rows = list(_bf16_split(gvec))
+        if variant != "hilo3":
+            rows += _bf16_split(hvec)
+    return [mvec * r for r in rows] + [mvec]
+
+
+def _root_hist_kernel(bins_ref, ghl_ref, out_ref, hi_ref, lo_ref, *,
+                      F, H, L, gf, variant, exact_dot, tiled):
+    """One grid step = one row chunk (of one feature tile, ``tiled``):
+    out_ref[p] += the histograms of feature group p over the chunk's
+    rows, by a two-digit split of the bin axis.
+
+    A row's bin b = hi x L + lo. For one feature, with the weight rows
+    w_c of _root_weight_rows, P[(c, l), k] = w_c[k] where lo[k] == l
+    (else 0) and Q[h, k] = 1 where hi[k] == h: hist[h x L + l, c] =
+    sum_k Q[h, k] P[(c, l), k], the very products, in the same order
+    over k, as the one-hot dot of _wave_hist_kernel, which spends
+    Bp x 128 MACs on a row of a feature where this spends
+    nchan x L x 128: the ``gf`` features of a group put their Q side by
+    side as the 128-lane operand of ONE dot and stream their P rows
+    past it; each feature's own [nchan x L, H] block of the product is
+    its histogram and the wrapper drops the others. Rows stay on the
+    lane axis throughout, as in every kernel of this file.
+
+    bins_ref: [F_rows, Ct] feature-major bins (uint8)
+    ghl_ref:  [4, Ct] f32 rows (grad, hess, leaf id: 0 in, -1 out, 0)
+    out_ref:  [groups, gf x nchan x L, 128] accumulators
+    hi_ref, lo_ref: [F_rows, Ct] i32 scratch, the block's two digits
+    """
+    step = pl.program_id(1 if tiled else 0)
+
+    @pl.when(step == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    i32, f32 = jnp.int32, jnp.float32
+    mvec = (ghl_ref[2:3, :] == 0.0).astype(f32)             # [1, Ct]
+    ct = mvec.shape[1]
+    # every weight row over the L sublanes of its lo digits, once a step
+    wb = [jnp.broadcast_to(r, (L, ct)) for r in _root_weight_rows(
+        ghl_ref[0:1, :], ghl_ref[1:2, :], mvec, variant)]
+    x = bins_ref[...].astype(i32)
+    hi_ref[...] = jax.lax.shift_right_logical(x, L.bit_length() - 1)
+    lo_ref[...] = jnp.bitwise_and(x, L - 1)
+    l_iota = jax.lax.broadcasted_iota(i32, (L, 1), 0)
+    h_iota = jax.lax.broadcasted_iota(i32, (H, 1), 0)
+    dt = f32 if exact_dot else jnp.bfloat16
+
+    def group(p, n_live):
+        """out_ref[p] += P . Q of the group's first ``n_live`` features
+        (zero operands stand for the others of a ragged last group)."""
+        ps, qs = [], []
+        for s in range(n_live):
+            r = pl.ds(p * gf + s, 1)
+            hit = lo_ref[r, :] == l_iota                    # [L, Ct]
+            ps += [jnp.where(hit, w, 0.0) for w in wb]
+            qs.append((hi_ref[r, :] == h_iota).astype(dt))
+        if n_live < gf:
+            ps.append(jnp.zeros(((gf - n_live) * len(wb) * L, ct), f32))
+            qs.append(jnp.zeros(((gf - n_live) * H, ct), dt))
+        # the lane axis of both operands contracts, as in every dot of
+        # this file; see _wave_hist_kernel on DEFAULT against HIGHEST
+        out_ref[p, :, :] += jax.lax.dot_general(
+            jnp.concatenate(ps, axis=0).astype(dt),         # [R, Ct]
+            jnp.concatenate(qs, axis=0),                    # [128, Ct]
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=(jax.lax.Precision.HIGHEST if exact_dot
+                       else jax.lax.Precision.DEFAULT),
+            preferred_element_type=f32)
+
+    # ONE body for the whole groups, walked by a loop, and one for a
+    # ragged last group. Unrolled, the groups ran 7% SLOWER at 67
+    # features and compiled in 21 s against 2.9 (PERF.md section 5,
+    # PR 33, call A); see the tiled _wave_hist_kernel on what unrolling
+    # its 16,384-lane one-hot tiles cost Mosaic
+    def whole_group(p, carry):
+        group(p, gf)
+        return carry
+
+    jax.lax.fori_loop(0, F // gf, whole_group, 0)
+    if F % gf:
+        group(F // gf, F % gf)
+
+
+def root_nchan(precision, variant) -> int:
+    """Weight rows of the root kernel's one leaf (_root_weight_rows)."""
+    return 5 if precision == "highest" and variant != "hilo3" else 3
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("num_bins", "chunk", "interpret",
+                                    "precision", "variant",
+                                    "feature_tile"))
+def root_histogram_pallas(bins_t, g, h, leaf_ids, *, num_bins, chunk=2048,
+                          interpret=False, precision="highest",
+                          variant="hilo5", feature_tile=None):
+    """[1, F, B, 3]: the histogram of leaf 0, a tree's root pass.
+
+    What ``wave_histogram_pallas`` returns in slot 0 for
+    ``wave_leaves = [0, -1, ...]``, sum for sum, by a dot that asks the
+    MXU for ``nchan x 8 x 128`` MACs on a row of a feature where that
+    one asks for ``Bp x 128`` (_root_hist_kernel): one leaf needs no
+    lanes for other leaves' channels. ``leaf_ids``: 0 for the rows that
+    count, -1 for out-of-bag rows (and for the chunk's pad rows). The
+    bf16 tiers only (``precision`` "highest" with its ``variant``, or
+    "default"), byte bins of at most 256 levels;
+    autotune.root_split_applies is the grower's question.
+
+    ``chunk`` is the caller's (the grower's, chosen by what the wave
+    and fused kernels cost); the kernel walks the largest
+    ``chunk / 2^k`` rows a step that its own working set fits VMEM at
+    (autotune.root_hist_tiling: 16384 of 32768 or 65536 at 5 channels x
+    256 bins). Its sums then add up in the halved chunk's order: equal
+    to the wave kernel's at THAT chunk bit for bit, and to the one at
+    the caller's chunk as closely as two chunk sizes of the wave kernel
+    agree.
+
+    The Mosaic call keeps the name ``wave_histogram_pallas``: a trace
+    reader that groups a tree's histogram passes by kernel name goes on
+    counting the root pass with the others.
+    Where the bin block and its digit scratches of all F features no
+    longer fit VMEM the grid gains an outer axis over tiles of features
+    (autotune.root_hist_tiling); ``feature_tile`` (stored bin rows a
+    tile) forces one, for tests.
+    """
+    if precision not in ("highest", "default"):
+        raise NotImplementedError(
+            f"the root kernel serves the bf16 tiers, not {precision!r}")
+    F, n = bins_t.shape
+    B = num_bins
+    nchan = root_nchan(precision, variant)
+    variant = variant if precision == "highest" else None
+    chunk, geom, n_tiles = autotune.root_hist_tiling(
+        F=F, B=B, nchan=nchan, chunk=chunk,
+        bins_bytes=bins_t.dtype.itemsize, force=feature_tile)
+    H, L, gf, groups = geom["H"], geom["L"], geom["gf"], geom["groups"]
+
+    bins_t, ghl = _chunk_padded_rows(bins_t, g, h, leaf_ids, chunk)
+    tiled = n_tiles > 1
+    kernel = functools.partial(
+        _root_hist_kernel, F=geom["F"], H=H, L=L, gf=gf, variant=variant,
+        exact_dot=interpret, tiled=tiled)
+    blk = autotune.root_hist_block_shapes(chunk=chunk, geom=geom)
+    grid, at = _tile_grid(n_tiles, bins_t.shape[1] // chunk)
+    out = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec(blk["bins"], at(lambda t, i: (t, i)),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(blk["ghl"], at(lambda t, i: (0, i)),
+                         memory_space=pltpu.VMEM),
+        ],
+        # every tile's accumulator is one block of the output's group axis
+        out_specs=pl.BlockSpec(blk["hist"], at(lambda t, i: (t, 0, 0)),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_tiles * groups,) + blk["hist"][1:], jnp.float32),
+        scratch_shapes=[pltpu.VMEM(blk["bins"], jnp.int32)] * 2,
+        compiler_params=autotune.tpu_compiler_params(),
+        name="wave_histogram_pallas",
+        interpret=interpret,
+    )(bins_t, ghl)
+
+    # [tiles x groups, gf x nchan x L, gf x H]: feature j of a group
+    # keeps block (j, j) of its group's product, the products of two
+    # different features' digits are dropped; -> [F, B, nchan]
+    out = out.reshape(n_tiles * groups, gf, nchan, L, gf, H)
+    out = jnp.stack([out[:, j, :, :, j] for j in range(gf)], axis=1)
+    out = out.transpose(0, 1, 4, 3, 2)           # [G, gf, H, L, nchan]
+    out = out.reshape(n_tiles, groups * gf, H * L, nchan)
+    out = out[:, :geom["F"], :B].reshape(-1, B, nchan)[:F]
+    if nchan == 5:
+        out = jnp.stack([out[..., 0] + out[..., 1],       # g = hi + lo
+                         out[..., 2] + out[..., 3],       # h = hi + lo
+                         out[..., 4]], axis=-1)           # count
+    elif variant == "hilo3":
+        # the fused hess/count plane serves both (see _wave_hist_kernel)
+        out = jnp.stack([out[..., 0] + out[..., 1],
+                         out[..., 2], out[..., 2]], axis=-1)
+    return out[None]
 
 
 def _qscale_vec(gh_scale):

@@ -40,7 +40,8 @@ import numpy as np
 from ..obs import registry as obs
 from .autotune import DEFAULT_HIST_CHUNK
 from .grower import TreeRecord
-from .hist_wave import (fused_partition_histogram_pallas, wave_histogram,
+from .hist_wave import (fused_partition_histogram_pallas,
+                        root_histogram_pallas, root_nchan, wave_histogram,
                         wave_histogram_pallas)
 from .partition import member_column, row_goes_right
 from .split import (FeatureMeta, SplitParams, SplitResult, KMIN_SCORE,
@@ -415,6 +416,23 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             variant=(cfg.exact_variant
                      if cfg.precision == "highest" else None))
         obs.gauge("hist/feature_tiles").set(float(n_tiles))
+    # the root pass: a kernel of its own wherever the shapes and the
+    # tier say its digit split pays (autotune.root_split_applies), else
+    # the wave kernel with one live slot; an injected hist_fn (the
+    # parallel learners', the EFB seam's) keeps its own root. The gauge
+    # is set on every path, so it never carries an earlier grower's
+    # value: 0 where the root's dot is not this grower's to price (an
+    # injected hist_fn, the XLA scatter, the sparse tier)
+    root_macs = 0
+    use_root_kernel = False
+    if pallas_hist and hist_fn is None and not cfg.sparse_hist:
+        use_root_kernel = autotune.root_split_applies(
+            B=B, precision=cfg.precision, count_proxy=proxy,
+            packed4=cfg.packed4)
+        root_macs = autotune.root_pass_macs(
+            B=B, nchan=root_nchan(cfg.precision, cfg.exact_variant),
+            split=use_root_kernel)
+    obs.gauge("hist/root_macs").set(float(root_macs))
 
     if hist_fn is None and cfg.sparse_hist:
         # sparse tier: the histogram source is the (dense bins, sparse
@@ -622,7 +640,14 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             root_wl = jnp.concatenate(
                 [jnp.zeros(1, jnp.int32), jnp.full(W - 1, -1, jnp.int32)])
             leaf0 = jnp.zeros(n, jnp.int32)
-            if use_fused and (proxy or cfg.packed4):
+            if use_root_kernel:
+                from ..utils.device import on_tpu
+                local_root = root_histogram_pallas(
+                    bins_t, hg, hh, bag_mask_ids(leaf0), num_bins=B,
+                    chunk=cfg.chunk or DEFAULT_HIST_CHUNK,
+                    interpret=not on_tpu(), precision=cfg.precision,
+                    variant=cfg.exact_variant)       # [1, F, B, 3]
+            elif use_fused and (proxy or cfg.packed4):
                 # proxy/packed4 root: the partition-free wave kernel in the
                 # matching tier — no partition logic to pay for on an
                 # unsplit tree, and (packed4) the default hist_fn never
@@ -1085,6 +1110,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         return rec, state.leaf_ids
 
     # jit-capture: ok(B, hp, cfg, quant, use_fused, use_fused_xla,
+    # use_root_kernel,
     # fused_chunk, fused_interpret, fused_partition_histogram_xla,
     # meta_const,
     # bound_counts, depth_ok, hist_fn, hist_reduce_fn, reduce_fn,

@@ -359,15 +359,21 @@ def _pallas_calls(path):
     ("stacked_predict.py", "forest_predict_pallas"),
 ])
 def test_every_pallas_call_is_named_after_its_entry_point(module, kernel):
-    """Four ``pallas_call``s in ops/, each with ``name=`` its jitted entry
+    """Five ``pallas_call``s in ops/, each with ``name=`` its jitted entry
     point's own name: whichever of the two the compiler shows in a trace,
-    the name is the same and stays put."""
+    the name is the same and stays put. The one exception is on purpose:
+    the root pass's kernel of its own (``root_histogram_pallas``) keeps the
+    name ``wave_histogram_pallas`` the root pass has always run under, so
+    whoever groups a tree's histogram passes by kernel name (``benchmark/
+    traffic/train.json``) goes on counting it."""
     calls = dict(_pallas_calls(OPS / module))
     assert calls[kernel] == kernel
+    if module == "hist_wave.py":
+        assert calls["root_histogram_pallas"] == "wave_histogram_pallas"
     all_calls = [c for m in ("hist_wave.py", "predict.py",
                              "stacked_predict.py")
                  for c in _pallas_calls(OPS / m)]
-    assert len(all_calls) == 4 and all(n for _, n in all_calls)
+    assert len(all_calls) == 5 and all(n for _, n in all_calls)
 
 
 def _traced_kernel_names(fn, *args):
@@ -484,3 +490,62 @@ def test_span_off_path_stays_in_microseconds():
             pass
     per_span_us = (time.perf_counter() - t0) / n * 1e6
     assert per_span_us < 500.0, per_span_us
+
+
+@pytest.mark.parametrize("tile", [None, 32], ids=["one-tile", "tiled"])
+def test_root_kernel_reaches_the_compiler_under_the_root_passs_name(tile):
+    """The root pass's kernel of its own is a Mosaic call named
+    ``wave_histogram_pallas``, as the root pass has always been: the
+    benchmark's ``hist`` group (``step.passes_per_iter`` 16.0 a tree,
+    ``kernel.ms_per_pass``) and ``kernel.root_ms_per_iter`` find it by
+    that substring."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.hist_wave import root_histogram_pallas
+    S = jax.ShapeDtypeStruct
+    N, F = 8192, 72
+    fn = functools.partial(root_histogram_pallas, num_bins=255, chunk=4096,
+                           variant="hilo5", feature_tile=tile)
+    (name,) = _traced_kernel_names(
+        fn, S((F, N), jnp.uint8), S((N,), jnp.float32),
+        S((N,), jnp.float32), S((N,), jnp.int32))
+    patterns = json.loads((ROOT / "benchmark" / "traffic" / "train.json")
+                          .read_text())["kernels"]["hist"]
+    assert name == "wave_histogram_pallas" and name in patterns
+
+
+def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
+    """``hist/root_macs`` (MACs the root's dot spends on a row of a
+    feature, as the kernel was built) is set where the grower is built,
+    beside ``hist/feature_tiles``; ``benchmark/readers/kernel.root_macs.py``
+    reads it. The grown step carries the root kernel inside the scope
+    ``lgbm/root_hist``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+    from lightgbm_tpu.ops.wave_grower import (WaveGrowerConfig,
+                                              make_wave_grower)
+    reg = obs.default_registry()
+    # put back at the end what the gauge held: the next test on this
+    # worker reads its own grower's value or the one before
+    monkeypatch.setattr(reg.gauge("hist/root_macs"), "_value", -1.0)
+    f, n, B = 8, 1024, 255
+    meta = FeatureMeta(
+        num_bin=np.full(f, B, np.int32), missing_type=np.zeros(f, np.int32),
+        default_bin=np.zeros(f, np.int32), monotone=np.zeros(f, np.int32),
+        penalty=np.ones(f, np.float32))
+    cfg = WaveGrowerConfig(
+        num_leaves=7, num_bins=B, wave_size=4, chunk=512,
+        route="pallas-tpu", hp=SplitParams(min_data_in_leaf=5, has_cat=False))
+    grow = make_wave_grower(cfg, meta, jit=False)
+    assert reg.snapshot()["gauges"]["hist/root_macs"] == 5 * 8 * 128
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(grow).lower(
+        S((f, n), jnp.uint8), S((n,), jnp.float32), S((n,), jnp.float32),
+        S((n,), jnp.float32), S((f,), jnp.bool_)).as_text(debug_info=True)
+    assert "lgbm/root_hist/" in text
+    # the CPU's own route runs no Mosaic kernel: no dot to price, and
+    # not the value of the grower before
+    _booster().train_one_iter()
+    assert reg.snapshot()["gauges"]["hist/root_macs"] == 0.0

@@ -159,6 +159,67 @@ def test_wave_histogram_kernel_compiles(spec, name):
     assert _mosaic(compiled) == 1
 
 
+@pytest.mark.parametrize("F, N", [(67, 10_485_760), (2000, 393_216)],
+                         ids=["criteo", "epsilon"])
+def test_root_histogram_kernel_compiles_at_the_cells_shapes(spec, F, N):
+    """The root pass's kernel of its own (a two-digit split of the bin
+    axis) at both benchmark cells' whole shapes, 255 bins, the cells'
+    chunk and layout: Mosaic accepts it, the tile it walks is priced
+    inside the VMEM budget, and its one rolled group body compiles in
+    seconds (the wave kernel's 67 unrolled groups took 130 s)."""
+    import time
+    from lightgbm_tpu.ops import autotune
+    from lightgbm_tpu.ops.hist_wave import root_histogram_pallas
+    chunk, geom, tiles = autotune.root_hist_tiling(F=F, B=255, nchan=5,
+                                                   chunk=16384)
+    assert chunk == 16384
+    assert autotune.root_hist_vmem_bytes(chunk=16384, geom=geom) \
+        <= autotune.PALLAS_VMEM_BUDGET_BYTES
+    args = (spec((F, N), jnp.uint8), spec((N,), jnp.float32),
+            spec((N,), jnp.float32), spec((N,), jnp.int32))
+    t0 = time.perf_counter()
+    compiled = jax.jit(functools.partial(
+        root_histogram_pallas, num_bins=255, chunk=16384,
+        precision="highest", variant="hilo5")).lower(*args).compile()
+    print(f"root kernel, {F} features in {tiles} tile(s) of "
+          f"{geom['F']} rows: compiled in "
+          f"{time.perf_counter() - t0:.1f} s")
+    assert _mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize("layout, F, N, chunk", [
+    ("hilo5", 67, 10_485_760, 65536), ("hilo5", 2000, 393_216, 65536),
+    ("hilo5", 2000, 393_216, 32768), ("hilo3", 67, 10_485_760, 65536),
+    ("bf16", 2000, 393_216, 32768)],
+    ids=["criteo-65536", "epsilon-65536", "epsilon-32768",
+         "criteo-hilo3-65536", "epsilon-bf16-32768"])
+def test_root_histogram_kernel_compiles_at_the_tuners_largest_chunks(
+        spec, layout, F, N, chunk):
+    """The grower's chunk is the tuner's, priced for the wave and fused
+    kernels: 32768 is offered both cells' shapes, 65536 under
+    tpu_autotune=exhaustive. The root kernel's operands outgrow VMEM
+    there, so it walks the largest half of the chunk that fits
+    (autotune.root_hist_tiling) and Mosaic accepts that: the three-channel
+    layouts keep a larger chunk than hilo5's 16384, one no cell runs."""
+    from lightgbm_tpu.ops import autotune
+    from lightgbm_tpu.ops.hist_wave import root_histogram_pallas, root_nchan
+    kw = dict(hilo5=dict(precision="highest", variant="hilo5"),
+              hilo3=dict(precision="highest", variant="hilo3"),
+              bf16=dict(precision="default"))[layout]
+    nchan = root_nchan(kw["precision"], kw.get("variant"))
+    own, geom, tiles = autotune.root_hist_tiling(F=F, B=255, nchan=nchan,
+                                                 chunk=chunk)
+    assert own == (16384 if nchan == 5 else 32768)
+    args = (spec((F, N), jnp.uint8), spec((N,), jnp.float32),
+            spec((N,), jnp.float32), spec((N,), jnp.int32))
+    compiled = jax.jit(functools.partial(
+        root_histogram_pallas, num_bins=255, chunk=chunk,
+        **kw)).lower(*args).compile()
+    print(f"root kernel ({layout}), asked {chunk} rows a step, walks "
+          f"{own} in {tiles} tile(s) of {geom['F']} rows")
+    assert _mosaic(compiled) == 1
+
+
 def test_largest_offered_chunk_is_priced_inside_what_compiles(spec):
     """The tuner's VMEM pricing against the compiler: the LARGEST
     chunk hist_chunk_candidates offers a proxy-tier geometry (priced
