@@ -67,6 +67,7 @@ class Sizes(NamedTuple):
     lrb_sample: int = 3_600            # training rows drawn per window
     lrb_objects: int = 20_000
     multichip_iters: int = 5
+    rank_queries: int = 4096           # lambdarank train; lengths 1..139
 
 
 REAL = Sizes()
@@ -656,6 +657,76 @@ def phase_lrb(sz: Sizes, seed: int, on_chip: bool = True) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase: lambdarank (the pair gradient laid out by query length)
+# ---------------------------------------------------------------------------
+
+def phase_rank(sz: Sizes, seed: int, on_chip: bool = True) -> None:
+    """The program's lambdas and hessians against the benchmark's plain
+    reference (``benchmark/reference_rank.py``) on 64 queries of lengths
+    1..139 at all-equal, random and tied scores; then a three-tree
+    lambdarank train through ``Dataset(group=)`` on the kernels' route."""
+    import jax
+    import jax.numpy as jnp
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata
+    from lightgbm_tpu.objectives import create_objective
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "benchmark"))
+    import reference_rank
+    r = np.random.default_rng(seed + 11)
+    counts = np.concatenate([[1, 2, 7, 8, 9, 64, 139], np.clip(np.rint(np.exp(
+        2.2 + r.standard_normal(57))), 1, 139)]).astype(np.int64)
+    n = int(counts.sum())
+    lab = r.integers(0, 5, n)
+    obj = create_objective("lambdarank",
+                           Config().set({"objective": "lambdarank"}))
+    obj.init(Metadata(label=lab.astype(np.float32), group=counts), n)
+    fn = jax.jit(obj.gradient_builder())
+    ref = reference_rank.RankGrads(lab.astype(np.float32), counts,
+                                   {"sigmoid": 1.0, "max_position": 20})
+    worst = 0.0
+    for score in (np.zeros(n, np.float32),
+                  r.standard_normal(n).astype(np.float32),
+                  (r.integers(0, 4, n) * 0.25).astype(np.float32)):
+        g, h = (np.asarray(a) for a in fn(jnp.asarray(score),
+                                          obj.gradient_aux()))
+        gh = ref.at(jnp.asarray(score))
+        worst = max(worst,
+                    float(np.abs(g - gh[:, 0]).max() / np.abs(gh[:, 0]).max()),
+                    float(np.abs(h - gh[:, 1]).max() / np.abs(gh[:, 1]).max()))
+    check(len(obj._pair_classes) > 1 and worst <= 1e-5,
+          f"rank: lambdas and hessians of {len(counts)} queries of 1..139 "
+          f"documents in {len(obj._pair_classes)} width classes match the "
+          f"plain reference (widest gap {worst:.2e} of the largest, limit "
+          f"1e-5: float32 sums in another order, exp and log2 of the chip)")
+    counts = np.clip(np.rint(np.exp(2.78 + 0.9 * r.standard_normal(
+        sz.rank_queries))), 1, 139).astype(np.int64)
+    X, y = higgs_like(int(counts.sum()), seed + 12)
+    grades = np.clip(np.rint(X[:, 0] * X[:, 1] + X[:, 2] + 1.5), 0,
+                     4).astype(np.float32)
+    params = dict(HIGGS_PARAMS, objective="lambdarank", metric="ndcg",
+                  num_leaves=sz.leaves, min_data_in_leaf=0,
+                  min_sum_hessian_in_leaf=10.0)
+    bst = lgb.Booster(params, lgb.Dataset(X, label=grades, group=counts,
+                                          params=dict(params)))
+    for _ in range(3):
+        bst.update()
+    rep = bst.device_report()
+    if on_chip:
+        check(rep["route"] == "pallas-tpu" and rep["fused_pallas"]
+              and not rep["interpret"],
+              f"rank: trained on the Mosaic route ({rep['route']})")
+    scores = np.asarray(bst._gbdt.train_scores()[0])[:len(grades)]
+    ref = reference_rank.RankGrads(grades, counts, {})
+    nd = ref.ndcg(jnp.asarray(np.stack([np.zeros_like(scores), scores])))
+    check(bst.current_iteration() == 3 and np.all(np.isfinite(scores))
+          and nd[1] > nd[0] + 0.05,
+          f"rank: three lambdarank trees over {len(counts)} queries "
+          f"({len(grades)} rows) raise NDCG@10 {nd[0]:.4f} -> {nd[1]:.4f}")
+
+
+# ---------------------------------------------------------------------------
 # phase: the step registry's promise
 # ---------------------------------------------------------------------------
 
@@ -837,6 +908,7 @@ def run(chips: int, seed: int, sz: Sizes = REAL) -> dict:
         phase_compare(proxy.device_report(), sz, seed)
         phase_predict(proxy, holdout, sz, "proxy", full=False)
         phase_registry(sz, seed)
+        phase_rank(sz, seed)
         phase_lrb(sz, seed)
     check(moved(c0, "retry/retries") == 0
           and moved(c0, "retry/giveups") == 0,

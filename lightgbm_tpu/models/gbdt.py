@@ -1359,13 +1359,13 @@ class GBDT:
         the row axis (objectives/objective.py seam contract); pad it
         from n to the bucketed n_score with zeros and place it under
         the step's row sharding. Leaves under a dict key starting with
-        ``_`` are NOT row-shaped (lambdarank's padded query tables):
-        they are placed replicated, unpadded."""
+        ``_`` are NOT row-shaped (lambdarank's query tables, a pytree of
+        its width classes): their leaves are placed replicated, unpadded."""
         if aux is None:
             return None
         if isinstance(aux, dict):
-            return {k: (self._place_step_raw(v) if k.startswith("_")
-                        else self._pad_step_aux(v))
+            return {k: (jax.tree_util.tree_map(self._place_step_raw, v)
+                        if k.startswith("_") else self._pad_step_aux(v))
                     for k, v in aux.items()}
         a = np.asarray(aux)
         pad = self._n_score - a.shape[-1]

@@ -8,8 +8,9 @@ exactly (file:line cited per class); evaluation is vectorized jax instead
 of OpenMP loops. Scores are laid out [num_class, N] like the reference's
 class-major score buffer.
 
-The pairwise lambdarank loops (rank_objective.hpp:81-166) become padded
-per-query dense [Q, Q] matrices under ``vmap`` — no data-dependent loops.
+The pairwise lambdarank loops (rank_objective.hpp:81-166) become dense
+per-query [w, w] blocks under ``vmap``, the queries sorted into a few width
+classes by their length — no data-dependent loops, no scatter.
 The reference's sigmoid lookup table (rank_objective.hpp:171-196) is a CPU
 speed hack; we compute the exact sigmoid on the VPU.
 """
@@ -66,8 +67,9 @@ class ObjectiveFunction:
     # ``get_gradients`` delegates to the same pure fn, so the legacy
     # per-instance step and the shared step run IDENTICAL code — a
     # registry hit cannot change numerics. Aux dict keys starting with
-    # ``_`` are NOT row-shaped (lambdarank's padded query tables) and
-    # ride to the device unpadded/replicated. An objective without a
+    # ``_`` are NOT row-shaped (lambdarank's query tables, a pytree of
+    # its width classes) and ride to the device unpadded/replicated. An
+    # objective without a
     # sound pure seam would return None and keep the legacy closure
     # (none remain in-tree — lambdarank, the last holdout, rides its
     # query tables as ``_``-keys).
@@ -668,7 +670,43 @@ def _xentlambda_weighted(score, y, w):
 # LambdaRank (src/objective/rank_objective.hpp:19-240)
 # --------------------------------------------------------------------------
 
+# What the pair layout is chosen by (LambdarankNDCG.init): no knob.
+PAIR_SLOTS_BOUND = 4.0      # slots one padded class may spend per real pair
+PAIR_MIN_WIDTH = 8          # the narrowest width class
+PAIR_BLOCK_BYTES = 1 << 26  # one float32 [block, w, w] temporary of a class
+
+
+def pair_class_widths(counts, nq_padded: int) -> list:
+    """The width classes of a ranking data set, from its observed query
+    lengths. One class of width ``qmax`` (the layout every release had)
+    while that spends at most ``PAIR_SLOTS_BOUND`` slots per real pair
+    (``sum n_q^2``); else powers of two from ``PAIR_MIN_WIDTH`` with the
+    widest cut to ``qmax``, a query in the narrowest class that holds it
+    (so at most 4 slots a pair from 4 documents up), empty classes left
+    out."""
+    counts = np.asarray(counts, np.int64)
+    qmax = int(counts.max())
+    if nq_padded * qmax * qmax <= PAIR_SLOTS_BOUND * float(np.sum(counts ** 2)):
+        return [qmax]
+    widths = []
+    w = PAIR_MIN_WIDTH
+    while w < qmax:
+        widths.append(w)
+        w *= 2
+    widths.append(qmax)
+    used = np.unique(np.searchsorted(widths, counts))
+    return [widths[i] for i in used]
+
+
 class LambdarankNDCG(ObjectiveFunction):
+    """rank_objective.hpp:19-240. A query's rows are one contiguous range
+    (``query_boundaries``), so the pair block is laid out by query length:
+    ``init`` sorts the queries into a few width classes
+    (``pair_class_widths``), each a ``[nq_c, w_c]`` table with ``nq_c``
+    padded to its pow2 bucket (windows of like shape share one compiled
+    step), and every row learns where its sums will stand (``src``). The
+    step evaluates ``[nq_c, w_c, w_c]`` pairs a class and GATHERS the rows'
+    sums: no scatter anywhere."""
     name = "lambdarank"
     need_query = True
 
@@ -687,67 +725,82 @@ class LambdarankNDCG(ObjectiveFunction):
         lab = self.label.astype(np.int32)
         if lab.max() >= len(self.label_gain):
             log.fatal("Label exceeds label_gain size")
+        if lab.min() < 0:
+            log.fatal("Label should be non-negative for ranking task")
+        self._build_pair_layout(lab)
+        # the eager entry (``refit_existing``) runs the pair block as ONE
+        # program, as the step does: op by op every [nq_c, w, w] temporary
+        # would be materialised
+        # jit-capture: ok(*) — the pure seam's builder closes over config
+        # scalars alone (``static_key``); labels and tables arrive in aux
+        self._eager_grads = jax.jit(self.gradient_builder())
 
-        # pad queries to a fixed max length (TPU static shapes)
-        nq = len(self.query_boundaries) - 1
-        counts = np.diff(self.query_boundaries)
-        qmax = int(counts.max())
-        idx = np.zeros((nq, qmax), np.int32)
-        valid = np.zeros((nq, qmax), bool)
-        for q in range(nq):
-            c = counts[q]
-            idx[q, :c] = np.arange(self.query_boundaries[q],
-                                   self.query_boundaries[q + 1])
-            valid[q, :c] = True
-        self.q_idx = idx
-        self.q_valid = valid
-        # inverse max DCG at k per query (rank_objective.hpp:55-68)
-        self.inv_max_dcg = np.zeros(nq, np.float64)
-        for q in range(nq):
-            labels_q = lab[idx[q, :counts[q]]]
-            top = np.sort(labels_q)[::-1][:self.optimize_pos_at]
-            dcg = np.sum(self.label_gain[top]
-                         / np.log2(np.arange(len(top)) + 2.0))
-            self.inv_max_dcg[q] = 1.0 / dcg if dcg > 0 else 0.0
-
-    def _bucketed_query_tables(self):
-        """(q_idx, q_valid, inv_max_dcg) with the QUERY axis padded to
-        its pow2 bucket under the booster's ``tpu_row_bucket`` policy
-        (0 = exact), so ranking windows whose query counts land in the
-        same bucket share ONE compiled step — the sliding-window
-        retrain hits the registry instead of re-tracing per window.
-        Pad queries are all-invalid: every pairwise term is masked by
-        ``pair_ok`` and the scatter by ``flat_valid``, so they
-        contribute exact +0.0 (bit-identical to the exact-shape run).
-        ``qmax`` is deliberately NOT bucketed: the per-query pair sums
-        reduce over that axis, and a wider axis regroups the reduction
-        of the REAL values (ulp drift) even though the pad terms are
-        exact zeros."""
+    def _build_pair_layout(self, lab):
+        from ..obs import registry as obs
         from ..ops.step_cache import pow2_bucket
-        nq, qmax = self.q_idx.shape
-        if getattr(self.config, "tpu_row_bucket", -1) == 0:
-            return self.q_idx, self.q_valid, self.inv_max_dcg
-        nq_p = pow2_bucket(nq, 16)
-        if nq_p == nq:
-            return self.q_idx, self.q_valid, self.inv_max_dcg
-        idx = np.zeros((nq_p, qmax), np.int32)
-        valid = np.zeros((nq_p, qmax), bool)
-        imd = np.zeros(nq_p, np.float64)
-        idx[:nq] = self.q_idx
-        valid[:nq] = self.q_valid
-        imd[:nq] = self.inv_max_dcg
-        return idx, valid, imd
+        qb = self.query_boundaries
+        counts = np.diff(qb)
+        nq, qmax = len(counts), int(counts.max())
+        exact = getattr(self.config, "tpu_row_bucket", -1) == 0
+
+        def bucket(n):
+            return n if exact else pow2_bucket(n, 16)
+
+        valid = np.arange(qmax)[None, :] < counts[:, None]
+        lab_q = np.full((nq, qmax), -1, np.int32)
+        lab_q[valid] = lab
+        # inverse max DCG at k per query (rank_objective.hpp:55-68): the
+        # labels a query a line, sorted descending, the top k discounted;
+        # summed a group of like length at a time, so that each query's
+        # sum runs over its own documents alone, in their order
+        top_q = -np.sort(-lab_q, axis=1)[:, :self.optimize_pos_at]
+        dcg = np.zeros(nq, np.float64)
+        kept = np.minimum(counts, top_q.shape[1])
+        for m in np.unique(kept):
+            rows = kept == m
+            dcg[rows] = np.sum(self.label_gain[top_q[rows, :m]]
+                               / np.log2(np.arange(m) + 2.0), axis=1)
+        self.inv_max_dcg = np.where(dcg > 0, 1.0 / np.where(dcg > 0, dcg, 1.0),
+                                    0.0)
+
+        widths = pair_class_widths(counts, bucket(nq))
+        cls = np.searchsorted(widths, counts)
+        src = np.zeros(int(qb[-1]), np.int64)
+        classes, at = [], 1               # flat[0] is the pad rows' exact +0.0
+        for c, w in enumerate(widths):
+            qs = np.nonzero(cls == c)[0]
+            nq_p = bucket(len(qs))
+            start = np.zeros(nq_p, np.int32)
+            imd = np.zeros(nq_p, np.float32)
+            labels = np.full((nq_p, w), -1, np.int32)
+            start[:len(qs)] = qb[qs]
+            imd[:len(qs)] = self.inv_max_dcg[qs]
+            labels[:len(qs)] = lab_q[qs, :w]
+            classes.append({"start": start, "imd": imd, "lab": labels})
+            # row qb[q] + p stands at flat[at + k * w + p], k the query's
+            # place in its class
+            p_in = _within(counts[qs])
+            src[np.repeat(qb[qs], counts[qs]) + p_in] = np.repeat(
+                at + np.arange(len(qs), dtype=np.int64) * w, counts[qs]) + p_in
+            at += nq_p * w
+        if at >= 2 ** 31:
+            log.fatal("Lambdarank: query tables exceed int32 positions")
+        self._pair_classes = tuple(classes)
+        self._pair_src = src.astype(np.int32)
+        obs.gauge("rank/queries").set(float(nq))
+        obs.gauge("rank/qmax").set(float(qmax))
+        obs.gauge("rank/width_classes").set(float(len(classes)))
+        obs.gauge("rank/pairs_real").set(float(np.sum(counts ** 2)))
+        obs.gauge("rank/pair_slots").set(float(sum(
+            c["lab"].shape[0] * c["lab"].shape[1] ** 2 for c in classes)))
 
     def gradient_aux(self):
-        idx, valid, imd = self._bucketed_query_tables()
         return {
-            "y": self.label.astype(np.int32),
             "w": self.weights,
-            # query tables are [nq, qmax]/[nq] — NOT row-shaped; the
-            # ``_`` prefix tells the caller to place them unpadded
-            "_q_idx": idx,
-            "_q_valid": valid,
-            "_inv_max_dcg": imd.astype(np.float32),
+            "src": self._pair_src,
+            # [nq_c(, w_c)] tables, NOT row-shaped: the ``_`` prefix tells
+            # the caller to place them unpadded
+            "_classes": self._pair_classes,
             "_label_gain": self.label_gain.astype(np.float32),
         }
 
@@ -756,12 +809,10 @@ class LambdarankNDCG(ObjectiveFunction):
         weighted = self.weights is not None
 
         def fn(score, aux):
-            lam, hes = _lambdarank_grads(
-                score, jnp.asarray(aux["y"]),
-                jnp.asarray(aux["_q_idx"]),
-                jnp.asarray(aux["_q_valid"]),
-                jnp.asarray(aux["_inv_max_dcg"]),
-                jnp.asarray(aux["_label_gain"]), sigmoid)
+            with jax.named_scope("rank_pairs"):
+                lam, hes = _lambdarank_grads(
+                    score, aux["_classes"], jnp.asarray(aux["src"]),
+                    jnp.asarray(aux["_label_gain"]), sigmoid)
             if weighted:
                 w = jnp.asarray(aux["w"])
                 lam, hes = lam * w, hes * w
@@ -772,59 +823,78 @@ class LambdarankNDCG(ObjectiveFunction):
         return ("lambdarank", float(self.sigmoid))
 
     def get_gradients(self, score):
-        return self.gradient_builder()(score, self.gradient_aux())
+        return self._eager_grads(score, self.gradient_aux())
 
     def to_string(self):
         return "lambdarank"
 
 
-@jax.jit
-def _lambdarank_grads(score, labels, q_idx, q_valid, inv_max_dcg,
-                      label_gain, sigmoid):
-    """Padded pairwise lambda computation, vmapped over queries
-    (rank_objective.hpp:81-166)."""
+def _within(counts):
+    """0..c-1 for each c of ``counts``, concatenated."""
+    counts = np.asarray(counts, np.int64)
+    ends = np.cumsum(counts)
+    return np.arange(int(ends[-1]) if len(ends) else 0) - np.repeat(
+        ends - counts, counts)
 
-    def one_query(idx, valid, imd):
-        s = jnp.where(valid, score[idx], -jnp.inf)
-        lab = jnp.where(valid, labels[idx], -1)
-        q = idx.shape[0]
-        # rank positions by score desc (stable)
-        order = jnp.argsort(-s, stable=True)
-        rank_of = jnp.zeros(q, jnp.int32).at[order].set(
-            jnp.arange(q, dtype=jnp.int32))
-        discount = 1.0 / jnp.log2(rank_of.astype(jnp.float32) + 2.0)
-        valid_f = valid
-        best = jnp.max(jnp.where(valid_f, s, -jnp.inf))
-        worst = jnp.min(jnp.where(valid_f, s, jnp.inf))
-        norm_on = best != worst
 
-        gain = label_gain[jnp.clip(lab, 0)]
-        # pair (i, j): i=high (larger label), j=low
-        hi_l = lab[:, None]
-        lo_l = lab[None, :]
-        pair_ok = (hi_l > lo_l) & valid_f[:, None] & valid_f[None, :]
-        ds = s[:, None] - s[None, :]
-        dcg_gap = gain[:, None] - gain[None, :]
-        paired_disc = jnp.abs(discount[:, None] - discount[None, :])
-        delta = dcg_gap * paired_disc * imd
-        delta = jnp.where(norm_on, delta / (0.01 + jnp.abs(ds)), delta)
-        p_lambda = 2.0 / (1.0 + jnp.exp(2.0 * ds * sigmoid))
-        p_hess = p_lambda * (2.0 - p_lambda)
-        p_lambda = jnp.where(pair_ok, -p_lambda * delta, 0.0)
-        p_hess = jnp.where(pair_ok, 2.0 * p_hess * delta, 0.0)
-        lam = jnp.sum(p_lambda, axis=1) - jnp.sum(p_lambda, axis=0)
-        hes = jnp.sum(p_hess, axis=1) + jnp.sum(p_hess, axis=0)
-        return lam, hes
+def _lambdarank_query(score, label_gain, sigmoid, start, imd, lab):
+    """One query's lambdas and hessians [w] (rank_objective.hpp:81-166);
+    ``lab`` [w] is -1 beyond the query's documents."""
+    w = lab.shape[0]
+    pos = jnp.arange(w, dtype=jnp.int32)
+    valid = lab >= 0
+    idx = jnp.minimum(start + pos, score.shape[0] - 1)
+    s = jnp.where(valid, score[idx], -jnp.inf)
+    # rank under the stable descending order: the documents that beat
+    # this one (a higher score, or the same score earlier in the query)
+    beats = (s[:, None] > s[None, :]) | ((s[:, None] == s[None, :])
+                                         & (pos[:, None] < pos[None, :]))
+    rank_of = jnp.sum(beats & valid[:, None], axis=0, dtype=jnp.int32)
+    discount = 1.0 / jnp.log2(rank_of.astype(jnp.float32) + 2.0)
+    best = jnp.max(jnp.where(valid, s, -jnp.inf))
+    worst = jnp.min(jnp.where(valid, s, jnp.inf))
+    norm_on = best != worst
 
-    lam_q, hes_q = jax.vmap(one_query)(q_idx, q_valid, inv_max_dcg)
-    n = score.shape[0]
-    flat_idx = q_idx.reshape(-1)
-    flat_valid = q_valid.reshape(-1)
-    lam = jnp.zeros(n, score.dtype).at[flat_idx].add(
-        jnp.where(flat_valid, lam_q.reshape(-1), 0.0))
-    hes = jnp.zeros(n, score.dtype).at[flat_idx].add(
-        jnp.where(flat_valid, hes_q.reshape(-1), 0.0))
+    gain = label_gain[jnp.clip(lab, 0)]
+    # pair (i, j): i=high (larger label), j=low
+    hi_l = lab[:, None]
+    lo_l = lab[None, :]
+    pair_ok = (hi_l > lo_l) & valid[:, None] & valid[None, :]
+    ds = s[:, None] - s[None, :]
+    dcg_gap = gain[:, None] - gain[None, :]
+    paired_disc = jnp.abs(discount[:, None] - discount[None, :])
+    delta = dcg_gap * paired_disc * imd
+    delta = jnp.where(norm_on, delta / (0.01 + jnp.abs(ds)), delta)
+    p_lambda = 2.0 / (1.0 + jnp.exp(2.0 * ds * sigmoid))
+    p_hess = p_lambda * (2.0 - p_lambda)
+    p_lambda = jnp.where(pair_ok, -p_lambda * delta, 0.0)
+    p_hess = jnp.where(pair_ok, 2.0 * p_hess * delta, 0.0)
+    lam = jnp.sum(p_lambda, axis=1) - jnp.sum(p_lambda, axis=0)
+    hes = jnp.sum(p_hess, axis=1) + jnp.sum(p_hess, axis=0)
     return lam, hes
+
+
+def _lambdarank_grads(score, classes, src, label_gain, sigmoid):
+    """The pair block of every width class, a block of queries at a time
+    where a class's ``[nq_c, w, w]`` would pass ``PAIR_BLOCK_BYTES``, then
+    each row's sums by a gather from (class, query, position)."""
+    flat_l = [jnp.zeros(1, score.dtype)]
+    flat_h = [jnp.zeros(1, score.dtype)]
+    for c in classes:
+        lab = jnp.asarray(c["lab"])
+        nq, w = lab.shape
+        args = (jnp.asarray(c["start"]), jnp.asarray(c["imd"]), lab)
+
+        def one(a):
+            return _lambdarank_query(score, label_gain, sigmoid, *a)
+        block = max(1, PAIR_BLOCK_BYTES // (4 * w * w))
+        if nq <= block:
+            lam, hes = jax.vmap(one)(args)
+        else:
+            lam, hes = jax.lax.map(one, args, batch_size=block)
+        flat_l.append(lam.reshape(-1))
+        flat_h.append(hes.reshape(-1))
+    return (jnp.concatenate(flat_l)[src], jnp.concatenate(flat_h)[src])
 
 
 # --------------------------------------------------------------------------

@@ -200,10 +200,12 @@ def bucket_entries(e: int, policy: Optional[int] = None) -> int:
 
 def aux_signature(aux) -> tuple:
     """Hashable structure+shape+dtype fingerprint of an aux pytree
-    (nested dicts of arrays / None) — part of the geometry key, so two
-    boosters only share a step when their traced aux trees match."""
+    (nested dicts and tuples of arrays / None) — part of the geometry key,
+    so two boosters only share a step when their traced aux trees match."""
     if aux is None:
         return ("none",)
+    if isinstance(aux, (tuple, list)):
+        return tuple(aux_signature(a) for a in aux)
     if isinstance(aux, dict):
         return tuple((k, aux_signature(aux[k])) for k in sorted(aux))
     return (tuple(getattr(aux, "shape", ())),

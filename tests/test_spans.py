@@ -549,3 +549,29 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
     # not the value of the grower before
     _booster().train_one_iter()
     assert reg.snapshot()["gauges"]["hist/root_macs"] == 0.0
+
+
+def test_ranking_objective_sets_its_gauges_and_names_its_scope():
+    """``rank/queries``, ``rank/qmax``, ``rank/width_classes``,
+    ``rank/pairs_real`` (sum of n_q^2) and ``rank/pair_slots`` (slots the
+    layout evaluates an iteration) are set where the objective builds its
+    query tables; ``benchmark/readers/rank.pair_slots_ratio.py`` reads the
+    last two. The pair block runs inside ``lgbm/gradients/rank_pairs``."""
+    from conftest import fit_gbdt
+    rng = np.random.default_rng(9)
+    counts = np.asarray([1, 3, 8, 9, 20, 33, 70, 5, 2, 12] * 8)
+    n = int(counts.sum())
+    X = rng.normal(size=(n, 5))
+    y = rng.integers(0, 4, n).astype(np.float32)
+    g = fit_gbdt(X, y, {"objective": "lambdarank"}, num_round=1,
+                 group=counts)
+    gauges = obs.default_registry().snapshot()["gauges"]
+    classes = g.objective._pair_classes
+    assert gauges["rank/queries"] == len(counts)
+    assert gauges["rank/qmax"] == 70
+    assert gauges["rank/width_classes"] == len(classes) == 5
+    assert gauges["rank/pairs_real"] == float(np.sum(counts ** 2))
+    assert gauges["rank/pair_slots"] == sum(
+        c["lab"].shape[0] * c["lab"].shape[1] ** 2 for c in classes)
+    assert "lgbm/gradients/rank_pairs/" in \
+        g.lower_step().as_text(debug_info=True)

@@ -394,9 +394,13 @@ def test_forest_kernel_compiles_at_the_tiles_the_guard_picks(
     assert _mosaic(lowered.compile()) == 1
 
 
-def _step_under_test(monkeypatch, tier, mesh=None):
+def _step_under_test(monkeypatch, tier, mesh=None, *,
+                     shape=(1 << 17, 1 << 14, 32, 64), chunk=4096,
+                     objective=None):
     """(jitted step, grower config pieces) of ops/step_cache.py
-    build_train_step at HIGGS widths. The grower factory asks
+    build_train_step at HIGGS widths (``shape`` = train rows, valid rows,
+    features, bins; ``objective(N)`` an initialised objective in the
+    binary one's place). The grower factory asks
     utils/device which backend it is on (it would take its interpret
     branch on this CPU host), so the test steers that — here, not
     through an option of the program."""
@@ -411,7 +415,7 @@ def _step_under_test(monkeypatch, tier, mesh=None):
     from lightgbm_tpu.utils import device
     monkeypatch.setattr(device, "on_tpu", lambda: True)
 
-    N, nvalid, F, B, L = 1 << 17, 1 << 14, 32, 64, 255
+    (N, nvalid, F, B), L = shape, 255
     if tier == "proxy":
         gcfg = WaveGrowerConfig(
             num_leaves=L, num_bins=B, wave_size=64, chunk=4096,
@@ -421,7 +425,7 @@ def _step_under_test(monkeypatch, tier, mesh=None):
     else:
         gcfg = WaveGrowerConfig(
             num_leaves=L, num_bins=B,
-            wave_size=autotune.EXACT_TIER_CAPS[tier], chunk=4096,
+            wave_size=autotune.EXACT_TIER_CAPS[tier], chunk=chunk,
             precision="highest", exact_variant=tier,
             route="pallas-tpu", hp=SplitParams(has_cat=False))
     meta = FeatureMeta(
@@ -436,12 +440,15 @@ def _step_under_test(monkeypatch, tier, mesh=None):
     assert grower.resolved == {"route": "pallas-tpu",
                                "fused_pallas": True, "fused_xla": False,
                                "interpret": False}
-    obj = create_objective("binary", Config().set(
-        {"objective": "binary"}))
-    obj.init(Metadata(label=(np.arange(N) % 2).astype(np.float32)), N)
+    if objective is None:
+        obj = create_objective("binary", Config().set(
+            {"objective": "binary"}))
+        obj.init(Metadata(label=(np.arange(N) % 2).astype(np.float32)), N)
+    else:
+        obj = objective(N)
     step = step_cache.build_train_step(
         grower=grower, K=1, n_score=N, n_total=N + nvalid,
-        valid_slices=((N, nvalid),), num_leaves=L,
+        valid_slices=((N, nvalid),) if nvalid else (), num_leaves=L,
         grad_fn=obj.gradient_builder(), renew_alpha=None,
         sample_hook=None, mesh=mesh, row_sharded=mesh is not None)
     return step, (N, nvalid, F), meta, obj.gradient_aux()
@@ -460,7 +467,7 @@ def _step_args(spec_rows, spec_rep, dims, meta, obj_aux):
 
     return (spec_rows((F, N + nvalid), jnp.uint8),
             spec_rows((1, N), jnp.float32),
-            (spec_rows((1, nvalid), jnp.float32),),
+            (spec_rows((1, nvalid), jnp.float32),) if nvalid else (),
             spec_rows((N + nvalid,), jnp.float32),
             spec_rep((F,), jnp.bool_), spec_rep((), jnp.float32),
             spec_rep((1,), jnp.float32), spec_rep((1, 1), jnp.float32),
@@ -482,6 +489,45 @@ def test_whole_training_step_compiles(spec, monkeypatch, tier):
                                       aux)).compile()
     # root wave kernel + fused kernel + the train/valid leaf gathers
     assert _mosaic(compiled) == 4
+
+
+def test_ranking_step_compiles_at_the_cells_shape(spec, monkeypatch):
+    """``yahoo_ltr.train_rank``'s whole step for the described chip: the
+    lambdarank pair gradient over the cell's own query tables (458,752 rows
+    in ~19,350 queries of 1..139 documents by the benchmark's generator: six
+    width classes) ahead of the grower at 704 padded columns (11 feature
+    tiles), 256 bins, 255 leaves, chunk 16384. The gradient lowers to
+    gathers and reductions: no Mosaic kernel of its own."""
+    import sys
+    from pathlib import Path
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata
+    from lightgbm_tpu.objectives import create_objective
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent
+                           / "benchmark"))
+    import datagen_rank
+    rows = 458_752
+    data = {"rows": rows, "query_len_min": 1, "query_len_max": 139,
+            "query_len_mean": 23.7, "query_len_sigma": 0.9}
+
+    def objective(n):
+        lengths = datagen_rank.query_lengths(data, seed=5)
+        obj = create_objective("lambdarank",
+                               Config().set({"objective": "lambdarank"}))
+        obj.init(Metadata(label=(np.arange(n) % 5).astype(np.float32),
+                          group=lengths), n)
+        assert [c["lab"].shape[1] for c in obj._pair_classes] \
+            == [8, 16, 32, 64, 128, 139]
+        return obj
+    step, dims, meta, aux = _step_under_test(
+        monkeypatch, "hilo4", shape=(rows, 0, 704, 256), chunk=16384,
+        objective=objective)
+    compiled = step.lower(*_step_args(spec, spec, dims, meta,
+                                      aux)).compile()
+    # the root kernel + the fused kernel + the train rows' leaf gather
+    assert _mosaic(compiled) == 3
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 3 * 2 ** 30
 
 
 @pytest.mark.parametrize("tier", ["proxy", "hilo4"])
