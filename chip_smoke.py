@@ -377,6 +377,8 @@ def phase_compare(tier_report: dict, sz: Sizes, seed: int,
     if not int8:
         _root_kernel_checks(d, r, tier=tier, trained=variant, B=B,
                             chunk=small_chunk, interp=interp)
+        _wave_split_checks(d, r, tier=tier, trained=variant, B=B,
+                           chunk=small_chunk, interp=interp)
 
     # one tree through the whole grower at the trained wave width:
     # Pallas route vs the two-pass XLA route. Depth is held to 4 on a
@@ -492,6 +494,50 @@ def _root_kernel_checks(d, r, *, tier, trained, B, chunk, interp) -> None:
     check(np.array_equal(root, slot0),
           f"compare[{tier}]: root kernel asked 32768 rows a step equals "
           f"the wave kernel's slot 0 at 16384 bit for bit")
+
+
+def _wave_split_checks(d, r, *, tier, trained, B, chunk, interp) -> None:
+    """The fused kernel's flush by slot (the staged rows in slot order,
+    each 128-row block dotted against the slots it holds by the root
+    kernel's two digits; the compacting bf16 tiers) against its one-hot
+    dot over the same staged rows, on the device's own bf16 operands:
+    the same partition, counts bit-equal, sums to 1e-4 of the largest
+    bin (the same products, added in another order), and at least one
+    (block, slot) pair a block; under the four layouts, at the smoke's
+    64 bins (eight features a dot) and at 255 (four), one resident
+    block and two feature tiles of 32 rows."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.hist_wave import fused_partition_histogram_pallas
+    F, N = d["bins"].shape
+    W = min(int(d["tbl"].shape[1]), 24)       # every layout's cap
+    tbl = d["tbl"][:, :W]
+    wide = jnp.asarray(r.integers(0, 255, (2 * F, N)).astype(np.uint8))
+    layouts = {"hilo5": dict(precision="highest", variant="hilo5"),
+               "hilo4": dict(precision="highest", variant="hilo4"),
+               "hilo3": dict(precision="highest", variant="hilo3"),
+               "bf16": dict(precision="default")}
+    for layout, kw in layouts.items():
+        # hilo3's gate: the hessian IS the bag mask
+        h = d["mask"] if layout == "hilo3" else d["h"]
+        for bins, nb, tile in ((d["bins"], B, None), (wide, 255, None),
+                               (wide, 255, 32)):
+            by_slot, one_hot = (fused_partition_histogram_pallas(
+                bins, d["g"], h, d["mask"], d["leaf"], tbl, num_bins=nb,
+                chunk=chunk, interpret=interp, any_cat=False, compact=True,
+                feature_tile=tile, split=split, **kw)
+                for split in (True, False))
+            what = (f"compare[{tier}]: flush by slot [W={W}, "
+                    f"F={bins.shape[0]}, B={nb}] ({layout}"
+                    f"{', trained' if layout == trained else ''}"
+                    f"{', two feature tiles' if tile else ''})")
+            work = np.asarray(by_slot[2])
+            check(np.array_equal(np.asarray(by_slot[0]),
+                                 np.asarray(one_hot[0]))
+                  and 0 < work[1] <= work[2] < work[1] + 16 * W,
+                  what + f": the one-hot dot's partition; {work[2]} "
+                  f"pairs over {work[1]} blocks")
+            _hist_close(np.asarray(by_slot[1]), np.asarray(one_hot[1]),
+                        False, what)
 
 
 def _hist_close(got, ref, int8: bool, what: str) -> None:

@@ -56,8 +56,8 @@ K_MODEL_VERSION = "v2"     # gbdt.h kModelVersion
 
 @jax.jit
 def _tail_summary(num_leaves, wave_work):
-    """[R, 3] int32 rows (num_leaves, wave_work) of R records' scalars
-    and [2] vectors, stacked on the device for one download."""
+    """[R, 4] int32 rows (num_leaves, wave_work) of R records' scalars
+    and [3] vectors, stacked on the device for one download."""
     return jnp.concatenate([jnp.stack(num_leaves)[:, None],
                             jnp.stack(wave_work)], axis=1)
 
@@ -295,7 +295,7 @@ class GBDT:
         # not an interval into training: it always stacks an interval's
         # records, a shorter tail padded (_tail_host; a mesh's replicated
         # records compile their own at the first check)
-        self._tail_pad = (jnp.zeros((), jnp.int32), jnp.zeros(2, jnp.int32))
+        self._tail_pad = (jnp.zeros((), jnp.int32), jnp.zeros(3, jnp.int32))
         self._tail_host([])
         # fused-step state (see _get_step_fn)
         self._step_key = None
@@ -1837,7 +1837,7 @@ class GBDT:
         return [(int(w) + K) * per_pass for w in waves]
 
     def _tail_host(self, records):
-        """(num_leaves [R], wave_work [R, 2]) of a list of records in
+        """(num_leaves [R], wave_work [R, 3]) of a list of records in
         ONE transfer: what the stop check reads."""
         R = self._stop_check_interval * self.num_tree_per_iteration
         parts = []
@@ -1897,10 +1897,11 @@ class GBDT:
         # the verified trees' wave-pass work rode the same download
         from ..obs import registry as obs
         from ..ops.hist_wave import COMPACT_TILE_UNIT
-        rows = work[:n_clean * K].astype(np.int64).sum(axis=0) \
-            * COMPACT_TILE_UNIT
+        work = work[:n_clean * K].astype(np.int64).sum(axis=0)
+        rows = work * COMPACT_TILE_UNIT
         obs.counter("hist/rows_scanned").add(int(rows[0]))
         obs.counter("hist/rows_dotted").add(int(rows[1]))
+        obs.counter("hist/blocks_dotted").add(int(work[2]))
         obs.counter("hist/trees_counted").add(n_clean * K)
         return first
 

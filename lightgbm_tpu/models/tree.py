@@ -524,7 +524,7 @@ def record_arrays_from_tree(tree: Tree, real_to_inner: dict, mappers,
         "internal_count": np.zeros(s, np.float32),
         "split_is_cat": np.zeros(s, bool),
         "split_cat_words": np.zeros((s, 8), np.int32),
-        "wave_work": np.zeros(2, np.int32),
+        "wave_work": np.zeros(3, np.int32),
     }
     for i in range(nl - 1):
         c = tree.left_child[i]

@@ -123,7 +123,9 @@ def hist_feature_tile(*, F: int, B: int, W: int, chunk: int, fused: bool,
                       F_rows: Optional[int] = None, bins_bytes: int = 1,
                       int8: bool = False, count_proxy: bool = False,
                       variant: Optional[str] = None,
-                      force: Optional[int] = None) -> int:
+                      force: Optional[int] = None,
+                      split: Optional[bool] = None,
+                      stage_rows: Optional[int] = None) -> int:
     """Stored bin rows of one feature tile of a histogram kernel at this
     row chunk: ALL of them (one tile, the kernel as it was before the
     tile axis) whenever the whole working set is inside the VMEM
@@ -132,12 +134,14 @@ def hist_feature_tile(*, F: int, B: int, W: int, chunk: int, fused: bool,
     compaction payload are, under HIST_TILE_MAX_GROUPS; 0 where not
     even the narrowest is (the chunk is then no candidate). A
     trace-time choice from the shapes, no knob; ``force`` (rows, for
-    tests and measurements) overrides the pricing only."""
+    tests and measurements) overrides the pricing only; ``split`` and
+    ``stage_rows`` are hist_vmem_bytes' (the fused kernel's alone)."""
     F_rows = F if F_rows is None else F_rows
     if force is not None:
         return min(int(force), F_rows)
     kw = dict(chunk=chunk, W=W, fused=fused, bins_bytes=bins_bytes,
-              int8=int8, count_proxy=count_proxy, variant=variant)
+              int8=int8, count_proxy=count_proxy, variant=variant,
+              split=split, stage_rows=stage_rows)
     whole = hist_geometry(F=F, B=B, W=W, F_rows=F_rows)
     if fits_vmem(hist_vmem_bytes(geom=whole, **kw)):
         return F_rows
@@ -231,7 +235,11 @@ def root_pass_macs(*, B: int, nchan: int, split: bool) -> int:
     """MACs the root pass's dot spends on one row of one feature: the
     root kernel's ``nchan x L`` streamed rows against 128 lanes where
     its digit split serves the root (``split``), the wave kernel's
-    ``Bp`` one-hot rows against 128 lanes where it does."""
+    ``Bp`` one-hot rows against 128 lanes where it does. The same two
+    numbers are what ONE block-dot of the fused kernel's flush spends:
+    by the digit split a row meets as many block-dots as its 128-row
+    block holds slots, by the one-hot dot against every slot's lanes
+    exactly one (gauge hist/wave_macs, ops/wave_grower.py)."""
     return 128 * (nchan << ROOT_SPLIT_LO_BITS if split else _round_up(B, 8))
 
 
@@ -359,7 +367,12 @@ HIST_COMPACT_TILE = 512
 # read in the same call (no categorical sweep; PERF.md section 6, PR 31,
 # call A); the narrow shapes were not timed again, so the threshold
 # stands where the old scan put it: re-derive it from a masked-against-
-# compacted timing at 28 x 63 before moving it.
+# compacted timing at 28 x 63 before moving it. Since PR 35 a compacted
+# row at 57 to 256 bins of a bf16 tier is dotted a slot at a time
+# (wave_split_applies) for a quarter to two thirds of the one-hot dot's
+# time (6.1 to 15.0 ns against 23.2 at 67 x 255), so the break-even now
+# stands on a dearer scan-to-dot ratio than the one above: the same
+# re-derivation is owed, and the constant was NOT re-timed or moved.
 HIST_COMPACT_MIN_MACS = 1 << 19
 # rows of the compaction's payload block ahead of the bin rows (one
 # packed bf16 sublane tile; ops/hist_wave.py _PAY_ROWS)
@@ -384,13 +397,80 @@ def hist_compact_tile(*, geom: Dict[str, int], chunk: int,
     return T if force else 0
 
 
+# The wave passes' dot by the root's two-digit split (ops/hist_wave.py
+# _flush_by_slot): the compacted rows are staged this many at a time,
+# put in slot order by one exact gather and dotted a 128-row block at a
+# time against the slots the block holds. The ordering costs
+# C x WAVE_SPLIT_STAGE_ROWS MACs a dotted row and the (block, slot)
+# pairs number at most STAGE_ROWS / 128 + live slots - 1, so a wider
+# stage buys fewer pairs a row with a dearer ordering. Measured on a
+# v5e, the kernel alone, 67 x 255, hilo5, chunk 16384, a quarter of
+# 2,097,152 rows contributing (PERF.md section 5, PR 35, call E; ns a
+# dotted row at 1 / 8 / 24 live slots): 1,024 rows 6.13 / 11.63 /
+# 21.39, 2,048 6.13 / 10.14 / 15.07, 4,096 5.62 / 11.06 / 13.61 (10% at
+# 24 slots, 9% lost at 8, and 15 MB more VMEM priced, which the largest
+# offered chunk at 67 features does not have).
+WAVE_SPLIT_STAGE_ROWS = 2048
+# columns of the ordered tile one gather of the ordering fills (its
+# [cols, STAGE_ROWS] one-hot is built a column block at a time)
+WAVE_SPLIT_ORDER_COLS = 512
+# rows of one block of the ordered tile: the contraction of one dot
+# (ops/hist_wave.py COMPACT_TILE_UNIT, the unit its counts are in)
+WAVE_SPLIT_BLOCK = 128
+# blocks whose builds and first slots' dots stand in one straight line
+# (ops/hist_wave.py _flush_by_slot): one [160, 128] x [128, 128] dot is
+# one weight load, and a region of 17 of them between two scalar reads
+# held 47% of the MXU's peak where 4 x 17 hold 73%. The same call, ns a
+# dotted row at 1 / 24 live slots: 2 blocks 6.26 / 15.29, 4 blocks
+# 6.09 / 14.95, 8 blocks 5.63 / 15.11 (twice the code for 0.5 ns at one
+# slot and nothing at 24: PERF.md section 5, PR 35, call B). A tile of
+# more groups unrolls fewer blocks (wave_split_unroll): what is bought
+# is dots in a line, and 140 unrolled group bodies cost Mosaic and
+# XLA:CPU minutes
+WAVE_SPLIT_UNROLL = 4
+
+
+def wave_split_unroll(groups: int) -> int:
+    """Blocks of the ordered tile whose builds and first slots' dots
+    stand in one straight line, for a tile of ``groups`` feature
+    groups: WAVE_SPLIT_UNROLL up to 18 groups (the 67 features of the
+    one-tile cell, the 16 groups of a 64-feature tile), the power of
+    two that keeps the line at about as many dots beyond."""
+    u = WAVE_SPLIT_UNROLL
+    while u > 1 and u * groups > 18 * WAVE_SPLIT_UNROLL:
+        u //= 2
+    return u
+
+
+def wave_split_applies(*, B: int, precision: str, compact_tile: int,
+                       count_proxy: bool = False,
+                       packed4: bool = False) -> bool:
+    """Whether the fused kernel's flush dots its staged rows by the
+    root's two-digit split, a slot at a time, in place of the one-hot
+    dot against every slot's lanes: the root's rule
+    (root_split_applies: the bf16 tiers, byte bins, 57 to 256 of them)
+    where the kernel compacts (hist_compact_tile), since only compacted
+    rows can be put in slot order. A trace-time choice from the shapes
+    and the tier, no knob."""
+    return compact_tile > 0 and root_split_applies(
+        B=B, precision=precision, count_proxy=count_proxy, packed4=packed4)
+
+
 def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
                             tbl_rows: int, compact_tile: int = 0,
-                            tiled: bool = False) -> Dict[str, tuple]:
+                            tiled: bool = False,
+                            split: Optional[Dict[str, int]] = None,
+                            W: int = 0, stage_rows: int = 0
+                            ) -> Dict[str, tuple]:
     """VMEM block shapes of fused_partition_histogram_pallas; with
     ``compact_tile`` also its compaction scratch, and ``tiled`` (``geom``
     is then one feature tile's) the block of the wave's split columns,
-    which lie in other tiles."""
+    which lie in other tiles. ``split`` (fused_wave_split, with the
+    wave's ``W`` slots and the ``stage_rows`` staged at a time): the
+    accumulators hold a slot's histograms by channel and digit, three
+    planes of them leave the kernel, the staging buffer is
+    ``stage_rows`` wide, and the flush keeps the ordered tile and a few
+    blocks' keys and selected weight rows beside it."""
     s = {
         "tbl": (128, tbl_rows),                           # i32 const
         "bins": (geom["F_rows"], chunk),                  # grid-indexed
@@ -407,9 +487,53 @@ def fused_hist_block_shapes(*, chunk: int, geom: Dict[str, int],
             "sel": (1, chunk),               # f32 0/1 contributes
             "staged": (c, compact_tile),     # f32, across grid steps
         })
+    if split:
+        c = s["staged"][0]
+        s.update({
+            "hist": (W, split["groups"], 3 * split["L"], 128),
+            "acc": (W, split["groups"], split["nl"], 128),   # f32
+            "staged": (c, stage_rows),       # f32, across grid steps
+            "ord": (c, stage_rows),          # f32, the tile by slot
+            "key": (wave_split_unroll(split["groups"]),
+                    c - HIST_COMPACT_PAY_ROWS,
+                    WAVE_SPLIT_BLOCK),       # i32, lane digit by slot
+            "p": (wave_split_unroll(split["groups"]), split["groups"],
+                  split["R"], WAVE_SPLIT_BLOCK),
+        })
     if tiled:
         s["cols"] = (_round_up(geom["wp"], HIST_TILE_ROW_ALIGN), chunk)
     return s
+
+
+def fused_wave_split(*, geom: Dict[str, int], compact_tile: int,
+                     int8: bool = False, count_proxy: bool = False,
+                     variant: Optional[str] = None,
+                     force: Optional[bool] = None
+                     ) -> Optional[Dict[str, int]]:
+    """Geometry of the digit split's dot in a fused kernel call whose
+    flush takes it, else None: wave_split_applies asked of what the
+    kernel's wrapper and the VMEM pricing both hold (4-bit packed bins
+    show as more features than stored rows; of the bf16 tiers the
+    exact one has a ``variant``). The root kernel's geometry over the
+    tile's stored bin rows (root_hist_geometry: L, H, gf, R) with the
+    fused kernel's channel multiplicands for ``nchan`` (ops/hist_wave.py
+    _channel_rows, the count among them in every layout: "hilo4" rides
+    the five rows of "hilo5", one slot's dot has lanes to spare), and
+    ``nl`` = nchan x L, the rows of a (slot, group) accumulator once
+    the products of two different features' digits are dropped.
+    ``force`` = False keeps the one-hot dot, for tests and for the
+    measurement beside WAVE_SPLIT_STAGE_ROWS; nothing forces the split
+    on what it cannot serve."""
+    if force is False or not wave_split_applies(
+            B=geom["Bp"], precision="int8" if int8 else "highest",
+            compact_tile=compact_tile, count_proxy=count_proxy,
+            packed4=geom["F"] != geom["F_rows"]):
+        return None
+    g = root_hist_geometry(
+        F=geom["F_rows"], B=geom["Bp"],
+        nchan={"hilo5": 5, "hilo4": 5, "hilo3": 3, None: 4}[variant])
+    g["nl"] = g["nchan"] * g["L"]
+    return g
 
 
 def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
@@ -417,7 +541,8 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
                     count_proxy: bool = False,
                     tbl_rows: Optional[int] = None,
                     variant: Optional[str] = None,
-                    tiled: bool = False) -> int:
+                    tiled: bool = False, split: Optional[bool] = None,
+                    stage_rows: Optional[int] = None) -> int:
     """Working-set bytes of one grid step of a wave-histogram kernel,
     priced from the SAME block shapes the BlockSpecs use: grid-indexed
     blocks double-buffered, plus the in-kernel temporaries (the
@@ -433,6 +558,14 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
     [C, T] result) are added. ``tiled``: ``geom``
     is one feature tile's (hist_feature_tile), and the fused kernel
     reads the wave's split columns as one more double-buffered block.
+    Where the fused kernel's flush dots by the digit split
+    (fused_wave_split; ``split`` / ``stage_rows`` override the rule and
+    WAVE_SPLIT_STAGE_ROWS as the kernel's wrapper lets a test) the
+    one-hot tile, the weight matrix and the hilo4 count accumulator
+    give way to the flush's own: the ordered tile and its digits, one
+    column block of the ordering's one-hot with its compare, the
+    membership rows, one block's selected weight rows and a group's
+    operands and product.
     """
     oh_bytes = 1 if int8 else 2                  # int8 / bf16 one-hot
     acc_bytes = 4                                # i32 / f32 accumulator
@@ -445,9 +578,14 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
             tbl_rows = TBL_ROWS
         T = hist_compact_tile(geom=geom, chunk=chunk,
                               bins_bytes=bins_bytes, int8=int8)
+        sg = fused_wave_split(geom=geom, compact_tile=T, int8=int8,
+                              count_proxy=count_proxy, variant=variant,
+                              force=split)
+        T2 = stage_rows or WAVE_SPLIT_STAGE_ROWS
         s = fused_hist_block_shapes(chunk=chunk, geom=geom,
                                     tbl_rows=tbl_rows, compact_tile=T,
-                                    tiled=tiled)
+                                    tiled=tiled, split=sg, W=W,
+                                    stage_rows=T2)
         b = (2 * _nelem(s["bins"]) * bins_bytes
              + (2 * _nelem(s["cols"]) * bins_bytes if tiled else 0)
              + 2 * _nelem(s["ghm"]) * 4
@@ -469,6 +607,18 @@ def hist_vmem_bytes(*, chunk: int, geom: Dict[str, int], W: int,
                   + 128 * 128 * 6                      # rank triangle
                   + 2 * T * T * 6                      # [T, T] one-hots
                   + _nelem(s["staged"]) * 4)           # gathered result
+        if sg:
+            oc = min(WAVE_SPLIT_ORDER_COLS, T2)
+            return b + (
+                _nelem(s["ord"]) * 4 + 3 * _nelem(s["key"]) * 4
+                + _nelem(s["acc"]) * 4
+                + _nelem(s["staged"]) * 2    # the staged rows in bf16
+                + oc * T2 * 6                # the ordering's one-hot
+                + 4 * W * T2 * 4             # membership, ranks
+                + _nelem(s["p"]) * 2
+                + sg["R"] * WAVE_SPLIT_BLOCK * 6      # a group's rows
+                + 2 * WAVE_SPLIT_BLOCK * WAVE_SPLIT_BLOCK * 6
+                + 2 * sg["R"] * 128 * 4)     # its product, by digit
     else:
         s = wave_hist_block_shapes(chunk=chunk, geom=geom)
         b = (2 * _nelem(s["bins"]) * bins_bytes
@@ -930,12 +1080,21 @@ def tune_hist_chunk(*, fused: bool, F: int, B: int, W: int,
 EXACT_TIER_CAPS = {"hilo5": 24, "hilo4": 32, "hilo3": 40}
 
 
-def exact_tier_candidates(*, constant_hessian: bool) -> List[dict]:
+def exact_tier_candidates(*, constant_hessian: bool,
+                          by_slot: bool = False) -> List[dict]:
     """Feasible exact-tier layouts, widest wave first. ``hilo3`` (the
     fused hess/count plane) is only sound when the hessian plane is
     identically the sample mask — constant-unit-hessian objectives
-    without row weights (models/gbdt.py gates this)."""
-    out = [{"variant": "hilo4"}, {"variant": "hilo5"}]
+    without row weights (models/gbdt.py gates this). ``by_slot``: the
+    fused kernel's flush dots a slot at a time (wave_split_applies);
+    "hilo4" then rides hilo5's five rows through the same dot (its
+    second, count dot is gone) and differs from it by its wave cap
+    alone, and how many leaves a wave splits is the grower's (passes a
+    tree, the order the leaves are split in), not a layout's to move:
+    it is not timed against hilo5 there (tpu_exact_tier=hilo4 still
+    asks for it; ROADMAP S1(f): the wave's width under the split)."""
+    out = [{"variant": "hilo5"}] if by_slot \
+        else [{"variant": "hilo4"}, {"variant": "hilo5"}]
     if constant_hessian:
         out.insert(0, {"variant": "hilo3"})
     return out
@@ -977,6 +1136,17 @@ def tune_exact_tier(*, F: int, B: int, n_rows: int = 0,
     if not on_tpu() and _measure is None:
         # the analytic arm (see docstring)
         return cands[0]["variant"]
+    # the timed arm knows the kernel it times: where its flush dots by
+    # slot, hilo4 is no layout of its own
+    geom = hist_geometry(F=F, B=B, W=EXACT_TIER_CAPS["hilo5"])
+    cands = exact_tier_candidates(
+        constant_hessian=constant_hessian,
+        by_slot=wave_split_applies(
+            B=B, precision="highest", compact_tile=hist_compact_tile(
+                geom=geom, chunk=DEFAULT_HIST_CHUNK,
+                bins_bytes=bins_bytes)))
+    if len(cands) == 1:
+        return str(cands[0]["variant"])
     key = {"F": F, "B": B, "cat": bool(any_cat),
            "bins_bytes": bins_bytes, "device": device_kind(),
            "variants": [c["variant"] for c in cands]}

@@ -59,9 +59,11 @@ class TreeRecord(NamedTuple):
     internal_count: jax.Array      # [L-1]
     split_is_cat: jax.Array        # [L-1] bool categorical split flag
     split_cat_words: jax.Array     # [L-1, 8] int32 left-set bin bitset
-    # [2] int32: rows the fused TPU kernel's wave passes scanned, and
-    # rows they put through the one-hot dot, for this tree, both in
-    # units of hist_wave.COMPACT_TILE_UNIT rows (zeros on every other
+    # [3] int32: rows the fused TPU kernel's wave passes scanned, rows
+    # they put through the dot, both in units of
+    # hist_wave.COMPACT_TILE_UNIT rows, and the block-dots those rows
+    # met (the (block, slot) pairs where the flush dots a slot at a
+    # time, one a unit else), for this tree (zeros on every other
     # route). Not part of the model: pack_record leaves it out; the
     # stop check reads it beside num_leaves (models/gbdt.py).
     wave_work: jax.Array
@@ -296,7 +298,7 @@ def make_tree_grower(cfg: GrowerConfig, meta: FeatureMeta,
                 internal_count=jnp.zeros(L - 1, f32),
                 split_is_cat=jnp.zeros(L - 1, bool),
                 split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
-                wave_work=jnp.zeros(2, jnp.int32),
+                wave_work=jnp.zeros(3, jnp.int32),
             ),
         )
 

@@ -3,8 +3,8 @@
 TPU-native replacement for the reference's per-leaf histogram kernels
 (reference: src/io/dense_bin.hpp:72-130 CPU loops,
 src/treelearner/ocl/histogram256.cl:345 OpenCL device kernels). Two key
-departures from round 1's per-leaf one-hot einsum, and a third for
-the root:
+departures from round 1's per-leaf one-hot einsum, a third for the
+root and a fourth for the wave passes' compacted rows:
 
 1. **Wave batching.** The MXU matmul that accumulates histograms has
    128 output lanes but a single leaf only needs 3 channels
@@ -27,6 +27,15 @@ the root:
    rows: a sixth of the one-hot dot's MACs at 255 bins, the same sums
    bit for bit.
 
+4. **A wave pass's dotted rows pay for the slots beside them.** Of
+   the 120 lanes a compacted row meets in the wave's one-hot dot, the 5
+   of its own slot carry its weights. No dense dot over rows of mixed
+   slots does better (it spends its whole output, W x Bp x channels,
+   on every row), so the fused kernel puts its staged rows in slot
+   order and takes the root's split to each 128-row block against the
+   slots it holds (``_flush_by_slot``): 5,120 to 12,480 MACs a row a
+   feature against 32,768, the same products in another order.
+
 Data layout is **feature-major**: ``bins_t [F, N]`` so that a feature's
 bin row is a contiguous lane vector — the transposed one-hot tile
 ``[group*B, Ct]`` is then built by broadcast compares with no VMEM
@@ -42,6 +51,7 @@ correctness oracle); the Pallas kernel is used on TPU.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -985,7 +995,7 @@ FUSED_MAX_WAVE_INT8_NC = 64  # 2 channels (count-proxy mode: the MXU dot
 def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
                   groups, group_sz, variant, exact_dot=False, int8=False,
                   any_cat=True, count_proxy=False, packed4=False,
-                  compact_tile=0, tiled=False):
+                  compact_tile=0, tiled=False, split=None):
     """One grid step: partition one row chunk by the wave's W splits,
     then accumulate the wave's smaller-child histograms — ONE data pass.
 
@@ -1006,12 +1016,17 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
                out-of-bag included)
     cols_ref:  (``tiled`` only) [Wp, Ct] the bin rows of the wave's W
                split features, one row a slot
-    hist_ref:  [groups, gb_pad, 128] accumulated histograms
+    hist_ref:  [groups, gb_pad, 128] accumulated histograms; with
+               ``split`` [W, groups of gf features, 3 x L, 128], a
+               slot's three planes (g, h, count) by digit, written on a
+               tile's last step from the accumulators (_flush_by_slot)
     leaf_out_ref: [1, Ct] i32 leaf ids AFTER this wave
     tiles_ref: [1] i32 (SMEM) rows put through the one-hot dot, in
-               units of COMPACT_TILE_UNIT
-    rest:      the count accumulator (count_proxy / "hilo4"), then —
-               with ``compact_tile`` — the compaction's scratch
+               units of COMPACT_TILE_UNIT; with ``split`` [2]: the
+               128-row blocks dotted, then the (block, slot) pairs
+    rest:      the count accumulator (count_proxy / "hilo4" without
+               ``split``), then — with ``compact_tile`` — the
+               compaction's scratch, then ``split``'s
 
     Channel layout: the exact tier (tpu_use_dp) rides one of the
     ``variant`` layouts of _wave_hist_kernel — "hilo5"
@@ -1033,6 +1048,14 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     with zero weights where it contributes nothing.
     autotune.hist_compact_tile chooses, from the dot's cost a row.
 
+    ``split`` (autotune.fused_wave_split's geometry, where
+    autotune.wave_split_applies): the staging buffer is
+    WAVE_SPLIT_STAGE_ROWS wide, filled a T-row window at a time by the
+    same gathers, and what is flushed is put in slot order and dotted
+    by the root's two-digit split, each 128-row block against the slots
+    it holds (_flush_by_slot). None: the one-hot dot of _accumulate_hist
+    against every slot's lanes, over a T-row tile.
+
     ``tiled``: the grid is (feature tiles, row chunks) and ``binsf_ref``,
     ``hist_ref``, ``F`` and ``groups`` are ONE tile's. A wave's split
     features lie in any tile, so their bin rows come in ``cols_ref``
@@ -1048,7 +1071,7 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     step = pl.program_id(1 if tiled else 0)
     first_tile = pl.program_id(0) == 0 if tiled else None
     T = compact_tile
-    has_cnt = count_proxy or variant == "hilo4"
+    has_cnt = count_proxy or (variant == "hilo4" and not split)
     cnt_ref = rest[0] if has_cnt else None
     scratch = rest[1:] if has_cnt else rest
 
@@ -1061,13 +1084,16 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
 
     @pl.when(step == 0)
     def _():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        if variant == "hilo4":
+        if not split:             # (the split's accumulators: below)
+            hist_ref[...] = jnp.zeros_like(hist_ref)
+        if variant == "hilo4" and not split:
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
         @once_a_pass
         def _():
             tiles_ref[0] = 0
+            if split:
+                tiles_ref[1] = 0
             if count_proxy:
                 cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
@@ -1209,7 +1235,7 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     acc_kw = dict(F=F, B=B, groups=groups, group_sz=group_sz,
                   variant=variant, exact_dot=exact_dot, int8=int8,
                   packed4=packed4)
-    hist_cnt_ref = cnt_ref if variant == "hilo4" else None
+    hist_cnt_ref = cnt_ref if variant == "hilo4" and not split else None
     if not T:
         # ---- masked full-chunk dot: every row of the chunk goes
         # through the one-hot dot, rows outside the wave's smaller
@@ -1245,7 +1271,11 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     # <= 64) and meets a single 1, so the gather is exact and the
     # histogram differs from the masked dot's only in the order of its
     # f32 additions.
-    x_ref, sel_ref, staged_ref, cnt_smem = scratch
+    x_ref, sel_ref, staged_ref, cnt_smem, *split_refs = scratch
+    # the staging buffer is NW windows of T rows: one, the dotted tile,
+    # without ``split``
+    TS = staged_ref.shape[1]
+    NW = TS // T
     xdt = jnp.float32 if exact_dot else jnp.bfloat16
     dot_prec = (jax.lax.Precision.HIGHEST if exact_dot
                 else jax.lax.Precision.DEFAULT)
@@ -1272,6 +1302,8 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
     def _():
         staged_ref[...] = jnp.zeros_like(staged_ref)
         cnt_smem[0] = 0
+        if split:
+            split_refs[-1][...] = jnp.zeros_like(split_refs[-1])
 
     # U[i, j] = 1 where i < j, one 128-lane block's worth: blk . U =
     # each row's rank among the block's selected rows before it (0/1
@@ -1303,7 +1335,26 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
             before = before + jnp.sum(blk, axis=1, keepdims=True)
         return jnp.concatenate(ranks, axis=1).astype(i32)
 
+    def window(w):
+        """The staging buffer's ``w``-th T-row window, as an index."""
+        if NW == 1:
+            return ...
+        return (slice(None), pl.ds(pl.multiple_of(w * T, T), T))
+
     def flush():
+        if split:
+            blocks, pairs = _flush_by_slot(
+                staged_ref, *split_refs, W=W, F=binsf_ref.shape[0],
+                nch=slot_row, geom=split, exact_dot=exact_dot)
+
+            @once_a_pass
+            def _():
+                tiles_ref[0] += blocks
+                tiles_ref[1] += pairs
+            # the windows past the first are not written again before
+            # they are added to
+            staged_ref[...] = jnp.zeros_like(staged_ref)
+            return
         m = (staged_ref[slot_row:slot_row + 1, :]
              == k1_c.astype(jnp.float32)).astype(jnp.float32)  # [W, T]
         rows = [staged_ref[j:j + 1, :] for j in range(slot_row)]
@@ -1323,8 +1374,11 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
         start = pl.multiple_of(jnp.minimum(i, n_sub - 1) * T, T)
         live_f = live.astype(jnp.float32)
         sel_t = sel_ref[:, pl.ds(start, T)] * live_f
+        # the window the rows land in and what it holds already
+        w = c // T if NW > 1 else 0
+        cw = c - w * T if NW > 1 else c
         # staging position of each selected row, -1 where not selected
-        tgt = jnp.where(sel_t > 0.0, rank_of(start, live_f) + c,
+        tgt = jnp.where(sel_t > 0.0, rank_of(start, live_f) + cw,
                         -1)                                 # [1, T]
 
         def gather(tgt):
@@ -1335,22 +1389,47 @@ def _fused_kernel(tbl_ref, binsf_ref, ghm_ref, leaf_ref, *rest, F, B, W,
                 precision=dot_prec,
                 preferred_element_type=jnp.float32)         # [C, T]
 
-        staged_ref[...] += gather(tgt)
+        staged_ref[window(w)] += gather(tgt)
         c = c + jnp.sum(sel_t).astype(i32)
-        full = (c >= T) | (jnp.logical_not(live) & (c > 0))
+        full = (c >= TS) | (jnp.logical_not(live) & (c > 0))
+        if NW > 1:
+            # the rows past a window's end open the next one, by the
+            # second gather the turn that fills a tile always made
+            @pl.when((c - w * T >= T) & (w < NW - 1))
+            def _():
+                staged_ref[window(w + 1)] = gather(tgt - T)
 
         @pl.when(full)
         def _():
             flush()
             # the rows past the tile's end open the next tile (an
             # unselected row's -1 - T meets no position either)
-            staged_ref[...] = gather(tgt - T)
+            staged_ref[window(0)] = gather(tgt - T)
 
-        return jnp.where(full, jnp.maximum(c - T, 0), c)
+        return jnp.where(full, jnp.maximum(c - TS, 0), c)
 
     last = step == pl.num_programs(1 if tiled else 0) - 1
     cnt_smem[0] = jax.lax.fori_loop(
         0, n_sub + last.astype(i32), sub_tile, cnt_smem[0])
+    if split:
+        # a tile's sums are whole: its channel rows leave the kernel as
+        # the three planes the wrapper hands on (g = hi + lo, h, count;
+        # _channel_rows), three fifths of what the accumulators hold
+        acc_ref, L = split_refs[-1], split["L"]
+
+        @pl.when(last)
+        def _():
+            def planes(k, c):
+                for p in range(split["groups"]):
+                    a = acc_ref[k, p]                        # [nch x L, 128]
+                    ch = [a[j * L:(j + 1) * L, :] for j in range(slot_row)]
+                    hist_ref[k, p] = jnp.concatenate(
+                        [ch[0] + ch[1],
+                         ch[2] + ch[3] if slot_row == 5 else ch[2],
+                         ch[-1]], axis=0)
+                return c
+
+            jax.lax.fori_loop(0, W, planes, 0)
 
 
 # rows of the compaction payload block ahead of the bin rows in the
@@ -1459,12 +1538,231 @@ def _accumulate_hist(get_row, chan, m, hist_ref, cnt_ref, *, F, B, groups,
             cnt_ref[p, :, :] += acc_c
 
 
+def _flush_by_slot(staged_ref, ord_ref, key_ref, p_ref, span_ref, acc_ref,
+                   *, W, F, nch, geom, exact_dot):
+    """acc_ref[k, p] += the histograms of feature group p over the
+    staged rows of slot k, by the root kernel's two-digit split of the
+    bin axis (_root_hist_kernel) a slot at a time; returns the 128-row
+    blocks and the (block, slot) pairs it dotted, as i32 scalars.
+
+    A dense dot over rows of mixed slots spends its whole output on
+    every row: W x Bp x nchan MACs a feature, whichever operand the
+    slot is folded into. Only a dot whose rows share a slot addresses
+    one slot's histogram, so the staged rows are first put IN SLOT
+    ORDER by one more exact one-hot gather (a row's place = the staged
+    rows of lower slots + its rank among its own slot's, from the
+    [W, T2] membership and the 128 x 128 triangle the scan ranks by;
+    stable, so a slot's sums stay in row order; skipped where one slot
+    holds every staged row). A slot is then a contiguous run, and each
+    128-row block holds slots first..last: for each that has a row in
+    it, the block's slot-free selected weight rows P (a channel
+    multiplicand where the bin's select digit is l, else 0: what
+    _root_weight_rows x ``lo == l`` is to the root kernel) contract
+    against the lane-digit one-hots Q of THAT slot's rows into that
+    slot's accumulator, the products of two different features' digits
+    dropped on the way. Q's compare folds the slot in: a row's key is
+    ``digit + H x slot`` (slot 1-based, 0 = no row), slot k's one-hot
+    row h is ``key == h + H x (k + 1)``. The digits are the root
+    kernel's the other way round: the H lanes take the bin's LOW digit
+    (bin = l x H + h), so that a plane's H lanes are H neighbouring
+    bins and the wrapper's transpose to [W, F, B, 3] moves runs of H
+    (the high digit on the lanes cost it 4.83 against 3.08 ms a pass at
+    700 features: PERF.md section 5, PR 35, call C).
+
+    One [R, 128] x [128, 128] dot is one weight load, and a region of
+    few of them between two scalar reads leaves the MXU idle while it
+    fills and drains: so the blocks' scalars (first slot, the one past
+    the last, which of those between hold a row) are all read ahead of
+    the dots, from one [W, blocks] compare, and the dots of a block's
+    FIRST slot, which every block that holds a row has, stand in one
+    straight line with its build, WAVE_SPLIT_UNROLL blocks at a time;
+    only a block's further slots take a loop of their own.
+
+    staged_ref: [C, T2] f32: ``nch`` channel multiplicands, the slot,
+                then (from _PAY_ROWS) the F stored bin rows
+    ord_ref:    [C, T2] f32 scratch, the same in slot order
+    key_ref:    [U, C - _PAY_ROWS, 128] i32 scratch, U blocks' keys
+    p_ref:      [U, groups, R, 128] scratch, U blocks' P by group
+    span_ref:   [2 x T2 / 128] i32 SMEM scratch, the blocks' scalars
+    acc_ref:    [W, groups, nch x L, 128] f32 scratch: row (channel, l),
+                lane (feature of the group, h)
+    """
+    i32, f32 = jnp.int32, jnp.float32
+    dt = f32 if exact_dot else jnp.bfloat16
+    prec = (jax.lax.Precision.HIGHEST if exact_dot
+            else jax.lax.Precision.DEFAULT)
+    H, L, gf, nl = geom["H"], geom["L"], geom["gf"], geom["nl"]
+    groups = geom["groups"]
+    T2 = staged_ref.shape[1]
+    LB = COMPACT_TILE_UNIT
+    NB = T2 // LB
+    U = key_ref.shape[0]
+    slot_row = nch
+
+    def dot_lanes(a, b):
+        """[M, n] x [N, n] -> [M, N]: the lane axis of both contracts,
+        as in every dot of this file."""
+        return jax.lax.dot_general(
+            a, b, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=prec, preferred_element_type=f32)
+
+    srow = staged_ref[slot_row:slot_row + 1, :]              # [1, T2]
+    k_iota = jax.lax.broadcasted_iota(i32, (W, 1), 0)
+    k1 = (k_iota + 1).astype(f32)
+    m = (srow == k1).astype(f32)                             # [W, T2]
+    tot_c = jnp.sum(m, axis=1, keepdims=True)                # [W, 1]
+    # rows of slots up to and including each slot / before it
+    incl_c = jnp.sum(((srow > 0.0) & (srow <= k1)).astype(f32), axis=1,
+                     keepdims=True)
+    offs_c = incl_c - tot_c
+    n_tot = jnp.sum(tot_c)
+    mixed = jnp.sum((tot_c > 0.0).astype(f32)) > 1.5
+
+    @pl.when(mixed)
+    def _():
+        lower = jnp.sum(jnp.where(k1 < srow, tot_c, 0.0), axis=0,
+                        keepdims=True)                       # [1, T2]
+        tri = (jax.lax.broadcasted_iota(i32, (LB, LB), 0)
+               < jax.lax.broadcasted_iota(i32, (LB, LB), 1)).astype(dt)
+        own, before = [], jnp.zeros((W, 1), f32)
+        for b in range(NB):
+            blk = m[:, b * LB:(b + 1) * LB]                  # [W, LB]
+            within = jax.lax.dot_general(
+                blk.astype(dt), tri,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=f32)
+            own.append(jnp.sum(blk * (within + before), axis=0,
+                               keepdims=True))
+            before = before + jnp.sum(blk, axis=1, keepdims=True)
+        place = jnp.where(srow > 0.0,
+                          lower + jnp.concatenate(own, axis=1),
+                          -1.0).astype(i32)                  # [1, T2]
+        src = staged_ref[...].astype(dt)
+        oc = min(autotune.WAVE_SPLIT_ORDER_COLS, T2)
+        col = jax.lax.broadcasted_iota(i32, (oc, 1), 0)
+        for j in range(T2 // oc):
+            perm = (col + j * oc == place).astype(dt)        # [oc, T2]
+            ord_ref[:, j * oc:(j + 1) * oc] = dot_lanes(src, perm)
+
+    @pl.when(jnp.logical_not(mixed))
+    def _():
+        ord_ref[...] = staged_ref[...]
+
+    # ---- the blocks' scalars, all ahead of the dots ----
+    # Block b holds places [128 b, 128 b + 128): its first slot is the
+    # number of slots that end at or before its start, the one past its
+    # last the number that start before its end; a slot between them
+    # holds a row of it where its run reaches into it. One [W, blocks]
+    # compare, two f32-exact sums a block: first + 64 x stop, and the
+    # slots past the first that hold a row as bits (KNOWN of them; a
+    # slot further on is dotted unseen)
+    KNOWN = 24
+    pos0 = (jax.lax.broadcasted_iota(i32, (1, NB), 1) * LB).astype(f32)
+    started = offs_c < jnp.minimum(pos0 + LB, n_tot)         # [W, NB]
+    first_r = jnp.sum((incl_c <= pos0).astype(f32), axis=0, keepdims=True)
+    span_r = first_r + 64.0 * jnp.sum(started.astype(f32), axis=0,
+                                      keepdims=True)
+    here = started & (incl_c > pos0) & (tot_c > 0.0)
+    rel = k_iota - first_r.astype(i32)                       # [W, NB]
+    seen = here & (rel >= 1) & (rel <= KNOWN)
+    bits_r = jnp.sum(jnp.where(
+        seen, jnp.left_shift(1, jnp.clip(rel - 1, 0, KNOWN - 1)), 0
+    ).astype(f32), axis=0, keepdims=True)
+    b_iota = jax.lax.broadcasted_iota(i32, (1, NB), 1)
+    for b in range(NB):
+        at_b = b_iota == b
+        span_ref[2 * b] = jnp.sum(jnp.where(at_b, span_r, 0.0)).astype(i32)
+        span_ref[2 * b + 1] = jnp.sum(
+            jnp.where(at_b, bits_r, 0.0)).astype(i32)
+    n_blocks = jnp.sum(jnp.max(here.astype(f32), axis=0,
+                               keepdims=True)).astype(i32)
+    n_pairs = jnp.sum(here.astype(f32)).astype(i32)
+
+    l_iota = jax.lax.broadcasted_iota(i32, (L, 1), 0)
+    h_iota = jax.lax.broadcasted_iota(i32, (H, 1), 0)
+    lane_s = jax.lax.broadcasted_iota(i32, (1, gf * H), 1) // H
+
+    def slot_dots(u, k, p, weight_rows):
+        """acc_ref[k, p] += block u's group p over slot k's rows."""
+        n_live = min(gf, F - p * gf)
+        key = h_iota + H * (k + 1)                           # [H, 1]
+        qs = [(key_ref[u, p * gf + s:p * gf + s + 1, :] == key).astype(dt)
+              for s in range(n_live)]
+        if n_live < gf:
+            qs.append(jnp.zeros(((gf - n_live) * H, LB), dt))
+        res = dot_lanes(weight_rows, jnp.concatenate(qs, axis=0))
+        # feature s keeps block (s, s) of the product
+        own = jnp.zeros((nl, gf * H), f32)
+        for s in range(n_live):
+            own = jnp.where(lane_s == s, res[s * nl:(s + 1) * nl, :], own)
+        acc_ref[k, p] += own
+
+    def first_slots(b0):
+        """U blocks' builds and first slots' dots, one straight line. A
+        block past the staged rows holds zero weights: its dots add
+        nothing, to slot W - 1."""
+        for u in range(U):
+            b = b0 + u
+            lanes = pl.ds(pl.multiple_of(b * LB, LB), LB)
+            k = jnp.minimum(jnp.bitwise_and(span_ref[2 * b], 63), W - 1)
+            # the block's rows: whole sublane tiles at a lane offset
+            pay = ord_ref[0:_PAY_ROWS, lanes]                # [16, LB]
+            x = ord_ref[_PAY_ROWS:, lanes].astype(i32)
+            lane_d = jnp.bitwise_and(x, H - 1)
+            sel_d = jax.lax.shift_right_logical(x, H.bit_length() - 1)
+            key_ref[u] = lane_d + H * pay[slot_row:slot_row + 1,
+                                          :].astype(i32)
+            wb = [jnp.broadcast_to(pay[c:c + 1, :], (L, LB))
+                  for c in range(nch)]
+            for p in range(groups):
+                n_live = min(gf, F - p * gf)
+                ps = []
+                for s in range(n_live):
+                    hit = sel_d[p * gf + s:p * gf + s + 1, :] == l_iota
+                    ps += [jnp.where(hit, w, 0.0) for w in wb]
+                if n_live < gf:
+                    ps.append(jnp.zeros(((gf - n_live) * nl, LB), f32))
+                weight_rows = jnp.concatenate(ps, axis=0).astype(dt)
+                p_ref[u, p] = weight_rows
+                slot_dots(u, k, p, weight_rows)
+
+    def further_slots(b0):
+        for u in range(U):
+            span, bits = span_ref[2 * (b0 + u)], span_ref[2 * (b0 + u) + 1]
+            first = jnp.bitwise_and(span, 63)
+
+            def slot(k, c):
+                r = k - first - 1
+
+                @pl.when((r >= KNOWN) | (jnp.bitwise_and(jnp.right_shift(
+                    bits, jnp.minimum(r, KNOWN - 1)), 1) > 0))
+                def _():
+                    for p in range(groups):
+                        slot_dots(u, k, p, p_ref[u, p])
+
+                return c
+
+            jax.lax.fori_loop(first + 1, jnp.right_shift(span, 6), slot, 0)
+
+    def some_blocks(q, c):
+        @pl.when((q * (U * LB)).astype(f32) < n_tot)
+        def _():
+            first_slots(q * U)
+            further_slots(q * U)
+
+        return c
+
+    jax.lax.fori_loop(0, NB // U, some_blocks, 0)
+    return n_blocks, n_pairs
+
+
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk",
                                              "interpret", "precision",
                                              "any_cat", "count_proxy",
                                              "packed4", "num_features",
                                              "dequant", "variant",
-                                             "compact", "feature_tile"))
+                                             "compact", "feature_tile",
+                                             "split", "stage_rows"))
 def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      leaf_ids, tbl, *, num_bins,
                                      chunk=2048, interpret=False,
@@ -1473,12 +1771,16 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      count_proxy=False, packed4=False,
                                      num_features=None, dequant=True,
                                      variant="hilo5", compact=None,
-                                     feature_tile=None):
+                                     feature_tile=None, split=None,
+                                     stage_rows=None):
     """Partition one wave + build its smaller-child histograms in ONE
     data pass. Returns (new_leaf_ids [N], hist [W, F, B, 3], work) —
     or, with ``count_proxy``, (new_leaf_ids, hist [W, F, B, 2],
-    cnt_right [W], work). ``work`` is a [2] int32: rows scanned and
-    rows put through the one-hot dot, in units of COMPACT_TILE_UNIT.
+    cnt_right [W], work). ``work`` is a [3] int32: rows scanned, rows
+    put through the dot, both in units of COMPACT_TILE_UNIT, and the
+    block-dots those rows met: one a unit where every slot's lanes
+    share a one-hot dot, the (block, slot) pairs where the flush dots a
+    slot at a time.
 
     Only rows that sit in one of the wave's smaller children and carry
     weight can change a sum, and the one-hot dot is what a row costs:
@@ -1487,6 +1789,16 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     dots whole tiles of them (_fused_kernel). ``compact`` = True /
     False overrides that choice — for tests and for the measurement
     that sets its threshold, never from a parameter.
+
+    Where the kernel compacts and the root's digit split applies
+    (autotune.wave_split_applies: the bf16 tiers at 57 to 256 byte
+    bins) the flush puts its staged rows in slot order and dots each
+    128-row block against the slots it holds, by the root kernel's two
+    digits (_flush_by_slot): a sixth to a third of the one-hot dot's
+    MACs at 255 bins, the same products, added up in another order.
+    ``split`` = True / False and ``stage_rows`` (rows staged ahead of a
+    flush; autotune.WAVE_SPLIT_STAGE_ROWS) override the rule and the
+    constant, for tests and the measurements beside them alone.
 
     Where one resident block cannot hold every feature's accumulator,
     bin rows and compaction payload, the pass walks tiles of features
@@ -1557,7 +1869,8 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     geom, n_tiles = autotune.hist_feature_tiling(
         F=F, B=B, W=W, chunk=chunk, fused=True, F_rows=bins_t.shape[0],
         bins_bytes=bins_t.dtype.itemsize, int8=int8,
-        count_proxy=count_proxy, variant=variant, force=feature_tile)
+        count_proxy=count_proxy, variant=variant, force=feature_tile,
+        split=split, stage_rows=stage_rows)
     tiled = n_tiles > 1
     Bp, group_sz, gb = geom["Bp"], geom["group_sz"], geom["gb"]
     groups, gb_pad = geom["groups"], geom["gb_pad"]
@@ -1584,19 +1897,34 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     T = autotune.hist_compact_tile(
         geom=geom, chunk=chunk, bins_bytes=bins_t.dtype.itemsize,
         int8=int8, force=compact)
+    sg = autotune.fused_wave_split(
+        geom=geom, compact_tile=T, int8=int8, count_proxy=count_proxy,
+        variant=variant, force=split)
+    if split and not sg:
+        raise NotImplementedError(
+            "the digit split serves the compacting bf16 tiers at 57 to "
+            "256 byte bins")
+    T2 = stage_rows or autotune.WAVE_SPLIT_STAGE_ROWS
+    if sg and T2 % math.lcm(autotune.WAVE_SPLIT_UNROLL * COMPACT_TILE_UNIT,
+                            T):
+        raise ValueError(f"stage_rows {T2}: whole {T}-row windows and "
+                         f"whole runs of {autotune.WAVE_SPLIT_UNROLL} "
+                         "blocks")
     exact_dot = interpret and not int8
     kernel = functools.partial(
         _fused_kernel, F=geom["F"], B=B, W=W, groups=groups,
         group_sz=group_sz, variant=variant, exact_dot=exact_dot,
         int8=int8, any_cat=any_cat, count_proxy=count_proxy,
-        packed4=packed4, compact_tile=T, tiled=tiled)
+        packed4=packed4, compact_tile=T, tiled=tiled, split=sg)
 
     blk = autotune.fused_hist_block_shapes(chunk=chunk, geom=geom,
                                            tbl_rows=TBL_ROWS,
-                                           compact_tile=T, tiled=tiled)
+                                           compact_tile=T, tiled=tiled,
+                                           split=sg, W=W, stage_rows=T2)
     grid, at = _tile_grid(n_tiles, n_pad // chunk)
-    # every tile's accumulator is one block of the output's group axis
-    hist_all = (n_tiles * groups,) + blk["hist"][1:]
+    # every tile's accumulator is one block of the output's first axis
+    hist_all = (n_tiles * blk["hist"][0],) + blk["hist"][1:]
+    hist_at = (lambda t, i: (t, 0, 0, 0)) if sg else (lambda t, i: (t, 0, 0))
     operands = [tblT, bins_t, ghm, leaf2d]
     in_specs = [
         pl.BlockSpec(blk["tbl"], at(lambda t, i: (0, 0)),
@@ -1627,8 +1955,7 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                                      at(lambda t, i: (0, i)),
                                      memory_space=pltpu.VMEM))
     out_specs = [
-        pl.BlockSpec(blk["hist"], at(lambda t, i: (t, 0, 0)),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec(blk["hist"], at(hist_at), memory_space=pltpu.VMEM),
         pl.BlockSpec(blk["leaf_out"], at(lambda t, i: (0, i)),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1637,7 +1964,7 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         jax.ShapeDtypeStruct(hist_all,
                              jnp.int32 if int8 else jnp.float32),
         jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        jax.ShapeDtypeStruct((1,), jnp.int32),
+        jax.ShapeDtypeStruct((2 if sg else 1,), jnp.int32),
     ]
     scratch = []
     if T:
@@ -1646,12 +1973,18 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
                    pltpu.VMEM(blk["sel"], jnp.float32),
                    pltpu.VMEM(blk["staged"], jnp.float32),
                    pltpu.SMEM((1,), jnp.int32)]
+    if sg:
+        scratch += [pltpu.VMEM(blk["ord"], jnp.float32),
+                    pltpu.VMEM(blk["key"], jnp.int32),
+                    pltpu.VMEM(blk["p"], xdt),
+                    pltpu.SMEM((2 * T2 // COMPACT_TILE_UNIT,), jnp.int32),
+                    pltpu.VMEM(blk["acc"], jnp.float32)]
     if count_proxy:
         out_specs.append(pl.BlockSpec(blk["cnt"],
                                       at(lambda t, i: (0, 0)),
                                       memory_space=pltpu.VMEM))
         out_shape.append(jax.ShapeDtypeStruct(blk["cnt"], jnp.float32))
-    elif variant == "hilo4":
+    elif variant == "hilo4" and not sg:
         # second histogram-shaped accumulator: the count-dot channels
         out_specs.append(pl.BlockSpec(blk["hist"],
                                       at(lambda t, i: (t, 0, 0)),
@@ -1670,11 +2003,26 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
         interpret=interpret,
     )(*operands)
     hist, leaf_out = outs[0], outs[1]
-    work = jnp.stack([jnp.int32(n_pad // COMPACT_TILE_UNIT), outs[2][0]])
+    # without the split a unit of dotted rows is one block-dot
+    work = jnp.stack([jnp.int32(n_pad // COMPACT_TILE_UNIT), outs[2][0],
+                      outs[2][-1]])
     outs = outs[:2] + outs[3:]
 
     def ret(*vals):
         return vals + (work,)
+
+    if sg:
+        # [tiles x W, groups, (plane, l), (s, h)] -> [W, F, bin = l x H + h,
+        # plane]: the lanes' digit is the bin's LOW one, so a plane's H
+        # lanes stay side by side through the transpose (_flush_by_slot)
+        gf = sg["gf"]
+        hist = hist.reshape(n_tiles, W, sg["groups"], 3, sg["L"], gf,
+                            sg["H"])
+        hist = hist.transpose(1, 0, 2, 5, 4, 6, 3)
+        hist = hist.reshape(W, n_tiles, sg["groups"] * gf,
+                            sg["L"] * sg["H"], 3)
+        return ret(leaf_out[0, :n], hist[:, :, :geom["F_rows"], :B]
+                   .reshape(W, -1, B, 3)[:, :F])
 
     # [groups, gb_pad, 128] -> [F, B, nchan*W] -> [W, F, B, nchan'].
     # channel rows were [c*W + k]: reshape (nchan, W) then combine

@@ -404,18 +404,32 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # in grow(): a step served by the registry is not traced again
     F_meta = int(meta_const.num_bin.shape[0])
     obs.gauge("mem/hist_pool_bytes").set(float(L * F_meta * B * 3 * 4))
+    # MACs one block-dot of a wave pass spends on a row of a feature
+    # (autotune.root_pass_macs; times hist/blocks_dotted over
+    # hist/rows_dotted it is what a dotted row met): the fused
+    # kernel's, 0 on every other path, as hist/root_macs below
+    wave_macs = 0
     if route == "pallas-tpu" and default_seams and not cfg.sparse_hist:
-        _geom, n_tiles = autotune.hist_feature_tiling(
-            F=F_meta, B=B, W=W, fused=bool(use_fused),
-            chunk=(fused_chunk if use_fused
-                   else cfg.chunk or DEFAULT_HIST_CHUNK),
+        bins_bytes = 1 if B <= 256 else 4
+        tier = dict(int8=quant, count_proxy=proxy,
+                    variant=(cfg.exact_variant
+                             if cfg.precision == "highest" else None))
+        chunk = (fused_chunk if use_fused
+                 else cfg.chunk or DEFAULT_HIST_CHUNK)
+        geom, n_tiles = autotune.hist_feature_tiling(
+            F=F_meta, B=B, W=W, fused=bool(use_fused), chunk=chunk,
             F_rows=(-(-F_meta // 2) if cfg.packed4 and use_fused
-                    else F_meta),
-            bins_bytes=1 if B <= 256 else 4, int8=quant,
-            count_proxy=proxy,
-            variant=(cfg.exact_variant
-                     if cfg.precision == "highest" else None))
+                    else F_meta), bins_bytes=bins_bytes, **tier)
         obs.gauge("hist/feature_tiles").set(float(n_tiles))
+        if use_fused:
+            split = autotune.fused_wave_split(
+                geom=geom, compact_tile=autotune.hist_compact_tile(
+                    geom=geom, chunk=chunk, bins_bytes=bins_bytes,
+                    int8=quant), **tier)
+            wave_macs = autotune.root_pass_macs(
+                B=B, nchan=split["nchan"] if split else 0,
+                split=bool(split))
+    obs.gauge("hist/wave_macs").set(float(wave_macs))
     # the root pass: a kernel of its own wherever the shapes and the
     # tier say its digit split pays (autotune.root_split_applies), else
     # the wave kernel with one live slot; an injected hist_fn (the
@@ -751,7 +765,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 internal_count=jnp.zeros(L - 1, f32),
                 split_is_cat=jnp.zeros(L - 1, bool),
                 split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
-                wave_work=jnp.zeros(2, jnp.int32),
+                wave_work=jnp.zeros(3, jnp.int32),
             ),
         )
 
@@ -795,9 +809,10 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 left_smaller = lcnt <= rcnt
                 small_ids = jnp.where(left_smaller, wl, new_ids)
                 small_ids = jnp.where(active, small_ids, -1)
-                # rows this pass scanned / put through the one-hot dot:
-                # the fused TPU kernel counts them, no other route does
-                wave_work = jnp.zeros(2, jnp.int32)
+                # rows this pass scanned / put through the dot, and the
+                # block-dots they met: the fused TPU kernel counts
+                # them, no other route does
+                wave_work = jnp.zeros(3, jnp.int32)
                 if use_fused:
                     safe_feat = jnp.maximum(feat, 0)
                     tbl = jnp.concatenate([jnp.stack([

@@ -190,10 +190,14 @@ class TestVmemPredicate:
         # where one resident block is over the budget the kernel walks
         # feature tiles (tests/test_wide_features.py), and the candidate
         # carries the tile it was priced with
+        # (200 features: since the fused kernel's flush dots a slot at
+        # a time, PR 35, its stage, ordered tile and per-slot
+        # accumulators are priced too, and 256 features no longer fit
+        # one block at any offered chunk)
         wide = autotune.hist_chunk_candidates(
-            F=256, B=256, W=24, fused=True)
+            F=200, B=256, W=24, fused=True)
         assert [c["chunk"] for c in wide] == [32768, 16384, 8192, 4096]
-        geom = autotune.hist_geometry(F=256, B=256, W=24)
+        geom = autotune.hist_geometry(F=200, B=256, W=24)
         for c in wide:
             one_block = autotune.fits_vmem(autotune.hist_vmem_bytes(
                 chunk=c["chunk"], geom=geom, W=24, fused=True))

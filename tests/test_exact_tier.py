@@ -486,6 +486,33 @@ class TestTuneExactTier:
             constant_hessian=True)]
         assert cands_c[0] == "hilo3"
 
+    @pytest.mark.parametrize("constant_hessian, want, timed", [
+        (False, "hilo5", []), (True, "hilo3", ["hilo3", "hilo5"])],
+        ids=["two_layouts", "constant_hessian"])
+    def test_hilo4_is_not_timed_where_the_flush_dots_by_slot(
+            self, fresh_tuner, constant_hessian, want, timed):
+        """At a shape the fused kernel's flush dots a slot at a time
+        (67 x 255: it compacts, and the root's split applies) hilo4 is
+        hilo5's dot at a wider wave, not a layout: the timed arm leaves
+        it out (one candidate left: nothing is timed), though the fake
+        timer would have it win; asked for by name it still runs. A
+        narrow shape (8 x 64 above) times all of them as before."""
+        calls = []
+
+        def fake(cand):
+            calls.append(cand["variant"])
+            return {"hilo3": 1.0, "hilo4": 0.5, "hilo5": 2.0}[
+                cand["variant"]]
+
+        assert autotune.tune_exact_tier(
+            F=67, B=255, constant_hessian=constant_hessian,
+            _measure=fake) == want
+        assert sorted(calls) == timed
+        assert autotune.tune_exact_tier(
+            F=67, B=255, requested="hilo4") == "hilo4"
+        assert [c["variant"] for c in autotune.exact_tier_candidates(
+            constant_hessian=False, by_slot=True)] == ["hilo5"]
+
     def test_failed_candidates_fall_back(self, fresh_tuner):
         def broken(cand):
             raise RuntimeError("mosaic says no")

@@ -241,10 +241,16 @@ def _offered_chunks(cell):
 
 def test_the_tuner_offers_the_cells_chunks_the_root_kernel_must_halve():
     """What the cases below stand on: the candidates reach past the
-    16384 both cells pin, to chunks no tile of the root kernel fits."""
+    16384 both cells pin, to a chunk no tile of the root kernel fits.
+    (Until PR 35 tpu_autotune=exhaustive also offered MAX_HIST_CHUNK;
+    the fused kernel's flush by slot keeps a 2,048-row stage, its
+    ordered copy and the accumulators as scratch beside the chunk's
+    25 MB of partition temporaries, and no tile of it is inside the
+    budget there. A chunk set by hand still reaches the root kernel:
+    the cases below keep 65536.)"""
     for cell in _CELLS:
-        assert {16384, 32768, autotune.MAX_HIST_CHUNK} <= set(
-            _offered_chunks(cell))
+        assert {16384, 32768} <= set(_offered_chunks(cell))
+        assert autotune.MAX_HIST_CHUNK not in _offered_chunks(cell)
     geom = autotune.root_hist_geometry(F=32, B=255, nchan=5)
     assert not autotune.fits_vmem(
         autotune.root_hist_vmem_bytes(chunk=32768, geom=geom))
@@ -261,7 +267,9 @@ def test_root_kernel_is_priced_at_every_chunk_the_grower_may_run(
     working set fits at, and a tile at that chunk: never a trace-time
     refusal where the wave kernel's root trained."""
     F, nchan = _CELLS[cell]["F"], _LAYOUT_NCHAN[layout]
-    assert chunk in _offered_chunks(cell) or chunk < 4096
+    # (65536: no longer the tuner's to offer, still a user's to set)
+    assert (chunk in _offered_chunks(cell) or chunk < 4096
+            or chunk == autotune.MAX_HIST_CHUNK)
     own, geom, n_tiles = autotune.root_hist_tiling(
         F=F, B=255, nchan=nchan, chunk=chunk)
     assert chunk % own == 0 and own >= min(chunk, 16384)

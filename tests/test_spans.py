@@ -376,21 +376,26 @@ def test_every_pallas_call_is_named_after_its_entry_point(module, kernel):
     assert len(all_calls) == 5 and all(n for _, n in all_calls)
 
 
-def _traced_kernel_names(fn, *args):
+def _pallas_calls_traced(fn, *args):
+    """The ``pallas_call`` equations ``fn`` traces to, nested ones too."""
     import jax
-    names = []
+    found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                names.append(eqn.params["name"])
+                found.append(eqn)
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (list, tuple)) else [v]):
                     inner = getattr(sub, "jaxpr", sub)
                     if hasattr(inner, "eqns"):
                         walk(inner)
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return names
+    return found
+
+
+def _traced_kernel_names(fn, *args):
+    return [e.params["name"] for e in _pallas_calls_traced(fn, *args)]
 
 
 @pytest.mark.parametrize("kernel", ["wave_histogram_pallas",
@@ -492,6 +497,46 @@ def test_span_off_path_stays_in_microseconds():
     assert per_span_us < 500.0, per_span_us
 
 
+def _pallas_out_shapes(fn, *args):
+    """Shapes of the one ``pallas_call``'s own outputs in ``fn``."""
+    (eqn,) = _pallas_calls_traced(fn, *args)
+    return [v.aval.shape for v in eqn.outvars]
+
+
+@pytest.mark.parametrize("tile", [None, 32], ids=["one-tile", "tiled"])
+def test_flush_by_slot_keeps_the_fused_kernels_name(tile):
+    """A shape on the digit split's side of the fused kernel's rule (255
+    bins, a dot dear enough to compact for: autotune.wave_split_applies)
+    is the same ``pallas_call`` under the same name; its sums leave the
+    kernel by slot, plane and digit and its SMEM output holds two
+    scalars (blocks, pairs) where the one-hot dot's holds one:
+    ``step.passes_per_iter`` goes on counting 16.0 a tree and
+    ``kernel.root_ms_per_iter`` stays the root's."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import autotune, hist_wave
+    S = jax.ShapeDtypeStruct
+    N, F, B, W = 8192, 72, 255, 24
+    args = (S((F, N), jnp.uint8), S((N,), jnp.float32), S((N,), jnp.float32),
+            S((N,), jnp.float32), S((N,), jnp.int32), S((18, W), jnp.int32))
+    geom = autotune.hist_geometry(F=F, B=B, W=W)
+    assert autotune.fused_wave_split(
+        geom=geom, variant="hilo5", compact_tile=autotune.hist_compact_tile(
+            geom=geom, chunk=4096)) is not None
+    rows, tiles = (32, 3) if tile else (F, 1)
+    for split, hist, scalars in (
+            (None, (tiles * W, -(-rows // 4), 24, 128), 2),
+            (False, (tiles * rows, 256, 128), 1)):
+        fn = functools.partial(
+            hist_wave.fused_partition_histogram_pallas, num_bins=B,
+            chunk=4096, variant="hilo5", feature_tile=tile, split=split)
+        (name,) = _traced_kernel_names(fn, *args)
+        assert name == "fused_partition_histogram_pallas"
+        assert _pallas_out_shapes(fn, *args) == [hist, (1, N), (scalars,)]
+        out = jax.eval_shape(fn, *args)
+        assert out[1].shape == (W, F, B, 3) and out[2].shape == (3,)
+
+
 @pytest.mark.parametrize("tile", [None, 32], ids=["one-tile", "tiled"])
 def test_root_kernel_reaches_the_compiler_under_the_root_passs_name(tile):
     """The root pass's kernel of its own is a Mosaic call named
@@ -530,6 +575,7 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
     # put back at the end what the gauge held: the next test on this
     # worker reads its own grower's value or the one before
     monkeypatch.setattr(reg.gauge("hist/root_macs"), "_value", -1.0)
+    monkeypatch.setattr(reg.gauge("hist/wave_macs"), "_value", -1.0)
     f, n, B = 8, 1024, 255
     meta = FeatureMeta(
         num_bin=np.full(f, B, np.int32), missing_type=np.zeros(f, np.int32),
@@ -540,6 +586,10 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
         route="pallas-tpu", hp=SplitParams(min_data_in_leaf=5, has_cat=False))
     grow = make_wave_grower(cfg, meta, jit=False)
     assert reg.snapshot()["gauges"]["hist/root_macs"] == 5 * 8 * 128
+    # and what one block-dot of a wave pass spends, beside it: this narrow
+    # a shape does not compact, so the one-hot dot's (the flush by slot's
+    # 5 x 8 x 128: tests/test_wave_split.py)
+    assert reg.snapshot()["gauges"]["hist/wave_macs"] == 256 * 128
     S = jax.ShapeDtypeStruct
     text = jax.jit(grow).lower(
         S((f, n), jnp.uint8), S((n,), jnp.float32), S((n,), jnp.float32),
@@ -549,6 +599,7 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
     # not the value of the grower before
     _booster().train_one_iter()
     assert reg.snapshot()["gauges"]["hist/root_macs"] == 0.0
+    assert reg.snapshot()["gauges"]["hist/wave_macs"] == 0.0
 
 
 def test_ranking_objective_sets_its_gauges_and_names_its_scope():

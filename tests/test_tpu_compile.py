@@ -106,6 +106,10 @@ _HIST_CASES = {
     # the one the cell's runs resolved
     "epsilon-hilo5-W24": (2000, 256, 24, "highest", "hilo5", False, False,
                           False, 1 << 16, 16384),
+    # the benchmark's ranking cell (yahoo_ltr.train_rank): 700 features
+    # in 11 tiles of 64, the chunk its cold tunes took
+    "yahoo-hilo5-W24": (700, 255, 24, "highest", "hilo5", False, False,
+                        False, 1 << 16, 4096),
     # edge shapes (the retired on-chip shape sweep)
     "edge-F1": (1, 64, 14, "int8", None, True, False, False, 8192, 4096),
     "edge-4bin-packed4-oddF": (27, 16, 64, "int8", None, True, True,
@@ -144,6 +148,40 @@ def test_fused_partition_histogram_kernel_compiles(spec, name):
     compiled = jax.jit(functools.partial(
         fused_partition_histogram_pallas, **kw)).lower(*args).compile()
     assert _mosaic(compiled) == 1
+
+
+# which flush a case's fused kernel takes by the rule
+# (autotune.wave_split_applies), and the feature tiles it walks: the
+# three benchmark cells and the LRB window model dot a slot at a time,
+# by the root's two digits; the int8 tiers and a dot too cheap to
+# compact for keep the one-hot dot
+_FLUSH_BY_SLOT = {"criteo-hilo5-W24": 1, "criteo-hilo4-W32": 1,
+                  "epsilon-hilo5-W24": 32, "yahoo-hilo5-W24": 11,
+                  "lrb-hilo4-W30": 1}
+
+
+@pytest.mark.parametrize("name", sorted(_HIST_CASES))
+def test_fused_kernels_flush_is_the_rules(name):
+    """The cases above compile whichever flush the rule hands them; this
+    says which, so that a case cannot change sides unseen: the split's
+    cases are the compacting bf16 tiers at 57 to 256 bins."""
+    from lightgbm_tpu.ops import autotune
+    F, B, W, prec, variant, proxy, packed4, _, N, chunk = _HIST_CASES[name]
+    int8 = prec == "int8"
+    kw = dict(int8=int8, count_proxy=proxy,
+              variant=variant if prec == "highest" else None)
+    geom, n_tiles = autotune.hist_feature_tiling(
+        F=F, B=B, W=W, chunk=chunk, fused=True,
+        F_rows=(F + 1) // 2 if packed4 else F, **kw)
+    split = autotune.fused_wave_split(
+        geom=geom, compact_tile=autotune.hist_compact_tile(
+            geom=geom, chunk=chunk, int8=int8), **kw)
+    assert (split is not None) == (name in _FLUSH_BY_SLOT)
+    if split:
+        assert n_tiles == _FLUSH_BY_SLOT[name]
+        assert autotune.hist_vmem_bytes(
+            chunk=chunk, geom=geom, W=W, fused=True, tiled=n_tiles > 1,
+            **kw) <= autotune.PALLAS_VMEM_BUDGET_BYTES
 
 
 @pytest.mark.parametrize("name", ["higgs-int8-proxy-W64",
@@ -290,17 +328,26 @@ def test_epsilon_tiles_are_priced_inside_the_budget(fused):
                          ids=["criteo", "epsilon"])
 def test_feature_tile_of_the_benchmark_cells_is_unmoved(F, rows):
     """The compaction's working set only shrank (a [C, T] staging
-    buffer, [T, T] one-hots): both cells keep the tile they ran with,
-    one resident block at 67 features, 64 stored rows (the 64-group cap)
-    at 2,000: the fused kernel at every chunk the tuner offers, the
-    root kernel (whose pricing did not change) at the cells' own."""
+    buffer, [T, T] one-hots), and the flush by slot keeps accumulators
+    of the one-hot dot's size beside a 2,048-row stage: both cells keep
+    the tile they ran with, one resident block at 67 features, 64 stored
+    rows (the 64-group cap) at 2,000: the fused kernel at every chunk
+    the tuner offers, the root kernel (whose pricing did not change) at
+    the cells' own. The one move: hilo4's 32-slot wave at the largest
+    offered chunk no longer holds 67 features, nor a tile of 64, in one
+    block (81 MB priced at 67: the stage, the ordered tile and a third
+    more slots' accumulators beside 16.8 MB of partition temporaries)
+    and walks tiles of 32; the tuner times that pair against the
+    smaller chunks."""
     from lightgbm_tpu.ops import autotune
     for variant, W in (("hilo5", 24), ("hilo4", 32)):
         for chunk, fused in [(c, True) for c in (4096, 8192, 16384, 32768)
                              ] + [(16384, False)]:
+            want = 32 if (variant, chunk, fused) == ("hilo4", 32768, True) \
+                else rows
             assert autotune.hist_feature_tile(
                 F=F, B=255, W=W, chunk=chunk, fused=fused,
-                variant=variant) == rows, (variant, chunk, fused)
+                variant=variant) == want, (variant, chunk, fused)
     geom = autotune.hist_geometry(F=min(F, rows), B=255, W=24, F_rows=rows)
     blk = autotune.fused_hist_block_shapes(
         chunk=16384, geom=geom, tbl_rows=24,

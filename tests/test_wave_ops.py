@@ -722,21 +722,25 @@ def test_compacted_fused_kernel_matches_xla(kind):
     kern, orac, B, small, mask, chunk = _compaction_case(kind)
     T = math.gcd(chunk, 512)
     leaf_x, hist_x = fused_partition_histogram_xla(*orac, num_bins=B)
+    # (the one-hot dot over T-row tiles; tests/test_wave_split.py holds
+    # the flush by slot, which this width would take by the rule)
     leaf_c, hist_c, work = fused_partition_histogram_pallas(
-        *kern, num_bins=B, chunk=chunk, interpret=True, compact=True)
+        *kern, num_bins=B, chunk=chunk, interpret=True, compact=True,
+        split=False)
     np.testing.assert_array_equal(np.asarray(leaf_c), np.asarray(leaf_x))
     hc, hx = np.asarray(hist_c), np.asarray(hist_x)
     np.testing.assert_array_equal(hc[..., 2], hx[..., 2])
     np.testing.assert_allclose(hc, hx, atol=5e-5)
     contributing = int((np.isin(np.asarray(leaf_x), small[small >= 0])
                         & (mask > 0)).sum())
-    scanned, dotted = (int(v) * COMPACT_TILE_UNIT for v in work)
+    scanned, dotted, block_dots = (int(v) * COMPACT_TILE_UNIT for v in work)
     assert scanned == -(-len(mask) // chunk) * chunk
-    assert dotted == -(-contributing // T) * T
+    assert dotted == block_dots == -(-contributing // T) * T
     # the same pass without compaction dots every row it scans
     *_, work_m = fused_partition_histogram_pallas(
         *kern, num_bins=B, chunk=chunk, interpret=True, compact=False)
-    assert int(work_m[1]) == int(work_m[0]) == scanned // COMPACT_TILE_UNIT
+    assert (int(work_m[1]) == int(work_m[0]) == int(work_m[2])
+            == scanned // COMPACT_TILE_UNIT)
 
 
 @pytest.mark.parametrize("tier", ["hilo5", "int8"])
@@ -771,7 +775,8 @@ def test_compacted_histogram_is_the_tiles_of_a_stable_compaction(tier):
     packed = (bins_t[:, rows], g[rows] * live, h[rows] * live,
               mask[rows] * live, np.where(live, leaf[rows], 9))
     _, hist_c, work = fused_partition_histogram_pallas(
-        bins_t, g, h, mask, leaf, tbl, chunk=chunk, compact=True, **kw)
+        bins_t, g, h, mask, leaf, tbl, chunk=chunk, compact=True,
+        split=False, **kw)
     assert int(work[1]) * COMPACT_TILE_UNIT == len(rows)
     hist_c = np.asarray(hist_c)
     if tier == "int8":
@@ -833,7 +838,7 @@ def test_compacted_fused_kernel_vs_masked(tier):
         *args, num_bins=B, chunk=512, interpret=True, compact=c, **kw)
         for c in (False, True)]
     assert len(outs[0]) == (4 if "proxy" in tier else 3)
-    (s_m, d_m), (s_c, d_c) = (np.asarray(o[-1]) for o in outs)
+    (s_m, d_m, _), (s_c, d_c, _) = (np.asarray(o[-1]) for o in outs)
     assert s_m == d_m == s_c and 0 < d_c < d_m     # rows scanned, dotted
     for a, b in zip(outs[0][:-1], outs[1][:-1]):
         a, b = np.asarray(a), np.asarray(b)
@@ -876,9 +881,9 @@ def test_grower_counts_rows_scanned_and_dotted(compaction_everywhere):
     np.testing.assert_array_equal(recs[True, "leaf"], recs[False, "leaf"])
     np.testing.assert_array_equal(np.asarray(rec.split_feature),
                                   np.asarray(ref.split_feature))
-    assert np.asarray(ref.wave_work).tolist() == [0, 0]
+    assert np.asarray(ref.wave_work).tolist() == [0, 0, 0]
     scanned, dotted = (int(v) * COMPACT_TILE_UNIT
-                       for v in np.asarray(rec.wave_work))
+                       for v in np.asarray(rec.wave_work)[:2])
     n_splits = int(rec.num_leaves) - 1
     n_pad = -(-n // 512) * 512
     waves = scanned // n_pad          # a pass scans every (padded) row

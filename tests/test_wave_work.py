@@ -1,10 +1,12 @@
 """The wave passes' work counters, from the tree's record to the benchmark's
-reader: ``TreeRecord.wave_work`` (rows scanned, rows put through the one-hot
-dot, in units of ``hist_wave.COMPACT_TILE_UNIT``; the kernel and the grower
-fill it, ``tests/test_wave_ops.py``) rides the stop check's ONE download and
-feeds ``hist/rows_scanned``, ``hist/rows_dotted`` and ``hist/trees_counted``;
-``benchmark/readers/kernel.dotted_rows_ratio.py`` lays them against the rows
-the grown trees require."""
+reader: ``TreeRecord.wave_work`` ([3]: rows scanned, rows put through the
+dot, both in units of ``hist_wave.COMPACT_TILE_UNIT``, and the block-dots
+those rows met; the kernel and the grower fill it, ``tests/test_wave_ops.py``
+and ``tests/test_wave_split.py``) rides the stop check's ONE download and
+feeds ``hist/rows_scanned``, ``hist/rows_dotted``, ``hist/blocks_dotted`` and
+``hist/trees_counted``; ``benchmark/readers/kernel.dotted_rows_ratio.py`` lays
+the rows against the rows the grown trees require, and
+``kernel.wave_macs_per_row.py`` the block-dots against the rows."""
 import importlib.util
 import sys
 from pathlib import Path
@@ -26,7 +28,8 @@ ROWS = 1200
 def _counters():
     c = obs.default_registry().counter_items()
     return {k: c.get(f"hist/{k}", 0)
-            for k in ("rows_scanned", "rows_dotted", "trees_counted")}
+            for k in ("rows_scanned", "rows_dotted", "blocks_dotted",
+                      "trees_counted")}
 
 
 @pytest.fixture(scope="module")
@@ -44,7 +47,9 @@ def _with_work(g, work):
 
 
 def test_stop_check_feeds_the_counters_once_a_tree(trained):
-    work = [(30, 9), (30, 11), (40, 7), (20, 20)]
+    work = [(30, 9, 21), (30, 11, 11), (40, 7, 16), (20, 20, 48)]
+    for r in trained.records:
+        assert r.wave_work.shape == (3,)
     _with_work(trained, work)
     before = _counters()
     assert trained._check_stop() is False
@@ -52,6 +57,7 @@ def test_stop_check_feeds_the_counters_once_a_tree(trained):
     got = {k: v - before[k] for k, v in _counters().items()}
     assert got == {"rows_scanned": 120 * COMPACT_TILE_UNIT,
                    "rows_dotted": 47 * COMPACT_TILE_UNIT,
+                   "blocks_dotted": 96,         # block-dots, not rows
                    "trees_counted": 4}
 
 
@@ -59,7 +65,7 @@ def test_units_past_int32_rows_add_up_on_the_host(trained):
     """A tree's rows can pass 2^31 (15 waves over 150 M rows); its units of
     128 rows cannot, and the host adds them as Python ints."""
     big = 2**31 // COMPACT_TILE_UNIT * 3 // 2
-    _with_work(trained, [(big, big)] * 4)
+    _with_work(trained, [(big, big, big)] * 4)
     before = _counters()
     trained._check_stop()
     assert (_counters()["rows_scanned"] - before["rows_scanned"]
