@@ -916,6 +916,15 @@ def phase_multichip(sz: Sizes, seed: int, chips: int = 4,
                 check("tpu_custom_call" in txt,
                       f"multichip[{tier}/data]: ... and Mosaic kernels")
         ser, par = models[tier, "serial"], models[tier, "data"]
+        if not quant:
+            # higgs_like's matrix is float32 and goes in as it is
+            # (basic.keeps_float32): the shards' bins are the one chip's
+            n = sz.train_rows
+            check(bool(np.array_equal(
+                np.asarray(par._gbdt._bins_dev)[:, :n],
+                np.asarray(ser._gbdt._bins_dev)[:, :n])),
+                f"multichip: float32 sharded bins == one-chip bins "
+                f"({n} rows x {HIGGS_FEATURES})")
         ps = ser.predict(Xp, raw_score=True)
         pd_ = par.predict(Xp, raw_score=True)
         diff = float(np.abs(ps - pd_).max())

@@ -49,11 +49,23 @@ def _is_scipy_sparse(data) -> bool:
         return False
 
 
+def keeps_float32(data) -> bool:
+    """True when ``data`` goes through ``Dataset`` and ``predict`` as the
+    float32 it is: a C-contiguous 2-D float32 ndarray. The device binner
+    then makes its keys ON the device from the raw bits (io/ingest.py,
+    "exactness": the float64 route's bins bit for bit, half the bytes on
+    the wire, no float64 copy of the matrix on the host). Every other
+    input becomes float64, as ever. Callers that hold tens of GB ask
+    this before they build the matrix (benchmark/kinds/train_loop_dp.py)."""
+    return (isinstance(data, np.ndarray) and data.dtype == np.float32
+            and data.ndim == 2 and data.flags.c_contiguous)
+
+
 def _data_to_2d(data, feature_name="auto", categorical_feature="auto"):
-    """Normalize input to (ndarray[N, F] float64, feature_names,
-    categorical_indices). Pandas categorical/object columns are
-    factorized like the reference's pandas handling
-    (basic.py _data_from_pandas)."""
+    """Normalize input to (ndarray[N, F] float64, or the float32 matrix
+    itself where ``keeps_float32``; feature_names, categorical_indices).
+    Pandas categorical/object columns are factorized like the
+    reference's pandas handling (basic.py _data_from_pandas)."""
     cat_idx: List[int] = []
     names: Optional[List[str]] = None
     if _is_pandas_df(data):
@@ -85,6 +97,8 @@ def _data_to_2d(data, feature_name="auto", categorical_feature="auto"):
         # config), and the predict paths densify in bounded chunks
         from .io.sparse import SparseMatrix
         X = SparseMatrix.from_scipy(data)
+    elif keeps_float32(data):
+        X = data
     else:
         X = np.asarray(data, np.float64)
         if X.ndim == 1:
@@ -853,7 +867,7 @@ class _InnerPredictor:
         """Raw predictions flattened class-major — the init_score layout
         (metadata.cpp init_score_ is [class][row])."""
         from .io.sparse import SparseMatrix
-        if not isinstance(X, SparseMatrix):
+        if not isinstance(X, SparseMatrix) and not keeps_float32(X):
             X = np.asarray(X, np.float64)
         raw = self._gbdt.predict_raw(X)
         if raw.ndim == 2:          # [N, K] -> class-major flat

@@ -30,7 +30,10 @@ a streaming pass that bins rows as they arrive):
   the chunk pipeline ACROSS the mesh and assembles the matrix directly
   under the grower's ``NamedSharding`` — each device receives and bins
   only its own contiguous row block, so no single-device staging copy
-  of the dataset ever exists (Design.md §7).
+  of the dataset ever exists (Design.md §7). ONE prefetch worker feeds
+  either pipeline, the sharded one's D device queues too: the main
+  thread uploads and dispatches, and on four chips 1, 2 and 4 workers
+  take the same time (PERF.md, PR 36).
 
 Exactness
 ---------
@@ -77,6 +80,12 @@ from ..obs import trace
 from ..utils import log, timing
 from .binning import BinMapper, BinType, MissingType
 
+# the float32 route finds a value's bin by COUNTING the bounds below it
+# (a compare and an add a bound on the vector unit, the rows on the
+# lanes) where a feature has at most this many bounds, and by the
+# float64 route's gather search above it: the search's eight dependent
+# gathers a value are what a chip is slowest at (PERF.md, PR 36)
+_F32_COUNT_MAX_BOUNDS = 256
 _TARGET_CHUNK_BYTES = 64 << 20      # ~64 MB of raw values per chunk
 _MIN_CHUNK_ROWS = 1 << 14
 _MAX_CHUNK_ROWS = 1 << 21
@@ -406,6 +415,12 @@ class DeviceBinner:
                          if m.bin_type != BinType.NUMERICAL]
         self.num_cols = used[self.num_inner]       # real/source columns
         self.cat_cols = used[self.cat_inner]
+        # every source column numerical, used and in order: a float32
+        # chunk is then the matrix's own rows, sent as they lie
+        self._num_cols_identity = bool(
+            len(self.num_cols)
+            and np.array_equal(self.num_cols,
+                               np.arange(len(self.num_cols))))
         max_bin_global = max(m.num_bin for m in mappers)
         self.out_dtype = np.uint8 if max_bin_global <= 256 else np.int32
         self.chunk_rows = auto_chunk_rows(config, len(mappers),
@@ -503,12 +518,33 @@ class DeviceBinner:
                 pos = jnp.where(go, pos + step, pos)
             return pos
 
+        def count_below(xk):
+            """The same count by compares: bounds are sorted and their
+            pad is the max key, which is below nothing. The rows lie on
+            the lanes, a bound is a column broadcast along them, eight
+            bounds a turn of the loop so the [Fn, C] count is carried
+            through memory Bp / 8 times. -> [C, Fn]."""
+            xt = xk.T                                      # [Fn, C]
+            turn = min(Bp, 8)
+
+            def body(i, acc):
+                for j in range(turn):
+                    col = jax.lax.dynamic_slice_in_dim(
+                        bhi, i * turn + j, 1, axis=1)      # [Fn, 1]
+                    acc = acc + (col < xt).astype(jnp.int32)
+                return acc
+            return jax.lax.fori_loop(
+                0, Bp // turn, body, jnp.zeros(xt.shape, jnp.int32)).T
+
         def key32_dev(x):
             b = jax.lax.bitcast_convert_type(x, jnp.uint32)
             neg = (b >> jnp.uint32(31)).astype(bool)
             mask = jnp.where(neg, jnp.uint32(0xFFFFFFFF),
                              jnp.uint32(0x80000000))
             return b ^ mask
+
+        search32 = (count_below if Bp <= _F32_COUNT_MAX_BOUNDS
+                    else lambda xk: lower_bound(xk, None))
 
         def chunk(xa, xb, nan, cat_iv):
             """One chunk -> [F, C] bins. f32 input: xa = raw f32
@@ -520,7 +556,7 @@ class DeviceBinner:
                     nanm = jnp.isnan(xa)
                     v = jnp.where(nanm, jnp.float32(0.0), xa) \
                         + jnp.float32(0.0)           # -0.0 -> +0.0
-                    pos = lower_bound(key32_dev(v), None)
+                    pos = search32(key32_dev(v))
                 else:
                     nanm = nan
                     pos = lower_bound(xa, xb)
@@ -542,7 +578,7 @@ class DeviceBinner:
             return jnp.take(allout, inv_perm, axis=0).astype(out_dtype)
 
         # jit-capture: ok(Fn, f32_input, out_dtype, nan_bin, cats,
-        # cat_nbin, inv_perm, key32_dev, lower_bound) —
+        # cat_nbin, inv_perm, key32_dev, lower_bound, search32) —
         # per-binner jit: the captured mapper tables ARE the kernel's
         # constants, derived from THIS dataset's bin mappers and
         # cached on the binner instance (one binner per dataset,
@@ -570,8 +606,13 @@ class DeviceBinner:
         C = pad_to if pad_to is not None else self.chunk_rows
         k = X.shape[0]
         pad = C - k
-        Xn = X[:, self.num_cols] if len(self.num_cols) else \
-            np.zeros((k, 0), X.dtype)
+        if (self.f32_input and self._num_cols_identity
+                and X.shape[1] == len(self.num_cols)):
+            Xn = X                       # a view: no host copy at all
+        elif len(self.num_cols):
+            Xn = X[:, self.num_cols]
+        else:
+            Xn = np.zeros((k, 0), X.dtype)
         if self.f32_input:
             xa = np.ascontiguousarray(Xn, np.float32)
             if pad:
@@ -631,6 +672,9 @@ class DeviceBinner:
             obs.counter("ingest/h2d_bytes").add(nbytes)
             obs.counter("ingest/h2d_chunks").add(1)
             obs.counter("ingest/rows_device").add(k)
+            if self.f32_input:
+                # rows that crossed the wire as the float32 they were
+                obs.counter("ingest/f32_rows").add(k)
             if assemble is not None:
                 arrs = assemble(arrs)
             out = self._chunk_fn(*arrs)
@@ -773,9 +817,12 @@ class DeviceBinner:
         shard are zero bins, the same values row padding would write),
         its chunks stream host->device pinned to d, and the chunk
         submission round-robins ACROSS devices so every chip's transfer
-        + bin kernel overlap the next chip's host prep. Bit-exact with
-        ``bin_matrix``: the identical compiled chunk kernel maps the
-        identical row slices — only the destination device differs.
+        + bin kernel overlap the next chip's host prep. ONE prefetch
+        worker preps for all D queues and the main thread submits: a
+        worker a queue takes the same time on four chips (PERF.md,
+        PR 36). Bit-exact with ``bin_matrix``: the identical compiled
+        chunk kernel maps the identical row slices — only the
+        destination device differs.
 
         Returns a jax.Array whose trailing ``N_pad - N`` columns are
         padding (the caller records the true row count)."""

@@ -56,8 +56,9 @@ K_MODEL_VERSION = "v2"     # gbdt.h kModelVersion
 
 @jax.jit
 def _tail_summary(num_leaves, wave_work):
-    """[R, 4] int32 rows (num_leaves, wave_work) of R records' scalars
-    and [3] vectors, stacked on the device for one download."""
+    """[R, 1 + w] int32 rows (num_leaves, wave_work) of R records' scalars
+    and [w] vectors (w = 3; 5 under a row-sharding learner), stacked on
+    the device for one download."""
     return jnp.concatenate([jnp.stack(num_leaves)[:, None],
                             jnp.stack(wave_work)], axis=1)
 
@@ -293,9 +294,12 @@ class GBDT:
         self._clean_groups = 0
         # compile the stop check's one stacked download here, in set-up,
         # not an interval into training: it always stacks an interval's
-        # records, a shorter tail padded (_tail_host; a mesh's replicated
-        # records compile their own at the first check)
-        self._tail_pad = (jnp.zeros((), jnp.int32), jnp.zeros(3, jnp.int32))
+        # records, a shorter tail padded (_tail_host). Under a mesh the
+        # records leave the step replicated over it, so the pads are
+        # placed the same way and this compile is the one a check uses
+        self._tail_pad = tuple(
+            self._place_step_raw(z) for z in (
+                np.zeros((), np.int32), np.zeros(self._work_len, np.int32)))
         self._tail_host([])
         # fused-step state (see _get_step_fn)
         self._step_key = None
@@ -347,6 +351,8 @@ class GBDT:
         self._mesh = mesh
         self._learner_mode = mode
         D = mesh.devices.size if mesh is not None else 1
+        from ..obs import registry as obs
+        obs.gauge("comm/devices").set(float(D))
         # EFB rides the histogram seam (bundle columns in, member
         # histograms out) and the meta-driven partition decode, which
         # compose with the serial grower, the row-sharded data/voting
@@ -788,6 +794,11 @@ class GBDT:
             mode, gcfg, meta, mesh, self._f_pad, cfg.top_k,
             hist_fn=hist_fn, efb_feature=efb_feature)
         self._step_key = None       # grower changed: rebuild fused step
+        # a row-sharding learner's records carry the fullest shard's
+        # dotted rows and the tree's wave passes beside the serial
+        # learner's three: the grower says how wide its records are
+        self._work_len = getattr(self._grower, "resolved", {}).get(
+            "work_len", 3)
 
     def _step_cache_eligible(self, mode: str) -> bool:
         """True when this booster's fused step can be served by the
@@ -1725,11 +1736,16 @@ class GBDT:
         recs = self.records[start_group * K:]
         if not recs:
             return [], []
-        nl = self._tail_host(recs)[0]
+        nl, work = self._tail_host(recs)
         leaves = nl.reshape(-1, K).tolist()
-        W = max(self._grower_cfg.wave_size, 1)
-        waves = [sum(max(-(-(int(l) - 1) // W), 1) for l in grp)
-                 for grp in leaves]
+        if self._work_len > 3:
+            # the passes the grower counted (a wave grows: 1, 2, 4 ..
+            # leaves split before it is ever full)
+            waves = work[:, 4].reshape(-1, K).sum(axis=1).tolist()
+        else:
+            W = max(self._grower_cfg.wave_size, 1)
+            waves = [sum(max(-(-(int(l) - 1) // W), 1) for l in grp)
+                     for grp in leaves]
         return leaves, waves
 
     def wire_encoding(self) -> str:
@@ -1753,10 +1769,10 @@ class GBDT:
             from ..obs import registry as obs
             for i, cb in enumerate(comm):
                 recorder.set_field(i + 1, "comm_bytes", cb)
-            obs.counter("comm/psum_bytes").add(sum(comm))
+            # comm/psum_bytes and comm/psum_passes are fed where the
+            # trees are counted (_first_splitless_group), report or none
             passes = (sum(waves)
                       + self.num_tree_per_iteration * len(waves))
-            obs.counter("comm/psum_passes").add(passes)
             saved = self._wire_bytes_saved_per_pass() * passes
             if saved:
                 obs.counter("comm/wire_bytes_saved").add(saved)
@@ -1828,18 +1844,32 @@ class GBDT:
         hundred bytes per tree and are not counted."""
         if self._mesh is None or self._learner_mode != "data":
             return None
-        gcfg = self._grower_cfg
-        C = self._wire_channels()
-        F_h = max(self.train_data.num_features, 1)
-        per_pass = (gcfg.wave_size * F_h * gcfg.num_bins * C
-                    * self._wire_entry_bytes())
         K = self.num_tree_per_iteration
-        return [(int(w) + K) * per_pass for w in waves]
+        return [self._psum_bytes(int(w), K) for w in waves]
+
+    def _psum_bytes(self, waves: int, trees: int) -> int:
+        """Bytes ONE chip hands the histogram seam for ``trees`` trees
+        grown in ``waves`` wave passes, from the shapes as the step
+        holds them: a wave pass sums a [W, F_pad, B, C] block, a root
+        pass one of ``root_slots`` slots (1 under the root kernel)."""
+        gcfg = self._grower_cfg
+        slot = (self._f_pad * gcfg.num_bins * self._wire_channels()
+                * self._wire_entry_bytes())
+        root = getattr(self._grower, "resolved", {}).get(
+            "root_slots", gcfg.wave_size)
+        return (waves * gcfg.wave_size + trees * root) * slot
 
     def _tail_host(self, records):
-        """(num_leaves [R], wave_work [R, 3]) of a list of records in
+        """(num_leaves [R], wave_work [R, w]) of a list of records in
         ONE transfer: what the stop check reads."""
         R = self._stop_check_interval * self.num_tree_per_iteration
+        w = self._work_len
+
+        def work(r):
+            # a loaded model's record is the serial learner's [3]
+            short = w - r.wave_work.shape[0]
+            return jnp.pad(r.wave_work, (0, short)) if short else r.wave_work
+
         parts = []
         for i in range(0, max(len(records), 1), R):
             part = records[i:i + R]
@@ -1847,7 +1877,7 @@ class GBDT:
             parts.append(np.asarray(_tail_summary(
                 tuple(r.num_leaves for r in part)
                 + (self._tail_pad[0],) * pad,
-                tuple(r.wave_work for r in part)
+                tuple(work(r) for r in part)
                 + (self._tail_pad[1],) * pad))[:len(part)])
         a = np.concatenate(parts)
         return a[:, 0], a[:, 1:]
@@ -1903,6 +1933,15 @@ class GBDT:
         obs.counter("hist/rows_dotted").add(int(rows[1]))
         obs.counter("hist/blocks_dotted").add(int(work[2]))
         obs.counter("hist/trees_counted").add(n_clean * K)
+        if self._mesh is not None and self._learner_mode == "data":
+            # the sum across chips: what the fullest shard dotted at
+            # each (how long the others waited), and the bytes a chip
+            # handed the histogram seam for these trees
+            obs.counter("hist/rows_dotted_max_shard").add(int(rows[3]))
+            waves = int(work[4])
+            obs.counter("comm/psum_passes").add(waves + n_clean * K)
+            obs.counter("comm/psum_bytes").add(
+                self._psum_bytes(waves, n_clean * K))
         return first
 
     def _trim_at_splitless(self, gi: int) -> None:

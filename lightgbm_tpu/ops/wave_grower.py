@@ -171,23 +171,37 @@ _SUM_BLOCK = 8192
 
 def _stable_sum(v: jax.Array) -> jax.Array:
     """Shape-stable f32 row reduction: fixed-width blocks reduced
-    per-block, then accumulated SEQUENTIALLY. A zero-padded tail (the
-    step cache's row bucketing, ops/step_cache.py) then cannot perturb
-    rounding — appended blocks are all-+0.0 and add exact zeros to the
-    running total, so bucket-padded training reproduces the exact-shape
-    run's root aggregates bit-for-bit. A plain ``jnp.sum`` re-shapes
-    its reduction tree with the array length, changing last-bit
-    rounding when only the padded width changed (observed as 1-ulp
-    root internal_value drift)."""
+    per-block, then the block sums added PAIRWISE, padded with zeros to
+    a power of two. A zero-padded tail (the step cache's row bucketing,
+    ops/step_cache.py) then cannot perturb rounding — every real block
+    meets the same partner whatever the padded width and the appended
+    blocks add exact zeros — so bucket-padded training reproduces the
+    exact-shape run's root aggregates bit-for-bit. A plain ``jnp.sum``
+    re-shapes its reduction tree with the array length, changing
+    last-bit rounding when only the padded width changed (observed as
+    1-ulp root internal_value drift).
+
+    Pairwise, not a running total: in a booster's FIRST tree every
+    row's hessian is the same number, so every block sum is, and a
+    running total rounds each addition the same way: 13..29 of 2.6 M
+    over 1,280 blocks (1e-5 of the total; PERF.md, PR 36). The root's
+    total then disagrees with its histogram, every ``total - cumsum``
+    hands that absolute error to the child on its side, and a leaf of a
+    few hundred rows at the end of such a chain got a hessian total of
+    a few rows' worth (the ``correct`` false of seeds 3000000101,
+    2600000431 and 2147483659)."""
     n = v.shape[0]
     pad = (-n) % _SUM_BLOCK
     if pad:
         v = jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
     bs = jnp.sum(v.reshape(-1, _SUM_BLOCK), axis=1)
-    if bs.shape[0] == 1:
-        return bs[0]
-    return jax.lax.fori_loop(
-        1, bs.shape[0], lambda i, acc: acc + bs[i], bs[0])
+    width = 1 << (bs.shape[0] - 1).bit_length()
+    if width > bs.shape[0]:
+        bs = jnp.concatenate(
+            [bs, jnp.zeros(width - bs.shape[0], bs.dtype)])
+    while bs.shape[0] > 1:
+        bs = bs[0::2] + bs[1::2]
+    return bs[0]
 
 
 def _mix32(x: jax.Array) -> jax.Array:
@@ -503,9 +517,24 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         def reduce_fn(x):
             return x
 
+    # a row-sharding learner injects its cross-shard max with its sum:
+    # a tree's wave_work then carries two scalars more, the FULLEST
+    # shard's dotted rows beside all shards' (how far the slowest shard
+    # holds the others at each sum) and the wave passes that grew the
+    # tree (a histogram sum each); a serial record stays [3]
+    n_work = 3 if max_reduce_fn is None else 5
+
     if hist_reduce_fn is None:
-        def hist_reduce_fn(h):
+        def hist_reduce_fn(h, scope=None):
             return h
+    else:
+        user_hist_reduce = hist_reduce_fn
+
+        def hist_reduce_fn(h, scope="lgbm/wave/hist_psum"):
+            # the learner's collective under a scope of its own: a
+            # trace viewer then tells the sum from the kernel before it
+            with jax.named_scope(scope):
+                return user_hist_reduce(h)
 
     if max_reduce_fn is None:
         def max_reduce_fn(x):
@@ -677,7 +706,8 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             else:
                 local_root = call_hist(hsrc, bag_mask_ids(leaf0),
                                        root_wl)              # [W, F, B, 3]
-            root_hist = dq(hist_reduce_fn(local_root))
+            root_hist = dq(hist_reduce_fn(local_root,
+                                          scope="lgbm/root_hist/psum"))
             F_h = root_hist.shape[1]
             if quant:
                 # root aggregates as dequantized sums of the SAME integer
@@ -765,7 +795,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 internal_count=jnp.zeros(L - 1, f32),
                 split_is_cat=jnp.zeros(L - 1, bool),
                 split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
-                wave_work=jnp.zeros(3, jnp.int32),
+                wave_work=jnp.zeros(n_work, jnp.int32),
             ),
         )
 
@@ -813,6 +843,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 # block-dots they met: the fused TPU kernel counts
                 # them, no other route does
                 wave_work = jnp.zeros(3, jnp.int32)
+                work_max = wave_work[1:2]
                 if use_fused:
                     safe_feat = jnp.maximum(feat, 0)
                     tbl = jnp.concatenate([jnp.stack([
@@ -837,6 +868,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     if proxy:
                         cnt_r = reduce_fn(fused_out[2])
                     wave_work = reduce_fn(fused_out[-1])   # all shards'
+                    work_max = max_reduce_fn(fused_out[-1][1:2])
                     # out-of-bag rows partition too; their g/h are pre-masked
                     # and the count channel rides on sample_mask
                 elif use_fused_xla:
@@ -901,6 +933,10 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     state.leaf_sum_g[wl], state.leaf_sum_h[wl],
                     hp.lambda_l1, hp.lambda_l2, hp.max_delta_step)
                 rec = state.rec
+                if n_work > 3:
+                    # ... the fullest shard's dotted rows, and this pass
+                    wave_work = jnp.concatenate(
+                        [wave_work, work_max, jnp.ones(1, jnp.int32)])
                 rec = rec._replace(
                     wave_work=rec.wave_work + wave_work,
                     num_leaves=rec.num_leaves + n_act,
@@ -1116,9 +1152,24 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 )
 
         state = jax.lax.while_loop(lambda s: s.go_on, body, state)
+        leaf_count = state.leaf_count
+        if n_work > 3 or n > 2 ** 24:
+            # past 2^24 rows a float32 count is no integer any more: a
+            # split's left count rounds in its cumsum, the right one
+            # comes by subtraction, and the error goes down the tree to
+            # leaves of any size. The rows' own leaf ids say how many
+            # each leaf holds, as integers, summed over the shards
+            # (which this grower cannot count: every row-sharding
+            # learner takes this path)
+            with jax.named_scope("lgbm/leaf_counts"):
+                ids = jnp.where(in_bag, state.leaf_ids, -1)
+                held = jnp.sum(
+                    ids[None, :] == jnp.arange(L, dtype=ids.dtype)[:, None],
+                    axis=1, dtype=jnp.int32)
+                leaf_count = reduce_fn(held).astype(f32)
         rec = state.rec._replace(
             leaf_output=state.leaf_output,
-            leaf_count=state.leaf_count,
+            leaf_count=leaf_count,
             leaf_sum_g=state.leaf_sum_g,
             leaf_sum_h=state.leaf_sum_h,
         )
@@ -1129,7 +1180,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # fused_chunk, fused_interpret, fused_partition_histogram_xla,
     # meta_const,
     # bound_counts, depth_ok, hist_fn, hist_reduce_fn, reduce_fn,
-    # max_reduce_fn, row_offset_fn, split_fn, partition_fn) —
+    # max_reduce_fn, row_offset_fn, split_fn, partition_fn, n_work) —
     # factory-scoped jit: every capture derives from this factory
     # call's WaveGrowerConfig/meta/seam callables. meta_const is the
     # LEGACY 5-arg fallback only; registry-path callers pass meta as
@@ -1143,7 +1194,13 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # smoke run can assert the kernels were compiled, not stood in for
     out.resolved = {"route": route, "fused_pallas": bool(use_fused),
                     "fused_xla": bool(use_fused_xla),
-                    "interpret": bool(use_fused and fused_interpret)}
+                    "interpret": bool(use_fused and fused_interpret),
+                    # slots of the ROOT pass's histogram (what a
+                    # row-sharding learner sums there): the root kernel
+                    # makes one, every other root a whole wave's
+                    "root_slots": 1 if use_root_kernel else W,
+                    # the width of a record's wave_work (n_work above)
+                    "work_len": n_work}
     return out
 
 

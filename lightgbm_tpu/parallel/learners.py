@@ -188,6 +188,19 @@ def training_mesh(config) -> Optional[Mesh]:
     want = (config.num_machines
             if getattr(config, "num_machines", 1) > 1 else None)
     mesh = make_mesh(want)
+    if (want is not None and mesh.devices.size < want
+            and jax.process_count() == 1):
+        # fewer chips than the job was written for: it trains, on a
+        # narrower mesh, and says so once (the counter every time)
+        from ..obs import registry as obs
+        from ..utils import log
+        obs.counter("learner/mesh_short").add(1)
+        if ("short", want, mesh.devices.size) not in _meshes_logged:
+            _meshes_logged.add(("short", want, mesh.devices.size))
+            log.warning("num_machines=%d but %d device(s) found: the "
+                        "%s learner trains over %d", want,
+                        mesh.devices.size, config.tree_learner,
+                        mesh.devices.size)
     return mesh if mesh.devices.size > 1 else None
 
 
@@ -198,11 +211,13 @@ def sync_best_splits(res: SplitResult) -> SplitResult:
     def base(v):
         return jax.lax.all_gather(v, AXIS)
     ov = _collective_overrides.get("allgather")
-    gathered = (ov(res, base) if ov is not None
-                else base(res))                   # pytree of [D, M, ...]
-    best = jnp.argmax(gathered.gain, axis=0)      # [M]
-    m = best.shape[0]
-    return SplitResult(*[leaf[best, jnp.arange(m)] for leaf in gathered])
+    with jax.named_scope("lgbm/wave/split_sync"):
+        gathered = (ov(res, base) if ov is not None
+                    else base(res))               # pytree of [D, M, ...]
+        best = jnp.argmax(gathered.gain, axis=0)  # [M]
+        m = best.shape[0]
+        return SplitResult(*[leaf[best, jnp.arange(m)]
+                             for leaf in gathered])
 
 
 def _slice_meta(meta: FeatureMeta, start, size: int) -> FeatureMeta:
@@ -502,7 +517,9 @@ def make_voting_parallel_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # jit-capture: ok(sharded) — shard_map-wrapped grower: the grow
     # factory's own jit site carries the capture audit (meta rides as
     # a replicated ARGUMENT, PR 4), and this jit is factory-scoped.
-    return jax.jit(sharded)
+    jitted = jax.jit(sharded)
+    jitted.resolved = grow.resolved
+    return jitted
 
 
 def make_grower_for_mode(mode: str, cfg: WaveGrowerConfig,

@@ -282,7 +282,7 @@ class TestReporting:
         iters = [r for r in report["iterations"] if "comm_bytes" in r]
         assert iters, "no per-iteration comm bytes recorded"
         gcfg = g._grower_cfg
-        per_pass = gcfg.wave_size * g.train_data.num_features \
+        per_pass = gcfg.wave_size * g._f_pad \
             * gcfg.num_bins * 3 * 4
         for r in iters:
             assert r["comm_bytes"] % per_pass == 0

@@ -626,3 +626,89 @@ def test_ranking_objective_sets_its_gauges_and_names_its_scope():
         c["lab"].shape[0] * c["lab"].shape[1] ** 2 for c in classes)
     assert "lgbm/gradients/rank_pairs/" in \
         g.lower_step().as_text(debug_info=True)
+
+
+# -- the collectives of the parallel learners (PR 36) --------------------------
+
+def _mesh_booster(learner, n=1280, **params):
+    from lightgbm_tpu.utils.device import get_devices
+    if len(get_devices()) < 4:
+        pytest.skip("needs a 4-device mesh")
+    return _booster(n=n, tree_learner=learner, num_machines=4, **params)
+
+
+@pytest.mark.parametrize("learner, scopes", [
+    ("data", ("lgbm/wave/hist_psum", "lgbm/root_hist/psum")),
+    ("feature", ("lgbm/wave/split_sync",)),
+])
+def test_lowered_parallel_step_names_its_collectives(learner, scopes):
+    """The sum of the wave histograms, the root's sum and the split sync
+    each sit under a scope of their own (the data-parallel learner needs no
+    split sync: every chip finds the same splits in the summed histograms;
+    the feature-parallel learner's is ``sync_best_splits``). The serial
+    step has none of them."""
+    text = _mesh_booster(learner).lower_step().as_text(debug_info=True)
+    for scope in scopes:
+        assert scope + "/" in text, scope
+    serial = _booster().lower_step().as_text(debug_info=True)
+    for scope in ("lgbm/wave/hist_psum", "lgbm/root_hist/psum",
+                  "lgbm/wave/split_sync"):
+        assert scope not in serial, scope
+
+
+def test_data_parallel_counters_are_shapes_times_passes():
+    """``comm/psum_bytes``: a 3-leaf tree is one root pass and two wave
+    passes (the first splits the root, the second one of its children) of
+    a ``[W, F_pad, B, 3]`` float32 block each (off the chip the root has no
+    kernel of its own and sums a whole wave too), by hand;
+    ``comm/psum_passes`` the passes; ``comm/devices`` the mesh;
+    ``hist/rows_dotted_max_shard`` (the ``pmax`` beside the sum) at least a
+    quarter of ``hist/rows_dotted``, and fed by the data learner alone."""
+    reg = obs.default_registry()
+    before = dict(reg.counter_items())
+    g = _mesh_booster("data", num_leaves=3)
+    assert reg.snapshot()["gauges"]["comm/devices"] == 4.0
+    trees = 4
+    for _ in range(trees):
+        g.train_one_iter()
+    g.finish_training()
+    after = dict(reg.counter_items())
+    d = lambda k: after.get(k, 0) - before.get(k, 0)
+    cfg = g._grower_cfg
+    assert g._grower.resolved["root_slots"] == cfg.wave_size    # no kernel here
+    block = cfg.wave_size * g._f_pad * cfg.num_bins * 3 * 4
+    assert d("hist/trees_counted") == trees
+    assert d("comm/psum_passes") == 3 * trees
+    assert d("comm/psum_bytes") == 3 * trees * block
+    assert 4 * d("hist/rows_dotted_max_shard") >= d("hist/rows_dotted") >= 0
+    rec = g.records[0]
+    assert rec.wave_work.shape == (5,)
+    assert int(rec.wave_work[4]) == 2
+    # a serial booster's records stay [3] and feed neither counter
+    s = _booster(num_leaves=3)
+    s.train_one_iter()
+    s.finish_training()
+    assert s.records[0].wave_work.shape == (3,)
+    assert dict(reg.counter_items()).get("comm/psum_bytes", 0) == \
+        after.get("comm/psum_bytes", 0)
+    assert reg.snapshot()["gauges"]["comm/devices"] == 1.0
+
+
+def test_short_mesh_is_said_and_counted():
+    """``num_machines`` beyond the devices found: the learner trains over
+    what there is, warns once and counts every time."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.parallel import learners
+    from lightgbm_tpu.utils.device import get_devices
+    have = len(get_devices())
+    if have < 2:
+        pytest.skip("needs a mesh")
+    c = obs.counter("learner/mesh_short")
+    n0 = c.value
+    cfg = Config().set({"tree_learner": "data", "num_machines": have + 3})
+    assert learners.training_mesh(cfg).devices.size == have
+    assert learners.training_mesh(cfg).devices.size == have
+    assert c.value == n0 + 2
+    cfg = Config().set({"tree_learner": "data", "num_machines": have})
+    learners.training_mesh(cfg)
+    assert c.value == n0 + 2

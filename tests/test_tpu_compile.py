@@ -484,9 +484,10 @@ def _step_under_test(monkeypatch, tier, mesh=None, *,
         is_cat=np.zeros(F, np.int32))
     grower = (make_wave_grower(gcfg, meta) if mesh is None
               else make_data_parallel_grower(gcfg, meta, mesh))
-    assert grower.resolved == {"route": "pallas-tpu",
-                               "fused_pallas": True, "fused_xla": False,
-                               "interpret": False}
+    assert {k: grower.resolved[k] for k in (
+        "route", "fused_pallas", "fused_xla", "interpret")} == {
+            "route": "pallas-tpu", "fused_pallas": True,
+            "fused_xla": False, "interpret": False}
     if objective is None:
         obj = create_objective("binary", Config().set(
             {"objective": "binary"}))
@@ -604,3 +605,40 @@ def test_data_parallel_step_compiles_over_the_four_chip_mesh(
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     N, nvalid, F = dims
     assert per_chip < (F * (N + nvalid)) // 2
+
+
+@pytest.mark.parametrize("rows_a_chip", [10_485_760])
+def test_criteo_dp4_step_compiles_at_the_cells_shape(topo, monkeypatch,
+                                                     rows_a_chip):
+    """The step of cell ``criteo_dp4.train``: tree_learner=data over the
+    described 2x2 host at 10,485,760 rows x 67 features (72 as the step
+    pads them) a chip, hilo5, chunk 16384, no valid set. Inside the
+    shards the kernels are the one-chip cells': the root kernel (one slot:
+    what the root's sum carries), the flush by slot, the leaf gather; the
+    sums come out as all-reduces; a chip holds a quarter of the rows."""
+    from lightgbm_tpu.parallel.learners import AXIS
+    mesh = Mesh(np.asarray(topo.devices), (AXIS,))
+
+    def placed(*axes):
+        return lambda shape, dt: jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P(
+                *([None] * (len(shape) - len(axes)) + list(axes)))))
+
+    N, F = 4 * rows_a_chip, 72
+    step, dims, meta, aux = _step_under_test(
+        monkeypatch, "hilo5", mesh, shape=(N, 0, F, 256), chunk=16384)
+    compiled = step.lower(*_step_args(placed(AXIS), placed(), dims,
+                                      meta, aux)).compile()
+    text = compiled.as_text()
+    assert _mosaic(compiled) == 3
+    for name in ("wave_histogram_pallas",
+                 "fused_partition_histogram_pallas", "leaf_gather_pallas"):
+        assert name in text, name
+    assert "all-reduce" in text
+    m = compiled.memory_analysis()
+    per_chip = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"criteo_dp4 step, bytes a chip: arguments "
+          f"{m.argument_size_in_bytes}, temp {m.temp_size_in_bytes}")
+    # a quarter of the bins and of the row state, the whole pool
+    assert F * rows_a_chip < per_chip < 3 * 2 ** 30
+
