@@ -12,16 +12,18 @@ a streaming pass that bins rows as they arrive):
 
 - bin boundaries still come from the bounded row sample
   (io/dataset.py find_column_mappers — unchanged semantics);
-- the value->bin map runs on device as a jitted chunked kernel: a
-  branchless lower-bound search over per-feature ``bin_upper_bound``
-  plus the missing/zero-bin/categorical rules of
-  ``BinMapper.value_to_bin``, BIT-EXACT against the host path (see
-  "exactness" below);
+- the value->bin map runs on device as a jitted chunked kernel: the
+  count of each feature's ``bin_upper_bound`` below a value, by
+  compares where a feature has at most ``_COUNT_MAX_BOUNDS`` bounds
+  and by a branchless lower-bound search past that, plus the
+  missing/zero-bin/categorical rules of ``BinMapper.value_to_bin``,
+  BIT-EXACT against the host path (see "exactness" below);
 - raw row chunks stream host->device double-buffered: a worker thread
-  prepares chunk k+1 (column select, key planes) while chunk k's
-  async ``device_put`` + kernel dispatch are in flight, so transfer
-  overlaps compute and the full host uint8 matrix + transpose + bulk
-  upload disappear from the critical path;
+  prepares chunk k+1 (column select where the columns are not the
+  matrix's own, the tail's pad; the values cross as the bits they
+  are) while chunk k's async ``device_put`` + kernel dispatch are in
+  flight, so transfer overlaps compute and the full host uint8 matrix
+  + transpose + bulk upload disappear from the critical path;
 - the feature-major ``[F, N]`` ``bins_t`` matrix is assembled directly
   on device (one concatenate over chunk outputs), which is exactly the
   layout the wave grower consumes (models/gbdt.py);
@@ -42,27 +44,29 @@ jax runs with x64 disabled, so comparing values against the float64
 comparison is done in the *sortable-integer* order of IEEE-754: a
 float maps to an unsigned key (sign bit flipped for positives, all
 bits flipped for negatives) whose integer order equals the float
-order. Two cases:
+order. Keys are computed ON DEVICE from the raw bits in both cases:
 
-- float32 input: keys are computed ON DEVICE from the raw f32 bits;
-  each float64 bound is rounded DOWN to float32 first. For any f32
-  value x and f64 bound b, ``b < x  <=>  floor32(b) < x`` (the largest
-  f32 <= b preserves the strict predicate over f32 operands), so the
-  f32 key search reproduces the f64 ``searchsorted(..., side="left")``
-  exactly.
-- float64 input: the host splits each value's 64-bit key into two
-  uint32 planes (same bytes on the wire as the raw f64) and the device
-  compares lexicographically — exact total order, no rounding anywhere.
+- float32 input: each float64 bound is rounded DOWN to float32 first.
+  For any f32 value x and f64 bound b, ``b < x  <=>  floor32(b) < x``
+  (the largest f32 <= b preserves the strict predicate over f32
+  operands), so the f32 key count reproduces the f64
+  ``searchsorted(..., side="left")`` exactly.
+- float64 input: each value crosses as its two raw uint32 words (a
+  zero-copy view, 8 B a value); the device keys the pair into a high
+  and a low plane and compares lexicographically,
+  ``(bh < xh) | ((bh == xh) & (bl < xl))`` — exact total order, no
+  rounding anywhere.
 
-``-0.0`` is normalized to ``+0.0`` (``v + 0.0``) on both sides before
-key extraction: numpy's searchsorted treats them as equal while the
-key order would not, and the zero-as-one-bin boundaries sit at
+``-0.0`` is normalized to ``+0.0`` on both sides before key
+extraction: numpy's searchsorted treats them as equal while the key
+order would not, and the zero-as-one-bin boundaries sit at
 ±kZeroThreshold right next to that crossing.
 
 NaN follows ``value_to_bin``: mapped as 0.0, then overridden to the
-last bin for MissingType.NAN features. Categorical columns are
-truncated to int on host (few columns, cheap) and matched against the
-category table on device.
+last bin for MissingType.NAN features (the device reads NaN from the
+bits: an exponent of all ones and a mantissa that is not zero).
+Categorical columns are truncated to int on host (few columns, cheap)
+and matched against the category table on device.
 """
 from __future__ import annotations
 
@@ -80,12 +84,13 @@ from ..obs import trace
 from ..utils import log, timing
 from .binning import BinMapper, BinType, MissingType
 
-# the float32 route finds a value's bin by COUNTING the bounds below it
-# (a compare and an add a bound on the vector unit, the rows on the
-# lanes) where a feature has at most this many bounds, and by the
-# float64 route's gather search above it: the search's eight dependent
-# gathers a value are what a chip is slowest at (PERF.md, PR 36)
-_F32_COUNT_MAX_BOUNDS = 256
+# both routes find a value's bin by COUNTING the bounds below it (a
+# compare and an add a bound on the vector unit, the rows on the lanes;
+# three compares with float64's two key planes) where a feature has at
+# most this many bounds, and by a gather search above it: the search's
+# eight dependent gathers a value are what a chip is slowest at
+# (PERF.md, PR 36)
+_COUNT_MAX_BOUNDS = 256
 _TARGET_CHUNK_BYTES = 64 << 20      # ~64 MB of raw values per chunk
 _MIN_CHUNK_ROWS = 1 << 14
 _MAX_CHUNK_ROWS = 1 << 21
@@ -295,7 +300,7 @@ class ChunkRing:
     uploads only the bucketed live-row region (``ring_upload_rows``)
     and the resident tail — whose rows are pad constants by the
     invariant below — is spliced back on device. The raw value/key
-    planes are MAPPER-INDEPENDENT, so a fresh window's fresh bin
+    words are MAPPER-INDEPENDENT, so a fresh window's fresh bin
     mappers bin the resident rows exactly as a full re-upload would:
     training results are bit-identical.
 
@@ -357,6 +362,31 @@ def _keys64_host(v: np.ndarray):
             u.astype(np.uint32))
 
 
+def _keys64_dev(w):
+    """Raw float64 words uint32 [C, 2Fn] (little-endian: word 1 is the
+    high word) -> (hi, lo) key planes and the NaN mask, each [Fn, C],
+    on the device: ``_keys64_host`` of ``where(isnan, 0, v) + 0.0``,
+    bit for bit. The word pair goes to a major axis after the
+    transpose, never to a trailing axis of 2 (which pads to 128
+    lanes)."""
+    import jax.numpy as jnp
+    C, F2 = w.shape
+    wt = w.T.reshape(F2 // 2, 2, C)
+    lo, hi = wt[:, 0], wt[:, 1]
+    mag = hi & jnp.uint32(0x7FFFFFFF)
+    # NaN: an exponent of all ones and a mantissa that is not zero
+    nanm = (mag > jnp.uint32(0x7FF00000)) | (
+        (mag == jnp.uint32(0x7FF00000)) & (lo != 0))
+    zero = nanm | ((mag == 0) & (lo == 0))     # NaN, -0.0 -> +0.0
+    hi = jnp.where(zero, jnp.uint32(0), hi)
+    lo = jnp.where(zero, jnp.uint32(0), lo)
+    neg = (hi >> jnp.uint32(31)).astype(bool)
+    return (hi ^ jnp.where(neg, jnp.uint32(0xFFFFFFFF),
+                           jnp.uint32(0x80000000)),
+            lo ^ jnp.where(neg, jnp.uint32(0xFFFFFFFF), jnp.uint32(0)),
+            nanm)
+
+
 def _key32_host(v: np.ndarray) -> np.ndarray:
     """float32 [..] -> uint32 key (NaN-free input)."""
     b = np.ascontiguousarray(v, np.float32).view(np.uint32)
@@ -415,8 +445,8 @@ class DeviceBinner:
                          if m.bin_type != BinType.NUMERICAL]
         self.num_cols = used[self.num_inner]       # real/source columns
         self.cat_cols = used[self.cat_inner]
-        # every source column numerical, used and in order: a float32
-        # chunk is then the matrix's own rows, sent as they lie
+        # every source column numerical, used and in order: a chunk is
+        # then the matrix's own rows, sent as they lie
         self._num_cols_identity = bool(
             len(self.num_cols)
             and np.array_equal(self.num_cols,
@@ -470,6 +500,8 @@ class DeviceBinner:
             self._bhi = jnp.asarray(bh)
             self._blo = jnp.asarray(bl)
         self._nan_bin = jnp.asarray(np.asarray(nan_bins, np.int32))
+        # numerical features binned by the count, not the search
+        self.counts = bool(Fn) and Bp <= _COUNT_MAX_BOUNDS
 
         # categorical tables (kept per-feature: lengths differ)
         self._cats = [jnp.asarray(np.asarray(m.bin_2_categorical,
@@ -518,23 +550,29 @@ class DeviceBinner:
                 pos = jnp.where(go, pos + step, pos)
             return pos
 
-        def count_below(xk):
-            """The same count by compares: bounds are sorted and their
-            pad is the max key, which is below nothing. The rows lie on
-            the lanes, a bound is a column broadcast along them, eight
-            bounds a turn of the loop so the [Fn, C] count is carried
-            through memory Bp / 8 times. -> [C, Fn]."""
-            xt = xk.T                                      # [Fn, C]
+        def count_below(xh, xl):
+            """The same count by compares, in the key planes' order
+            (``xl`` None: the float32 route's one plane): bounds are
+            sorted and their pad is the max key in both planes, which
+            is below nothing. The rows lie on the lanes ([Fn, C] in and
+            out), a bound is a column broadcast along them, eight
+            bounds a turn of the loop so the count is carried through
+            memory Bp / 8 times."""
             turn = min(Bp, 8)
 
             def body(i, acc):
                 for j in range(turn):
                     col = jax.lax.dynamic_slice_in_dim(
                         bhi, i * turn + j, 1, axis=1)      # [Fn, 1]
-                    acc = acc + (col < xt).astype(jnp.int32)
+                    below = col < xh
+                    if xl is not None:
+                        lcol = jax.lax.dynamic_slice_in_dim(
+                            blo, i * turn + j, 1, axis=1)
+                        below = below | ((col == xh) & (lcol < xl))
+                    acc = acc + below.astype(jnp.int32)
                 return acc
             return jax.lax.fori_loop(
-                0, Bp // turn, body, jnp.zeros(xt.shape, jnp.int32)).T
+                0, Bp // turn, body, jnp.zeros(xh.shape, jnp.int32))
 
         def key32_dev(x):
             b = jax.lax.bitcast_convert_type(x, jnp.uint32)
@@ -543,26 +581,29 @@ class DeviceBinner:
                              jnp.uint32(0x80000000))
             return b ^ mask
 
-        search32 = (count_below if Bp <= _F32_COUNT_MAX_BOUNDS
-                    else lambda xk: lower_bound(xk, None))
+        counts = self.counts
 
-        def chunk(xa, xb, nan, cat_iv):
-            """One chunk -> [F, C] bins. f32 input: xa = raw f32
-            [C, Fn], xb unused. f64 input: xa/xb = hi/lo key planes
-            (uint32), nan = host NaN mask."""
+        def chunk(x, cat_iv):
+            """One chunk -> [F, C] bins. x: the raw numerical values
+            [C, Fn] as float32, or float64's words as uint32 [C, 2Fn]."""
             parts = []
             if Fn:
                 if f32_input:
-                    nanm = jnp.isnan(xa)
-                    v = jnp.where(nanm, jnp.float32(0.0), xa) \
+                    nanm = jnp.isnan(x)
+                    v = jnp.where(nanm, jnp.float32(0.0), x) \
                         + jnp.float32(0.0)           # -0.0 -> +0.0
-                    pos = search32(key32_dev(v))
+                    xk = key32_dev(v)
+                    pos = (count_below(xk.T, None).T if counts
+                           else lower_bound(xk, None))
+                    out_num = jnp.where(nanm & (nan_bin[None, :] >= 0),
+                                        nan_bin[None, :], pos).T
                 else:
-                    nanm = nan
-                    pos = lower_bound(xa, xb)
-                out_num = jnp.where(nanm & (nan_bin[None, :] >= 0),
-                                    nan_bin[None, :], pos)
-                parts.append(out_num.T)
+                    xh, xl, nanm = _keys64_dev(x)
+                    pos = (count_below(xh, xl) if counts
+                           else lower_bound(xh.T, xl.T).T)
+                    out_num = jnp.where(nanm & (nan_bin[:, None] >= 0),
+                                        nan_bin[:, None], pos)
+                parts.append(out_num)
             for k, cvals in enumerate(cats):
                 iv = cat_iv[:, k]
                 default = jnp.int32(cat_nbin[k] - 1)
@@ -578,9 +619,9 @@ class DeviceBinner:
             return jnp.take(allout, inv_perm, axis=0).astype(out_dtype)
 
         # jit-capture: ok(Fn, f32_input, out_dtype, nan_bin, cats,
-        # cat_nbin, inv_perm, key32_dev, lower_bound, search32) —
-        # per-binner jit: the captured mapper tables ARE the kernel's
-        # constants, derived from THIS dataset's bin mappers and
+        # cat_nbin, inv_perm, key32_dev, lower_bound, count_below,
+        # counts) — per-binner jit: the captured mapper tables ARE the
+        # kernel's constants, derived from THIS dataset's bin mappers and
         # cached on the binner instance (one binner per dataset,
         # asserted by create_valid's mapper-reuse contract).
         return jax.jit(chunk)
@@ -588,7 +629,7 @@ class DeviceBinner:
     # -- host-side chunk prep ------------------------------------------------
 
     def _prep_chunk(self, X: np.ndarray, pad_to: Optional[int] = None):
-        """Slice + key one chunk on the host (worker-thread half of the
+        """Slice one chunk on the host (worker-thread half of the
         double buffer). Returns the transfer tuple, tail-padded to the
         fixed chunk shape so every chunk reuses one compiled kernel —
         or to ``pad_to`` rows (the ring path, which splices the
@@ -606,28 +647,19 @@ class DeviceBinner:
         C = pad_to if pad_to is not None else self.chunk_rows
         k = X.shape[0]
         pad = C - k
-        if (self.f32_input and self._num_cols_identity
-                and X.shape[1] == len(self.num_cols)):
+        if self._num_cols_identity and X.shape[1] == len(self.num_cols):
             Xn = X                       # a view: no host copy at all
         elif len(self.num_cols):
             Xn = X[:, self.num_cols]
         else:
             Xn = np.zeros((k, 0), X.dtype)
         if self.f32_input:
-            xa = np.ascontiguousarray(Xn, np.float32)
-            if pad:
-                xa = np.pad(xa, ((0, pad), (0, 0)))
-            xb = nan = np.zeros((0,), np.uint32)   # unused placeholders
+            x = np.ascontiguousarray(Xn, np.float32)
         else:
-            v = np.ascontiguousarray(Xn, np.float64)
-            nanm = np.isnan(v)
-            v = np.where(nanm, 0.0, v) + 0.0        # NaN->0, -0.0->+0.0
-            xa, xb = _keys64_host(v)
-            nan = nanm
-            if pad:
-                xa = np.pad(xa, ((0, pad), (0, 0)))
-                xb = np.pad(xb, ((0, pad), (0, 0)))
-                nan = np.pad(nan, ((0, pad), (0, 0)))
+            # the float64 words as they lie, keyed on the device
+            x = np.ascontiguousarray(Xn, np.float64).view(np.uint32)
+        if pad:
+            x = np.pad(x, ((0, pad), (0, 0)))    # zeros: +0.0
         if len(self.cat_cols):
             cat_iv = _cat_iv_host(X[:, self.cat_cols])
             if pad:
@@ -635,7 +667,7 @@ class DeviceBinner:
                                 constant_values=-1)
         else:
             cat_iv = np.zeros((C, 0), np.int32)
-        return (xa, xb, nan, cat_iv), k
+        return (x, cat_iv), k
 
     def _submit(self, prepped, device=None, assemble=None):
         """Main-thread half: async transfer + kernel dispatch. Returns
@@ -675,6 +707,9 @@ class DeviceBinner:
             if self.f32_input:
                 # rows that crossed the wire as the float32 they were
                 obs.counter("ingest/f32_rows").add(k)
+            if self.counts:
+                # rows whose numerical features the count binned
+                obs.counter("ingest/rows_counted").add(k)
             if assemble is not None:
                 arrs = assemble(arrs)
             out = self._chunk_fn(*arrs)
@@ -735,7 +770,7 @@ class DeviceBinner:
         pad constants (zeros; -1 for the categorical plane), never
         crossing the wire."""
         import jax.numpy as jnp
-        fill = -1 if idx == 3 else 0
+        fill = -1 if idx == 1 else 0
         return jnp.full((rows,) + tuple(like.shape[1:]), fill,
                         like.dtype)
 
@@ -744,17 +779,12 @@ class DeviceBinner:
         slot's pad tail -> full chunk_rows arrays (on device)."""
         import jax.numpy as jnp
         C = self.chunk_rows
-        full = []
-        for i, a in enumerate(up):
-            if getattr(a, "ndim", 0) != 2 or a.shape[0] != U or U >= C:
-                # placeholders ((0,)-shaped f32-mode planes) and
-                # full-width uploads pass through
-                full.append(a)
-                continue
-            tail = (resident[i][U:] if resident is not None
-                    else self._ring_tail(i, C - U, a))
-            full.append(jnp.concatenate([a, tail], axis=0))
-        return tuple(full)
+        if U >= C:                       # full-width uploads pass through
+            return tuple(up)
+        return tuple(
+            jnp.concatenate([a, resident[i][U:] if resident is not None
+                             else self._ring_tail(i, C - U, a)], axis=0)
+            for i, a in enumerate(up))
 
     def _bin_matrix_ringed(self, X: np.ndarray, ring: ChunkRing):
         import jax.numpy as jnp
@@ -798,9 +828,8 @@ class DeviceBinner:
             # bytes the full-pad path would have shipped for the rows
             # the ring kept resident (or created on device)
             up, _k = prepped
-            saved += sum((C - U) * int(a.nbytes) // max(a.shape[0], 1)
-                         for a in up if getattr(a, "ndim", 0) == 2
-                         and a.shape[0] == U and U < C)
+            if U < C:
+                saved += sum((C - U) * int(a.nbytes) // U for a in up)
         if saved:
             obs.counter("ingest/ring_saved_bytes").add(saved)
         bins_t = outs[0] if len(outs) == 1 else jnp.concatenate(outs, 1)
@@ -1001,11 +1030,13 @@ class SparseDeviceBinner(DeviceBinner):
     double-buffered prefetch pipeline as the dense ``DeviceBinner``.
 
     The host half (worker thread) slices a row-chunk of the CSR matrix
-    and keys its explicit VALUES exactly like the dense prep — the
-    sortable-integer f64 hi/lo planes of the module docstring — with
+    and keys its explicit VALUES on the host (``_keys64_host``'s
+    sortable-integer f64 hi/lo planes, the order ``_keys64_dev`` makes
+    on the device for the dense route) — with
     the entry COLUMN/ROW indices as two more planes on the transfer
-    thunk. The device half runs the same branchless lower-bound search
-    PER ENTRY (bounds row gathered by each entry's feature) and
+    thunk. The device half runs the dense kernel's branchless
+    lower-bound search PER ENTRY (bounds row gathered by each entry's
+    feature) and
     scatters the resulting bin codes over a zero-bin-filled ``[F, C]``
     block — the dense feature-major chunk layout, assembled without any
     host [N, F] matrix at any width. Bit-exact vs the host
@@ -1143,7 +1174,7 @@ class SparseDeviceBinner(DeviceBinner):
 
         # numerical planes: keyed values + indices (NaN -> key of +0.0
         # with the mask riding separately, -0.0 normalized — the dense
-        # prep's exact recipe)
+        # route's device keys' exact recipe)
         v = sub.data[numm]
         nanm = np.isnan(v)
         v = np.where(nanm, 0.0, v) + 0.0

@@ -34,6 +34,17 @@ def _pair(X, y, params=None, categorical=(), chunk=257):
     return ds0, ds1
 
 
+def _counters():
+    from lightgbm_tpu.obs import registry as obs
+    return dict(obs.default_registry().counter_items())
+
+
+def _moved(before):
+    """-> name -> how far that counter moved since ``before``."""
+    after = _counters()
+    return lambda name: after.get(name, 0) - before.get(name, 0)
+
+
 def _dev_bins(ds):
     assert ds.bins_t_dev is not None, "device ingest did not engage"
     return np.ascontiguousarray(np.asarray(ds.bins_t_dev).T)
@@ -75,13 +86,19 @@ class TestBinningParity:
         np.testing.assert_array_equal(ds0.bins, _dev_bins(ds1))
 
     def test_int32_tier(self):
+        """Past 256 bounds the float64 route keeps the search, on the
+        device's keys."""
         r = np.random.default_rng(2)
         X = r.normal(size=(1500, 3))
         y = np.zeros(1500, np.float32)
+        before = _counters()
         ds0, ds1 = _pair(X, y, params={"max_bin": 500,
                                        "min_data_in_bin": 1})
         assert ds1.bins_t_dev.dtype == np.int32
         np.testing.assert_array_equal(ds0.bins, _dev_bins(ds1))
+        moved = _moved(before)
+        assert moved("ingest/rows_device") == 1500
+        assert moved("ingest/rows_counted") == 0
 
     def test_values_at_bin_boundaries(self):
         """Adversarial: values placed exactly AT each bound and one
@@ -109,14 +126,52 @@ class TestBinningParity:
         ds0, ds1 = _pair(X, y, categorical=[0])
         np.testing.assert_array_equal(ds0.bins, _dev_bins(ds1))
 
-    def test_multi_chunk_tail(self):
+    @pytest.mark.parametrize("dtype", [np.float64, np.float32])
+    def test_multi_chunk_tail(self, dtype):
         """Chunking must be invisible: odd row count, chunk smaller
         than the matrix, tail chunk partially filled."""
         r = np.random.default_rng(5)
-        X = r.normal(size=(999, 4)).astype(np.float32)
+        X = r.normal(size=(999, 4)).astype(dtype)
         y = np.zeros(999, np.float32)
         ds0, ds1 = _pair(X, y, chunk=123)
         np.testing.assert_array_equal(ds0.bins, _dev_bins(ds1))
+
+    def test_count_and_search_give_the_same_float64_bins(self,
+                                                         monkeypatch):
+        """The count by compares in the two key planes' order and the
+        gather search it replaces bin the same float64 values alike:
+        the search is forced by a bound of 0 (no knob)."""
+        import lightgbm_tpu.io.ingest as ingest
+        X = _nasty_matrix(seed=21)
+        y = np.zeros(len(X), np.float32)
+        before = _counters()
+        _, counted = _pair(X, y, categorical=[3])
+        assert _moved(before)("ingest/rows_counted") == len(X)
+        monkeypatch.setattr(ingest, "_COUNT_MAX_BOUNDS", 0)
+        before = _counters()
+        host, searched = _pair(X, y, categorical=[3])
+        assert _moved(before)("ingest/rows_counted") == 0
+        np.testing.assert_array_equal(_dev_bins(counted),
+                                      _dev_bins(searched))
+        np.testing.assert_array_equal(host.bins, _dev_bins(searched))
+
+    def test_float64_rows_cross_as_their_raw_words(self):
+        """Every source column numerical, used and in order: a float64
+        chunk crosses as the matrix's own 8 B a value (no key planes,
+        no NaN mask), and the count bins every row."""
+        r = np.random.default_rng(22)
+        n, f, chunk = 1000, 5, 256
+        X = r.normal(size=(n, f))
+        X[::13, 2] = np.nan
+        y = np.zeros(n, np.float32)
+        before = _counters()
+        ds0, ds1 = _pair(X, y, chunk=chunk)
+        np.testing.assert_array_equal(ds0.bins, _dev_bins(ds1))
+        moved = _moved(before)
+        assert moved("ingest/h2d_bytes") == -(-n // chunk) * chunk * f * 8
+        assert moved("ingest/rows_counted") == moved(
+            "ingest/rows_device") == n
+        assert moved("ingest/f32_rows") == 0
 
 
 class TestSampledBoundaries:
@@ -278,6 +333,33 @@ class TestKeyOrder:
             v32 = (v.astype(np.float32) + np.float32(0.0))
         k32 = _key32_host(v32)
         np.testing.assert_array_equal(np.sort(v32), v32[np.argsort(k32)])
+
+    def test_device_keys_from_raw_words_are_the_hosts(self):
+        """``_keys64_dev`` on the raw words of hostile float64 values
+        gives ``_keys64_host``'s planes of ``where(isnan, 0, v) + 0.0``
+        bit for bit, and its NaN mask is ``np.isnan``."""
+        import jax
+        from lightgbm_tpu.io.ingest import _keys64_dev, _keys64_host
+        fmax, tiny = np.finfo(np.float64).max, np.finfo(np.float64).tiny
+        nans = np.array([0x7FF8000000000000, 0xFFF8000000000000,
+                         0x7FF0000000000001, 0xFFF0000000000001,
+                         0x7FFFFFFFFFFFFFFF, 0xFFFFFFFFFFFFFFFF,
+                         0x7FF0000100000000, 0x7FF4000000000000,
+                         0xFFF00000FFFFFFFF],
+                        np.uint64).view(np.float64)
+        r = np.random.default_rng(23)
+        v = np.concatenate([
+            [0.0, -0.0, np.inf, -np.inf, fmax, -fmax, tiny, -tiny,
+             5e-324, -5e-324, tiny / 3, -tiny / 3, 1.0, -1.0,
+             np.nextafter(tiny, 0), -np.nextafter(tiny, 0)],
+            nans, r.normal(size=71) * 10.0 ** r.integers(-300, 300, 71)])
+        X = v.reshape(-1, 3)                        # [C, Fn], 3 features
+        hi, lo, nanm = jax.jit(_keys64_dev)(X.view(np.uint32))
+        want = np.where(np.isnan(X), 0.0, X) + 0.0
+        wh, wl = _keys64_host(want)
+        np.testing.assert_array_equal(np.asarray(hi), wh.T)
+        np.testing.assert_array_equal(np.asarray(lo), wl.T)
+        np.testing.assert_array_equal(np.asarray(nanm), np.isnan(X).T)
 
     def test_floor32_is_largest_f32_below(self):
         from lightgbm_tpu.io.ingest import _floor32

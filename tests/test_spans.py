@@ -143,6 +143,32 @@ def test_prefetch_outside_the_ingest_emits_no_wait_span(events):
     assert events == [] and _timer("ingest/prefetch_wait") == before
 
 
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_ingest_counts_the_rows_each_route_binned(dtype):
+    """``ingest/rows_counted`` (rows the count by compares binned, either
+    route) beside ``ingest/rows_device`` and ``ingest/f32_rows`` (which
+    ``guard.float64_route`` and ``ingest.us_per_row`` read: float32 rows
+    alone), and the ingest's spans feed their timers."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata, TpuDataset
+    X, y = make_binary(n=700)
+    before = dict(obs.default_registry().counter_items())
+    prep, chunk = _timer("ingest/prep_chunk"), _timer("ingest/chunk")
+    cfg = Config().set({**TEST_PARAMS, "objective": "binary",
+                        "tpu_ingest": 1, "tpu_ingest_chunk_rows": 256})
+    ds = TpuDataset(cfg).construct_from_matrix(X.astype(dtype),
+                                               Metadata(label=y))
+    assert ds.bins_t_dev is not None
+    after = dict(obs.default_registry().counter_items())
+    moved = lambda n: after.get(n, 0) - before.get(n, 0)
+    assert moved("ingest/rows_device") == moved("ingest/rows_counted") \
+        == 700
+    assert moved("ingest/f32_rows") == (700 if dtype == np.float32 else 0)
+    assert moved("ingest/h2d_chunks") == 3
+    assert _timer("ingest/prep_chunk")[1] == prep[1] + 3
+    assert _timer("ingest/chunk")[1] == chunk[1] + 3
+
+
 def test_span_adds_to_its_timer_with_no_tracer_and_no_profiler():
     assert not trace.enabled()
     before = _timer("unit/always")

@@ -386,6 +386,32 @@ def test_criteo_width_still_lowers_with_one_tile(spec):
                     "epsilon-hilo5-W24": (2, 5)}
 
 
+@pytest.mark.parametrize("F", [67, 700])
+def test_float64_bin_kernel_compiles_at_the_cells_widths(spec, F):
+    """The dense float64 route's chunk kernel (io/ingest.py) at the
+    criteo and yahoo widths, 255 levels a column, at the chunk rows it
+    picks: the raw words in, keyed and counted on the chip, holding no
+    more than the two key planes and the count ([Fn, C] each) besides
+    its operands."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata, TpuDataset
+    from lightgbm_tpu.io.ingest import DeviceBinner
+    X = np.random.default_rng(F).integers(0, 255, (2000, F)).astype(
+        np.float64)
+    cfg = Config().set({"objective": "regression", "max_bin": 255,
+                        "tpu_ingest": 0, "enable_bundle": False})
+    ds = TpuDataset(cfg).construct_from_matrix(
+        X, Metadata(label=np.zeros(2000, np.float32)))
+    binner = DeviceBinner(ds.mappers, ds.used_feature_map, cfg,
+                          np.float64)
+    C = binner.chunk_rows
+    assert binner.counts and binner._Bp == 256
+    compiled = binner._chunk_fn.lower(spec((C, 2 * F), jnp.uint32),
+                                      spec((C, 0), jnp.int32)).compile()
+    plane = -(-F // 8) * 8 * C * 4          # [Fn, C] int32, sublane-padded
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3 * plane
+
+
 @pytest.mark.parametrize("num_leaves", [255, 31])
 def test_leaf_gather_kernel_compiles(spec, num_leaves):
     from lightgbm_tpu.ops.predict import leaf_gather_pallas
