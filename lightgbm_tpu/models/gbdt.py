@@ -41,6 +41,7 @@ from ..config import Config
 from ..io.dataset import TpuDataset
 from ..metrics import Metric
 from ..obs import reqlog as obs_reqlog
+from ..obs import scopes as obs_scopes
 from ..obs import trace as obs_trace
 from ..objectives import ObjectiveFunction
 from ..ops.grower import pack_record, unpack_record
@@ -59,8 +60,9 @@ def _tail_summary(num_leaves, wave_work):
     """[R, 1 + w] int32 rows (num_leaves, wave_work) of R records' scalars
     and [w] vectors (w = 3; 5 under a row-sharding learner), stacked on
     the device for one download."""
-    return jnp.concatenate([jnp.stack(num_leaves)[:, None],
-                            jnp.stack(wave_work)], axis=1)
+    with jax.named_scope("lgbm/stop_check"):
+        return jnp.concatenate([jnp.stack(num_leaves)[:, None],
+                                jnp.stack(wave_work)], axis=1)
 
 
 class GBDT:
@@ -301,6 +303,7 @@ class GBDT:
             self._place_step_raw(z) for z in (
                 np.zeros((), np.int32), np.zeros(self._work_len, np.int32)))
         self._tail_host([])
+        obs_scopes.watch("stop_check", _tail_summary, self._tail_args([]))
         # fused-step state (see _get_step_fn)
         self._step_key = None
         self._zero_bias = jnp.zeros(self.num_tree_per_iteration,
@@ -1863,6 +1866,18 @@ class GBDT:
         """(num_leaves [R], wave_work [R, w]) of a list of records in
         ONE transfer: what the stop check reads."""
         R = self._stop_check_interval * self.num_tree_per_iteration
+        parts = []
+        for i in range(0, max(len(records), 1), R):
+            part = records[i:i + R]
+            parts.append(np.asarray(
+                _tail_summary(*self._tail_args(part)))[:len(part)])
+        a = np.concatenate(parts)
+        return a[:, 0], a[:, 1:]
+
+    def _tail_args(self, part):
+        """``_tail_summary``'s arguments for up to an interval's records,
+        a shorter part padded to the interval: one compiled program."""
+        R = self._stop_check_interval * self.num_tree_per_iteration
         w = self._work_len
 
         def work(r):
@@ -1870,17 +1885,10 @@ class GBDT:
             short = w - r.wave_work.shape[0]
             return jnp.pad(r.wave_work, (0, short)) if short else r.wave_work
 
-        parts = []
-        for i in range(0, max(len(records), 1), R):
-            part = records[i:i + R]
-            pad = R - len(part)
-            parts.append(np.asarray(_tail_summary(
-                tuple(r.num_leaves for r in part)
+        pad = R - len(part)
+        return (tuple(r.num_leaves for r in part)
                 + (self._tail_pad[0],) * pad,
-                tuple(work(r) for r in part)
-                + (self._tail_pad[1],) * pad))[:len(part)])
-        a = np.concatenate(parts)
-        return a[:, 0], a[:, 1:]
+                tuple(work(r) for r in part) + (self._tail_pad[1],) * pad)
 
     def _drop_last_iterations(self, n_groups: int) -> None:
         """Remove the last ``n_groups`` boosting iterations AND subtract

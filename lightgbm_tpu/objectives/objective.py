@@ -787,9 +787,6 @@ class LambdarankNDCG(ObjectiveFunction):
             log.fatal("Lambdarank: query tables exceed int32 positions")
         self._pair_classes = tuple(classes)
         self._pair_src = src.astype(np.int32)
-        obs.gauge("rank/queries").set(float(nq))
-        obs.gauge("rank/qmax").set(float(qmax))
-        obs.gauge("rank/width_classes").set(float(len(classes)))
         obs.gauge("rank/pairs_real").set(float(np.sum(counts ** 2)))
         obs.gauge("rank/pair_slots").set(float(sum(
             c["lab"].shape[0] * c["lab"].shape[1] ** 2 for c in classes)))

@@ -39,6 +39,11 @@ nothing to switch on.
   objective specs evaluated by the exporter thread every interval;
   compliance, remaining error budget and burn rate become first-class
   gauges and the ``/healthz``/``/slo`` bodies.
+- ``obs.scopes`` — ``op_scopes()``: each instruction name of the
+  compiled steps (and the stop check's download) to the innermost
+  ``lgbm/`` scope it runs under, built on the first ask from the
+  abstract signature the step cache keeps at first dispatch; a reader
+  joins it with a profiler trace's device self time by op name.
 - ``obs.flight`` — flight recorder (``tpu_flight_buffer``): always-on
   bounded rings of recent spans, log lines, reqlog records and metric
   snapshots, dumped as ONE self-contained postmortem bundle on
@@ -47,16 +52,18 @@ nothing to switch on.
   the dumps as ``meta.flight_dumps``.
 
 Only the stdlib-dependency modules (registry, trace, export, reqlog,
-slo, flight) are imported eagerly (utils/timing.py depends on registry
-and trace at module load); recorder/profiler import jax-adjacent
-modules and load on first use.
+slo, flight, scopes) are imported eagerly (utils/timing.py depends on
+registry and trace at module load; scopes imports jax inside its
+functions); recorder/profiler import jax-adjacent modules and load on
+first use.
 """
-from . import export, flight, registry, reqlog, slo, trace
+from . import export, flight, registry, reqlog, scopes, slo, trace
 from .registry import (MetricsRegistry, counter, default_registry, gauge,
                        histogram, latency_histogram, timer)
+from .scopes import op_scopes
 
 __all__ = [
-    "registry", "trace", "export", "reqlog", "slo", "flight",
+    "registry", "trace", "export", "reqlog", "slo", "flight", "scopes",
     "MetricsRegistry", "default_registry", "counter", "gauge",
-    "histogram", "latency_histogram", "timer",
+    "histogram", "latency_histogram", "timer", "op_scopes",
 ]

@@ -68,7 +68,7 @@ from collections import OrderedDict
 from typing import Callable, Dict, Optional
 
 from ..obs import registry as obs
-from ..obs import trace
+from ..obs import scopes, trace
 from ..utils import log, timing
 
 # bounded registry: one entry per distinct training geometry; an LRU
@@ -316,6 +316,9 @@ def _instrument(fn: Callable, key: tuple) -> Callable:
                 "geometry": hashlib.sha1(geometry.encode()).hexdigest()[:12],
                 "key": geometry, "backend_compile_s": 0.0,
                 "persistent_hits": 0, "persistent_misses": 0}
+            # the abstract signature alone, for obs.op_scopes() to lower
+            # on its first ask (never a buffer: two arguments are donated)
+            scopes.watch(scopes.STEP_LABEL, fn, args)
             try:
                 with trace.span(COMPILE_SPAN, cat="cache",
                                 args=span_args) as sp:

@@ -599,64 +599,66 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         # have reassigned bins_t
         hsrc = ((bins_t, _sparse_planes) if cfg.sparse_hist
                 else bins_t)
-        grad = grad.astype(f32) * sample_mask
-        hess = hess.astype(f32) * sample_mask
-        in_bag = sample_mask > 0
+        # the grower's own g/h: bagged, and quantized on the int8 tiers
+        with jax.named_scope("lgbm/gradients"):
+            grad = grad.astype(f32) * sample_mask
+            hess = hess.astype(f32) * sample_mask
+            in_bag = sample_mask > 0
 
-        if quant:
-            # gradient quantization (tpu_quantized_hist): integer-valued
-            # g/h in [-127, 127] make every MXU histogram product an
-            # exact int8 op at 2x the bf16 rate.
-            # GLOBAL quantization scales (max_reduce_fn = pmax in data
-            # mode): shard-local scales would make the dequantized psum
-            # sums correct but leave count-proxy bounds computed on the
-            # GLOBAL histogram invalid (divided by a local scale) and
-            # shard-divergent — every shard must see one (sg, sh).
-            # max is order-independent, so the pmax of shard maxima
-            # equals the single-chip max EXACTLY.
-            sg_s = jnp.maximum(max_reduce_fn(jnp.max(jnp.abs(grad))),
-                               1e-30) / 127.0
-            sh_s = jnp.maximum(max_reduce_fn(jnp.max(hess)),
-                               1e-30) / 127.0
-            # stochastic rounding keyed by GLOBAL row index (shard
-            # offset + local position) and a per-tree salt: unbiased
-            # per-bin sums and — unlike a positional PRNG stream —
-            # the same draw for the same row under ANY row sharding,
-            # so quantized data-parallel training reproduces the
-            # single-chip quantized trees. The salt mixes the scale
-            # bits with a WRAPPING int32 sum of the raw gradient bits:
-            # mod-2^32 adds commute, so the psum of shard-local bit
-            # sums equals the single-chip sum exactly (layout
-            # invariance), and the stream re-rolls whenever ANY row's
-            # gradient moves — scale bits alone would freeze it for
-            # constant-bound objectives (L1-family: max|g| and max h
-            # never change between trees).
-            bg = jax.lax.bitcast_convert_type(
-                sg_s.astype(f32), jnp.uint32)
-            bh = jax.lax.bitcast_convert_type(
-                sh_s.astype(f32), jnp.uint32)
-            gbits_sum = reduce_fn(jnp.sum(
-                jax.lax.bitcast_convert_type(grad, jnp.int32),
-                dtype=jnp.int32))
-            salt = (bg ^ ((bh << jnp.uint32(16)) | (bh >> jnp.uint32(16)))
-                    ^ _mix32(gbits_sum.astype(jnp.uint32)))
-            gidx = (row_offset_fn(n)
-                    + jnp.arange(n, dtype=jnp.int32)).astype(jnp.uint32)
-            u_g = _hash_uniform(gidx, salt)
-            u_h = _hash_uniform(gidx, salt ^ jnp.uint32(0x9E3779B9))
-            gq = jnp.clip(jnp.floor(grad / sg_s + u_g), -127.0, 127.0)
-            hq = jnp.clip(jnp.floor(hess / sh_s + u_h), 0.0, 127.0)
-            gh_scale = (sg_s, sh_s)
-            hg, hh = gq, hq            # what histogram passes consume
+            if quant:
+                # gradient quantization (tpu_quantized_hist): integer-valued
+                # g/h in [-127, 127] make every MXU histogram product an
+                # exact int8 op at 2x the bf16 rate.
+                # GLOBAL quantization scales (max_reduce_fn = pmax in data
+                # mode): shard-local scales would make the dequantized psum
+                # sums correct but leave count-proxy bounds computed on the
+                # GLOBAL histogram invalid (divided by a local scale) and
+                # shard-divergent — every shard must see one (sg, sh).
+                # max is order-independent, so the pmax of shard maxima
+                # equals the single-chip max EXACTLY.
+                sg_s = jnp.maximum(max_reduce_fn(jnp.max(jnp.abs(grad))),
+                                   1e-30) / 127.0
+                sh_s = jnp.maximum(max_reduce_fn(jnp.max(hess)),
+                                   1e-30) / 127.0
+                # stochastic rounding keyed by GLOBAL row index (shard
+                # offset + local position) and a per-tree salt: unbiased
+                # per-bin sums and — unlike a positional PRNG stream —
+                # the same draw for the same row under ANY row sharding,
+                # so quantized data-parallel training reproduces the
+                # single-chip quantized trees. The salt mixes the scale
+                # bits with a WRAPPING int32 sum of the raw gradient bits:
+                # mod-2^32 adds commute, so the psum of shard-local bit
+                # sums equals the single-chip sum exactly (layout
+                # invariance), and the stream re-rolls whenever ANY row's
+                # gradient moves — scale bits alone would freeze it for
+                # constant-bound objectives (L1-family: max|g| and max h
+                # never change between trees).
+                bg = jax.lax.bitcast_convert_type(
+                    sg_s.astype(f32), jnp.uint32)
+                bh = jax.lax.bitcast_convert_type(
+                    sh_s.astype(f32), jnp.uint32)
+                gbits_sum = reduce_fn(jnp.sum(
+                    jax.lax.bitcast_convert_type(grad, jnp.int32),
+                    dtype=jnp.int32))
+                salt = (bg ^ ((bh << jnp.uint32(16)) | (bh >> jnp.uint32(16)))
+                        ^ _mix32(gbits_sum.astype(jnp.uint32)))
+                gidx = (row_offset_fn(n)
+                        + jnp.arange(n, dtype=jnp.int32)).astype(jnp.uint32)
+                u_g = _hash_uniform(gidx, salt)
+                u_h = _hash_uniform(gidx, salt ^ jnp.uint32(0x9E3779B9))
+                gq = jnp.clip(jnp.floor(grad / sg_s + u_g), -127.0, 127.0)
+                hq = jnp.clip(jnp.floor(hess / sh_s + u_h), 0.0, 127.0)
+                gh_scale = (sg_s, sh_s)
+                hg, hh = gq, hq            # what histogram passes consume
 
-            def call_hist(bt, lids, wl):
-                return hist_fn(bt, hg, hh, lids, wl, gh_scale)
-        else:
-            gh_scale = None
-            hg, hh = grad, hess
+                def call_hist(bt, lids, wl):
+                    return hist_fn(bt, hg, hh, lids, wl, gh_scale)
+            else:
+                gh_scale = None
+                hg, hh = grad, hess
 
-            def call_hist(bt, lids, wl):
-                return hist_fn(bt, hg, hh, lids, wl)
+                def call_hist(bt, lids, wl):
+                    return hist_fn(bt, hg, hh, lids, wl)
 
         def dq(hsum):
             """Dequantize a reduced quantized-wire histogram — identity
@@ -751,53 +753,57 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         def set0(arr, v):
             return arr.at[0].set(v[0] if v.ndim else v)
 
-        state = _State(
-            leaf_ids=leaf0,
-            hist=jnp.zeros((L, F_h, B, 3), f32).at[0].set(root_hist[0]),
-            t_gain=set0(jnp.full(L, KMIN_SCORE, f32), root_split.gain),
-            t_feature=set0(jnp.zeros(L, jnp.int32), root_split.feature),
-            t_bin=set0(jnp.zeros(L, jnp.int32), root_split.threshold_bin),
-            t_default_left=set0(jnp.zeros(L, bool),
-                                root_split.default_left),
-            t_left_output=set0(jnp.zeros(L, f32), root_split.left_output),
-            t_right_output=set0(jnp.zeros(L, f32),
-                                root_split.right_output),
-            t_left_count=set0(jnp.zeros(L, f32), root_split.left_count),
-            t_right_count=set0(jnp.zeros(L, f32), root_split.right_count),
-            t_left_sum_g=set0(jnp.zeros(L, f32), root_split.left_sum_g),
-            t_left_sum_h=set0(jnp.zeros(L, f32), root_split.left_sum_h),
-            t_right_sum_g=set0(jnp.zeros(L, f32), root_split.right_sum_g),
-            t_right_sum_h=set0(jnp.zeros(L, f32), root_split.right_sum_h),
-            t_is_cat=set0(jnp.zeros(L, bool), root_split.is_cat),
-            t_cat_words=jnp.zeros((L, 8), jnp.int32).at[0].set(
-                root_split.cat_words[0] if root_split.cat_words.ndim > 1
-                else root_split.cat_words),
-            leaf_output=jnp.zeros(L, f32),
-            leaf_count=jnp.zeros(L, f32).at[0].set(root_c),
-            leaf_sum_g=jnp.zeros(L, f32).at[0].set(root_g),
-            leaf_sum_h=jnp.zeros(L, f32).at[0].set(root_h),
-            leaf_depth=jnp.zeros(L, jnp.int32),
-            num_leaves=jnp.int32(1),
-            n_splits=jnp.int32(0),
-            go_on=jnp.bool_(True),
-            rec=TreeRecord(
-                num_leaves=jnp.int32(1),
-                split_leaf=jnp.full(L - 1, -1, jnp.int32),
-                split_feature=jnp.full(L - 1, -1, jnp.int32),
-                split_bin=jnp.zeros(L - 1, jnp.int32),
-                split_gain=jnp.zeros(L - 1, f32),
-                split_default_left=jnp.zeros(L - 1, bool),
+        with jax.named_scope("lgbm/root_hist"):
+            # the pool, the root's histogram in its first slot
+            pool0 = jnp.zeros((L, F_h, B, 3), f32).at[0].set(root_hist[0])
+        with jax.named_scope("lgbm/wave/bookkeep"):
+            state = _State(
+                leaf_ids=leaf0,
+                hist=pool0,
+                t_gain=set0(jnp.full(L, KMIN_SCORE, f32), root_split.gain),
+                t_feature=set0(jnp.zeros(L, jnp.int32), root_split.feature),
+                t_bin=set0(jnp.zeros(L, jnp.int32), root_split.threshold_bin),
+                t_default_left=set0(jnp.zeros(L, bool),
+                                    root_split.default_left),
+                t_left_output=set0(jnp.zeros(L, f32), root_split.left_output),
+                t_right_output=set0(jnp.zeros(L, f32),
+                                    root_split.right_output),
+                t_left_count=set0(jnp.zeros(L, f32), root_split.left_count),
+                t_right_count=set0(jnp.zeros(L, f32), root_split.right_count),
+                t_left_sum_g=set0(jnp.zeros(L, f32), root_split.left_sum_g),
+                t_left_sum_h=set0(jnp.zeros(L, f32), root_split.left_sum_h),
+                t_right_sum_g=set0(jnp.zeros(L, f32), root_split.right_sum_g),
+                t_right_sum_h=set0(jnp.zeros(L, f32), root_split.right_sum_h),
+                t_is_cat=set0(jnp.zeros(L, bool), root_split.is_cat),
+                t_cat_words=jnp.zeros((L, 8), jnp.int32).at[0].set(
+                    root_split.cat_words[0] if root_split.cat_words.ndim > 1
+                    else root_split.cat_words),
                 leaf_output=jnp.zeros(L, f32),
-                leaf_count=jnp.zeros(L, f32),
-                leaf_sum_g=jnp.zeros(L, f32),
-                leaf_sum_h=jnp.zeros(L, f32),
-                internal_value=jnp.zeros(L - 1, f32),
-                internal_count=jnp.zeros(L - 1, f32),
-                split_is_cat=jnp.zeros(L - 1, bool),
-                split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
-                wave_work=jnp.zeros(n_work, jnp.int32),
-            ),
-        )
+                leaf_count=jnp.zeros(L, f32).at[0].set(root_c),
+                leaf_sum_g=jnp.zeros(L, f32).at[0].set(root_g),
+                leaf_sum_h=jnp.zeros(L, f32).at[0].set(root_h),
+                leaf_depth=jnp.zeros(L, jnp.int32),
+                num_leaves=jnp.int32(1),
+                n_splits=jnp.int32(0),
+                go_on=jnp.bool_(True),
+                rec=TreeRecord(
+                    num_leaves=jnp.int32(1),
+                    split_leaf=jnp.full(L - 1, -1, jnp.int32),
+                    split_feature=jnp.full(L - 1, -1, jnp.int32),
+                    split_bin=jnp.zeros(L - 1, jnp.int32),
+                    split_gain=jnp.zeros(L - 1, f32),
+                    split_default_left=jnp.zeros(L - 1, bool),
+                    leaf_output=jnp.zeros(L, f32),
+                    leaf_count=jnp.zeros(L, f32),
+                    leaf_sum_g=jnp.zeros(L, f32),
+                    leaf_sum_h=jnp.zeros(L, f32),
+                    internal_value=jnp.zeros(L - 1, f32),
+                    internal_count=jnp.zeros(L - 1, f32),
+                    split_is_cat=jnp.zeros(L - 1, bool),
+                    split_cat_words=jnp.zeros((L - 1, 8), jnp.int32),
+                    wave_work=jnp.zeros(n_work, jnp.int32),
+                ),
+            )
 
         def body(state: _State) -> _State:
             f32 = jnp.float32
@@ -1151,7 +1157,9 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     rec=rec,
                 )
 
-        state = jax.lax.while_loop(lambda s: s.go_on, body, state)
+        with jax.named_scope("lgbm/wave/loop"):
+            # the loop's own: its condition and the carry's copies
+            state = jax.lax.while_loop(lambda s: s.go_on, body, state)
         leaf_count = state.leaf_count
         if n_work > 3 or n > 2 ** 24:
             # past 2^24 rows a float32 count is no integer any more: a

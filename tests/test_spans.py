@@ -629,11 +629,11 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
 
 
 def test_ranking_objective_sets_its_gauges_and_names_its_scope():
-    """``rank/queries``, ``rank/qmax``, ``rank/width_classes``,
-    ``rank/pairs_real`` (sum of n_q^2) and ``rank/pair_slots`` (slots the
-    layout evaluates an iteration) are set where the objective builds its
-    query tables; ``benchmark/readers/rank.pair_slots_ratio.py`` reads the
-    last two. The pair block runs inside ``lgbm/gradients/rank_pairs``."""
+    """``rank/pairs_real`` (sum of n_q^2) and ``rank/pair_slots`` (slots
+    the layout evaluates an iteration) are set where the objective builds
+    its query tables, a class of tables a width;
+    ``benchmark/readers/rank.pair_slots_ratio.py`` reads the two. The pair
+    block runs inside ``lgbm/gradients/rank_pairs``."""
     from conftest import fit_gbdt
     rng = np.random.default_rng(9)
     counts = np.asarray([1, 3, 8, 9, 20, 33, 70, 5, 2, 12] * 8)
@@ -644,9 +644,10 @@ def test_ranking_objective_sets_its_gauges_and_names_its_scope():
                  group=counts)
     gauges = obs.default_registry().snapshot()["gauges"]
     classes = g.objective._pair_classes
-    assert gauges["rank/queries"] == len(counts)
-    assert gauges["rank/qmax"] == 70
-    assert gauges["rank/width_classes"] == len(classes) == 5
+    # widths 8 .. 64 and the widest cut to the longest query, 70
+    assert [c["lab"].shape[1] for c in classes] == [8, 16, 32, 64, 70]
+    assert sum(int((c["lab"][:, 0] >= 0).sum()) for c in classes) \
+        == len(counts)
     assert gauges["rank/pairs_real"] == float(np.sum(counts ** 2))
     assert gauges["rank/pair_slots"] == sum(
         c["lab"].shape[0] * c["lab"].shape[1] ** 2 for c in classes)

@@ -668,3 +668,28 @@ def test_criteo_dp4_step_compiles_at_the_cells_shape(topo, monkeypatch,
     # a quarter of the bins and of the row state, the whole pool
     assert F * rows_a_chip < per_chip < 3 * 2 ** 30
 
+
+
+def test_op_scope_table_books_each_kernel_to_its_scope(spec, monkeypatch):
+    """``obs/scopes.parse_hlo`` on the whole step compiled for the
+    described chip, as ``obs.op_scopes()`` reads it on the chip: the
+    Mosaic calls, under the names a device trace gives them, lie in the
+    scopes the benchmark's readers book them to (the root kernel in
+    ``lgbm/root_hist``, the fused kernel in ``lgbm/wave/hist``: the two
+    share no name clash there, ROADMAP S2), and every fusion, custom call
+    and copy the step runs lies under an ``lgbm/`` scope."""
+    from test_op_scopes import RUN, opcodes
+    from lightgbm_tpu.obs import scopes
+    step, dims, meta, aux = _step_under_test(monkeypatch, "hilo5",
+                                             shape=(1 << 15, 0, 32, 64))
+    text = step.lower(*_step_args(spec, spec, dims, meta, aux)).compile(
+        ).as_text()
+    table, ops = scopes.parse_hlo(text), opcodes(text)
+    calls = {n.rsplit(".", 1)[0]: table[n] for n in table
+             if ops[n] == "custom-call" and "_pallas" in n}
+    assert calls == {"wave_histogram_pallas": "lgbm/root_hist",
+                     "fused_partition_histogram_pallas": "lgbm/wave/hist",
+                     "leaf_gather_pallas": "lgbm/score_update"}
+    unscoped = [n for n in table if ops[n] in RUN
+                and not scopes.under(table[n], "lgbm")]
+    assert not unscoped, unscoped
