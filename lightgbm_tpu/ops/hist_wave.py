@@ -66,6 +66,22 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def take_rows(x, idx):
+    """``x[idx]`` for a short static index vector along the leading axis,
+    as one row slice a slot.
+
+    XLA's gather expander lowers a gather of a few rows out of a large
+    operand (the wide cells' histogram pool and bins matrix: hundreds of
+    MB and up) as slices of the WHOLE operand, each read and written
+    once a call, before it gathers out of the copies; a row slice reads
+    its row and nothing else. The same semantics as ``x[idx]``: a
+    negative index wraps once (-1 is the last row), one out of range
+    clamps."""
+    return jnp.stack([jax.lax.dynamic_index_in_dim(x, idx[k], 0,
+                                                   keepdims=False)
+                      for k in range(idx.shape[0])])
+
+
 def _feature_row(get_row, f: int, cache: dict, packed4: bool):
     """Logical feature ``f``'s bin row as i32 lanes (shared by the wave
     and fused kernels). ``get_row(r)`` reads stored bin row ``r`` as
@@ -1939,16 +1955,16 @@ def fused_partition_histogram_pallas(bins_t, g, h, sample_mask,
     if tiled:
         # the wave's split columns, one row a slot: a tile holds only
         # its own features' rows, so the W rows the partition reads are
-        # gathered here, once a pass (W x N bytes beside the F x N the
+        # taken here, once a pass (W x N bytes beside the F x N the
         # pass streams anyway)
         feat = jnp.maximum(tbl[TBL_FEAT].astype(jnp.int32), 0)
         if packed4:
-            byte = bins_t[feat // 2]
+            byte = take_rows(bins_t, feat // 2)
             cols = jnp.where((feat % 2 == 1)[:, None],
                              jnp.right_shift(byte, 4),
                              jnp.bitwise_and(byte, 15))
         else:
-            cols = bins_t[feat]                            # [W, N]
+            cols = take_rows(bins_t, feat)                 # [W, N]
         operands.append(jnp.pad(
             cols, ((0, blk["cols"][0] - W), (0, 0))))
         in_specs.append(pl.BlockSpec(blk["cols"],

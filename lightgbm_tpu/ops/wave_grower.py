@@ -41,8 +41,8 @@ from ..obs import registry as obs
 from .autotune import DEFAULT_HIST_CHUNK
 from .grower import TreeRecord
 from .hist_wave import (fused_partition_histogram_pallas,
-                        root_histogram_pallas, root_nchan, wave_histogram,
-                        wave_histogram_pallas)
+                        root_histogram_pallas, root_nchan, take_rows,
+                        wave_histogram, wave_histogram_pallas)
 from .partition import member_column, row_goes_right
 from .split import (FeatureMeta, SplitParams, SplitResult, KMIN_SCORE,
                     calculate_leaf_output, find_best_split)
@@ -423,6 +423,9 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
     # hist/rows_dotted it is what a dotted row met): the fused
     # kernel's, 0 on every other path, as hist/root_macs below
     wave_macs = 0
+    # the fused kernel under feature tiles takes the wave's split
+    # columns out of the bins a pass (take_rows; hist/row_take_bytes)
+    takes_cols = False
     if route == "pallas-tpu" and default_seams and not cfg.sparse_hist:
         bins_bytes = 1 if B <= 256 else 4
         tier = dict(int8=quant, count_proxy=proxy,
@@ -435,6 +438,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             F_rows=(-(-F_meta // 2) if cfg.packed4 and use_fused
                     else F_meta), bins_bytes=bins_bytes, **tier)
         obs.gauge("hist/feature_tiles").set(float(n_tiles))
+        takes_cols = bool(use_fused) and n_tiles > 1
         if use_fused:
             split = autotune.fused_wave_split(
                 geom=geom, compact_tile=autotune.hist_compact_tile(
@@ -711,6 +715,13 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
             root_hist = dq(hist_reduce_fn(local_root,
                                           scope="lgbm/root_hist/psum"))
             F_h = root_hist.shape[1]
+            # the bytes one wave pass's row takes read (take_rows): the W
+            # parents' rows of the pool, and the W split columns where
+            # the fused kernel walks feature tiles. Set as the step is
+            # traced, where the rows are known
+            obs.gauge("hist/row_take_bytes").set(float(
+                W * F_h * B * 3 * 4
+                + (W * n * bins_t.dtype.itemsize if takes_cols else 0)))
             if quant:
                 # root aggregates as dequantized sums of the SAME integer
                 # g/h the histogram passes consume, so later subtractions
@@ -918,7 +929,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                     hist_small = bound_counts(hist_small, gh_scale)
                 else:
                     lcnt_x, rcnt_x = lcnt, rcnt
-                parent_hist = state.hist[wl]                 # [W, F, B, 3]
+                parent_hist = take_rows(state.hist, wl)      # [W, F, B, 3]
                 hist_large = parent_hist - hist_small
                 if proxy:
                     # the count channel holds lower bounds, which do NOT
@@ -1052,7 +1063,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
                 # sibling by subtraction (sizes don't matter here)
                 hist_left = dq(hist_reduce_fn(
                     call_hist(hsrc, bag_mask_ids(leaf_ids), wl)))
-                parent_hist = state.hist[wl]
+                parent_hist = take_rows(state.hist, wl)
                 hist_right = parent_hist - hist_left
                 wl_s = jnp.where(active, wl, L)
                 new_s = jnp.where(active, new_ids, L)
@@ -1184,7 +1195,7 @@ def make_wave_grower(cfg: WaveGrowerConfig, meta: FeatureMeta,
         return rec, state.leaf_ids
 
     # jit-capture: ok(B, hp, cfg, quant, use_fused, use_fused_xla,
-    # use_root_kernel,
+    # use_root_kernel, takes_cols,
     # fused_chunk, fused_interpret, fused_partition_histogram_xla,
     # meta_const,
     # bound_counts, depth_ok, hist_fn, hist_reduce_fn, reduce_fn,

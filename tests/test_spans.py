@@ -628,6 +628,47 @@ def test_grower_on_the_kernels_route_sets_the_root_macs_gauge(monkeypatch):
     assert reg.snapshot()["gauges"]["hist/wave_macs"] == 0.0
 
 
+@pytest.mark.parametrize("tile", [None, 32], ids=["one-tile", "tiled"])
+def test_grower_sets_the_row_take_gauge(monkeypatch, tile):
+    """``hist/row_take_bytes`` (the bytes one wave pass's row takes read
+    through ``take_rows``) is set as the grower is traced, where the rows
+    are known: the W parents' rows of the ``[L, F, B, 3]`` f32 pool, and
+    where the fused kernel walks feature tiles the W split columns of N
+    bin bytes its wrapper takes too."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import autotune
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+    from lightgbm_tpu.ops.wave_grower import (WaveGrowerConfig,
+                                              make_wave_grower)
+    reg = obs.default_registry()
+    monkeypatch.setattr(reg.gauge("hist/row_take_bytes"), "_value", -1.0)
+    if tile:
+        real = autotune.hist_feature_tile
+        monkeypatch.setattr(autotune, "hist_feature_tile",
+                            lambda **kw: real(**{**kw, "force": tile}))
+    jax.clear_caches()
+    f, n, B, W = 72, 1024, 255, 4
+    meta = FeatureMeta(
+        num_bin=np.full(f, B, np.int32), missing_type=np.zeros(f, np.int32),
+        default_bin=np.zeros(f, np.int32), monotone=np.zeros(f, np.int32),
+        penalty=np.ones(f, np.float32))
+    cfg = WaveGrowerConfig(
+        num_leaves=7, num_bins=B, wave_size=W, chunk=512,
+        route="pallas-tpu", hp=SplitParams(min_data_in_leaf=5, has_cat=False))
+    grow = make_wave_grower(cfg, meta, jit=False)
+    assert reg.snapshot()["gauges"]["hist/feature_tiles"] == \
+        (3 if tile else 1)                             # 72 rows / 32
+    S = jax.ShapeDtypeStruct
+    jax.jit(grow).lower(
+        S((f, n), jnp.uint8), S((n,), jnp.float32), S((n,), jnp.float32),
+        S((n,), jnp.float32), S((f,), jnp.bool_))
+    jax.clear_caches()
+    pool_rows = W * f * B * 3 * 4
+    assert reg.snapshot()["gauges"]["hist/row_take_bytes"] == \
+        pool_rows + (W * n if tile else 0)
+
+
 def test_ranking_objective_sets_its_gauges_and_names_its_scope():
     """``rank/pairs_real`` (sum of n_q^2) and ``rank/pair_slots`` (slots
     the layout evaluates an iteration) are set where the objective builds

@@ -24,6 +24,7 @@ time may load the TPU library, and every xdist worker imports this
 file.
 """
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -693,3 +694,77 @@ def test_op_scope_table_books_each_kernel_to_its_scope(spec, monkeypatch):
     unscoped = [n for n in table if ops[n] in RUN
                 and not scopes.under(table[n], "lgbm")]
     assert not unscoped, unscoped
+
+
+_HLO_INSTR = re.compile(
+    r"^\s+(ROOT\s+)?%?([^\s=]+)\s+=\s+\w+\[([\d,]*)\]\S*\s+"
+    r"([a-z][a-z0-9\-]*)\((.*)$")
+_HLO_HEADER = re.compile(r"^(?:ENTRY\s+)?%?(\S+)\s+\(")
+
+
+def _whole_operand_ops(text, dims):
+    """``(name, opcode)`` of every instruction of a compiled module's text
+    whose array output spans every row of an operand of shape ``dims``
+    (its rank, its leading dimension: the whole operand, or a piece of it
+    cut along another axis), less those that only pass the buffer on
+    (parameters, tuple elements, loops, bitcasts) or update it in place
+    (a dynamic-update-slice or a scatter, or a fusion whose root is
+    one)."""
+    roots, comp, found = {}, None, []
+    for line in text.splitlines():
+        if not line[:1].isspace():
+            m = _HLO_HEADER.match(line)
+            comp = m.group(1) if m else None
+            continue
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        if m.group(1):
+            roots[comp] = m.group(4)
+        out = tuple(int(d) for d in m.group(3).split(",") if d)
+        if len(out) == len(dims) and out[0] == dims[0]:
+            called = re.search(r"calls=%?([\w.\-]+)", m.group(5))
+            found.append((m.group(2), m.group(4),
+                          called.group(1) if called else None))
+    in_place = {"dynamic-update-slice", "scatter"}
+    passes = {"parameter", "get-tuple-element", "tuple", "while",
+              "bitcast"} | in_place
+    return [(name, op) for name, op, called in found
+            if op not in passes
+            and not (op == "fusion" and roots.get(called) in in_place)]
+
+
+@pytest.mark.parametrize("F, N", [(2000, 393_216), (704, 458_752)],
+                         ids=["epsilon", "yahoo"])
+def test_row_takes_copy_no_whole_operand_in_the_wave_loop(spec, F, N):
+    """The wave loop's two row takes at the wide cells' shapes: the
+    parents' histograms out of the ``[255, F, 256, 3]`` f32 pool, which
+    the same pass then updates in place, and the split columns out of the
+    ``u8[F, N]`` bins. ``take_rows`` lowers to row slices: no
+    ``mini-gather-slice`` of XLA's gather expander, and no op in the
+    compiled loop writes a buffer that spans every row of the pool or of
+    the bins but the pool's in-place updates (a plain ``x[idx]`` copies
+    each whole operand in pieces every pass there)."""
+    from lightgbm_tpu.ops.hist_wave import take_rows
+    L, B, W = 255, 256, 24
+    pool_dims, bins_dims = (L, F, B, 3), (F, N)
+
+    def loop(pool, bins, wl, feat, small):
+        def body(i, carry):
+            pool, acc = carry
+            w = (wl + i) % L
+            large = take_rows(pool, w) - small
+            pool = pool.at[w].set(large, mode="drop")
+            pool = pool.at[(w + 7) % L].set(small, mode="drop")
+            cols = take_rows(bins, (feat + i) % F)
+            return pool, acc + cols.astype(jnp.int32)
+        return jax.lax.fori_loop(
+            0, 15, body, (pool, jnp.zeros((W, N), jnp.int32)))
+
+    text = jax.jit(loop, donate_argnums=0).lower(
+        spec(pool_dims, jnp.float32), spec(bins_dims, jnp.uint8),
+        spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W, F, B, 3), jnp.float32)).compile().as_text()
+    assert "while" in text and "mini-gather-slice" not in text
+    assert _whole_operand_ops(text, pool_dims) == []
+    assert _whole_operand_ops(text, bins_dims) == []

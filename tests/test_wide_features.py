@@ -143,6 +143,40 @@ def test_tiled_fused_kernel_equals_untiled(tier, compact):
         np.testing.assert_array_equal(np.asarray(b), np.asarray(a))
 
 
+# rows -> (operand shape, dtype): a pool of [F, B, 3] f32 histograms, a
+# bins matrix's rows, a packed4 matrix's byte rows
+_TAKE_OPERANDS = {
+    "pool-f32": ((15, 6, 16, 3), np.float32),
+    "bins-u8": ((72, 300), np.uint8),
+    "packed4-bytes": ((36, 300), np.uint8),
+}
+
+
+@pytest.mark.parametrize("w", [1, 24])
+@pytest.mark.parametrize("operand", sorted(_TAKE_OPERANDS))
+def test_take_rows_is_the_gather(operand, w):
+    """``take_rows(x, idx)`` is ``x[idx]`` bit for bit: negative indices
+    wrap (an idle wave slot's -1 takes the last row), repeated ones take
+    the row again, one past either end clamps."""
+    from lightgbm_tpu.ops.hist_wave import take_rows
+    shape, dt = _TAKE_OPERANDS[operand]
+    r = np.random.default_rng(w)
+    if dt == np.float32:
+        x = r.normal(size=shape).astype(dt)
+    else:
+        x = r.integers(0, 256, shape).astype(dt)
+    n = shape[0]
+    idx = np.concatenate([[-1, 3, 3, -n, n - 1, -n - 2, n + 5],
+                          r.integers(-n, n, 24)])[:w].astype(np.int32)
+    if w == 1:
+        idx[0] = -1
+    got = jax.jit(take_rows)(jnp.asarray(x), jnp.asarray(idx))
+    want = jnp.asarray(x)[jnp.asarray(idx)]
+    assert got.shape == (w,) + shape[1:] and got.dtype == dt
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got)[0], x[-1])
+
+
 def test_one_tile_wherever_everything_fits():
     """The benchmark's 67-feature cell (72 rows with the registry's
     pad) and the smoke's widths price as ONE tile, which takes the
@@ -227,12 +261,12 @@ def forced_tiles(monkeypatch):
     jax.clear_caches()
 
 
-def _grow(fused):
+def _grow(fused, packed4=False):
     from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
     from lightgbm_tpu.ops.wave_grower import (WaveGrowerConfig,
                                               make_wave_grower)
     r = np.random.default_rng(5)
-    n, f, B = 1500, 72, 32
+    n, f, B = 1500, 72, 16 if packed4 else 32
     bins = r.integers(0, B, (n, f)).astype(np.uint8)
     y = ((bins[:, 3] > 12) ^ (bins[:, 40] > 20) ^ (bins[:, 70] > 9))
     grad = jnp.asarray(np.where(y, -0.5, 0.5).astype(np.float32)
@@ -244,10 +278,14 @@ def _grow(fused):
         penalty=np.ones(f, np.float32))
     cfg = WaveGrowerConfig(
         num_leaves=15, num_bins=B, wave_size=8, chunk=512, fused=fused,
-        route="pallas-tpu" if fused else "two-pass",
+        route="pallas-tpu" if fused else "two-pass", packed4=packed4,
         hp=SplitParams(min_data_in_leaf=5, has_cat=False))
     grow = make_wave_grower(cfg, meta)
-    rec, leaf = grow(jnp.asarray(np.ascontiguousarray(bins.T)), grad, hess,
+    bins_t = np.ascontiguousarray(bins.T)
+    if packed4:
+        # two features a byte, the even one in the low nibble
+        bins_t = bins_t[0::2] | (bins_t[1::2] << 4)
+    rec, leaf = grow(jnp.asarray(bins_t), grad, hess,
                      jnp.ones(n, jnp.float32), jnp.ones(f, bool))
     return rec, np.asarray(leaf)
 
@@ -271,6 +309,34 @@ def test_grower_under_tiles_grows_the_same_tree(forced_tiles):
                                np.asarray(ref.leaf_output), rtol=2e-5)
     assert {int(f) // 32 for f in np.asarray(rec.split_feature)
             if f >= 0} == {0, 1, 2}                    # splits in every tile
+
+
+@pytest.mark.parametrize("packed4", [False, True],
+                         ids=["uint8", "packed4"])
+def test_grower_takes_rows_as_a_gather_would(forced_tiles, monkeypatch,
+                                             packed4):
+    """Under tiles the grower takes the wave's parent histograms out of
+    the pool and the fused kernel's wrapper its split columns out of the
+    bins by ``take_rows``; the tree is the one plain ``x[idx]`` gathers
+    grow, every field of the record bit for bit, and the gauge
+    ``hist/row_take_bytes`` counts both takes."""
+    from lightgbm_tpu.ops import hist_wave, wave_grower
+    rec, leaf = _grow(fused=True, packed4=packed4)
+    f_rows = 36 if packed4 else 72
+    assert obs.default_registry().snapshot()["gauges"][
+        "hist/row_take_bytes"] == 8 * 72 * (16 if packed4 else 32) * 3 * 4 \
+        + 8 * 1500
+    assert obs.default_registry().snapshot()["gauges"][
+        "hist/feature_tiles"] == -(-f_rows // 32)
+    jax.clear_caches()
+    for mod in (hist_wave, wave_grower):
+        monkeypatch.setattr(mod, "take_rows", lambda x, idx: x[idx])
+    ref, leaf_ref = _grow(fused=True, packed4=packed4)
+    assert int(rec.num_leaves) == 15
+    np.testing.assert_array_equal(leaf, leaf_ref)
+    for name, a in rec._asdict().items():
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(getattr(ref, name)), name)
 
 
 def test_wide_booster_under_tiles_against_the_plain_reference(
